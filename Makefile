@@ -6,7 +6,7 @@
 # `set -o pipefail` in the tier1 recipe needs bash, not POSIX sh.
 SHELL := /bin/bash
 
-.PHONY: check tier1 verify bench-smoke bench-rl trace-smoke
+.PHONY: check tier1 verify chip-smoke bench-smoke bench-rl trace-smoke
 
 # Static analysis over the files changed vs origin/main (the whole
 # package is still parsed, so cross-module rules keep context).  Falls
@@ -28,6 +28,11 @@ tier1:
 		| tee /tmp/_t1.log
 
 verify: check tier1
+
+# The first command on the chip (fails unless JAX's first device is a
+# TPU; sent through the chip tool, one command per call).
+chip-smoke:
+	python chip_smoke.py
 
 # Flagship perf drill on the synthetic input-bound workload (ISSUE 18):
 # a real launch fan-out — 1 input host + trainer + compile-artifact

@@ -12,6 +12,9 @@ if [ "$1" = "--tsan" ]; then
       -o libtpurecord_tsan.so tpurecord.cc -lz
   echo "built $(pwd)/libtpurecord_tsan.so (ThreadSanitizer)"
 else
-  g++ -O3 -fPIC -shared -std=c++17 -Wall -o libtpurecord.so tpurecord.cc -lz
+  # Build beside the target and rename: a concurrent loader (xdist
+  # workers, launch ranks) never dlopens a half-written library.
+  g++ -O3 -fPIC -shared -std=c++17 -Wall -o "libtpurecord.so.$$" tpurecord.cc -lz
+  mv -f "libtpurecord.so.$$" libtpurecord.so
   echo "built $(pwd)/libtpurecord.so"
 fi

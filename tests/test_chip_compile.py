@@ -1,0 +1,87 @@
+"""The main path's kernels compile for the real chip — without the chip.
+
+The TPU compiler is installed in the CPU sandbox and compiles for a
+*described* v5e (guide ``on-chip-measurement`` §2.3).  Every other flash
+test runs ``interpret=True``, which cannot see what Mosaic refuses (a
+slice off the tiling, too much fast memory); these cases can, at real
+widths, about two seconds each and no chip time.
+
+This is the only file that describes the chip.  The topology is described
+inside a module-scoped fixture — nothing at import time, not in
+conftest.py, not autouse, no child process: one process at a time may
+load the TPU library, xdist workers each import every test file, and a
+file that decides its tests at import gives workers different collections.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    # conftest.py strips every TPU_* variable; without this the compiler
+    # logs under /tmp.
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure to describe = skip
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # A compile for a described chip is written to the persistent cache
+    # but cannot be read back without a chip: keep these out of it.
+    was_enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was_enabled)
+    cc.reset_cache()
+
+
+# (id, S, D, q heads, kv heads, flash_attention kwargs, segment_ids?)
+CASES = [
+    ("s2048_d128_b512x256", 2048, 128, 32, 8,
+     dict(block_q=512, block_k=256), False),
+    ("s4096_d128_b256x512", 4096, 128, 32, 8,
+     dict(block_q=256, block_k=512), False),
+    ("s8192_d128_b256x512", 8192, 128, 32, 8,
+     dict(block_q=256, block_k=512), False),
+    ("s2048_d64_default", 2048, 64, 32, 8, {}, False),
+    ("s8192_d64_default", 8192, 64, 32, 8, {}, False),
+    ("s4096_d40_unet_full", 4096, 40, 8, 8, dict(causal=False), False),
+    ("s2048_d128_segment_ids", 2048, 128, 32, 8,
+     dict(block_q=512, block_k=256), True),
+    ("s2048_d128_ring_hop_offsets", 2048, 128, 32, 8,
+     dict(block_q=512, block_k=256, q_offset=2048, k_offset=0), False),
+    ("s2000_d128_padded", 2000, 128, 32, 8, {}, False),
+]
+
+
+@pytest.mark.parametrize("s,d,h,hkv,kwargs,segments",
+                         [c[1:] for c in CASES], ids=[c[0] for c in CASES])
+def test_flash_fwd_bwd_compiles_for_v5e(one_chip, s, d, h, hkv, kwargs,
+                                        segments):
+    from tpucfn.kernels.flash_attention import flash_attention
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    q = sds((1, s, h, d), jnp.bfloat16)
+    kv = sds((1, s, hkv, d), jnp.bfloat16)
+    args = [q, kv, kv] + ([sds((1, s), jnp.int32)] if segments else [])
+
+    def loss(q, k, v, seg=None):
+        out = flash_attention(q, k, v, segment_ids=seg, interpret=False,
+                              **{"causal": True, **kwargs})
+        return jnp.sum(out.astype(jnp.float32))
+
+    step = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2)))
+    text = step.lower(*args).compile().as_text()
+    # forward, dq and dk/dv kernels — compiled, not interpreted
+    assert text.count("tpu_custom_call") >= 3, text.count("tpu_custom_call")

@@ -381,12 +381,11 @@ def test_mfu_gauge_stays_unset_without_flops_or_peak():
 
 # ---- cost-analysis helpers ----------------------------------------------
 
-def test_cost_analysis_flops_unwraps_list_and_dict():
-    assert cost_analysis_flops([{"flops": 3.0}]) == 3.0  # jax <= 0.4.x
-    assert cost_analysis_flops({"flops": 5.0}) == 5.0    # jax >= 0.5
-    assert cost_analysis_flops([]) is None
+def test_cost_analysis_flops_reads_dict_or_nothing():
+    assert cost_analysis_flops({"flops": 5.0}) == 5.0
+    assert cost_analysis_flops({}) is None
     assert cost_analysis_flops(None) is None
-    assert cost_analysis_flops([{"bytes accessed": 1.0}]) is None
+    assert cost_analysis_flops({"bytes accessed": 1.0}) is None
     assert cost_analysis_flops("garbage") is None
 
 

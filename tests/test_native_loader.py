@@ -2,6 +2,10 @@
 byte-identical payloads, same corruption detection, batch reads, and the
 dataset integration path."""
 
+import os
+import shutil
+from pathlib import Path
+
 import numpy as np
 import pytest
 
@@ -98,3 +102,43 @@ def test_native_and_python_agree_on_dataset(tmp_path):
     ex = decode_example(a[0])
     assert ex["image"].shape == (32, 32, 3)
     np.testing.assert_array_equal(ex["image"], decode_example(b[0])["image"])
+
+
+# ---- the library is built from what git holds (ISSUE 22) -----------------
+
+NATIVE_SRC = Path(native.__file__).resolve().parent.parent.parent / "native"
+
+
+@pytest.fixture
+def native_dir(tmp_path, monkeypatch):
+    """A private copy of native/ wired into a fresh (unloaded) loader."""
+    d = tmp_path / "native"
+    d.mkdir()
+    for name in ("tpurecord.cc", "build.sh"):
+        shutil.copy(NATIVE_SRC / name, d / name)
+    monkeypatch.setattr(native, "_NATIVE_DIR", d)
+    monkeypatch.setattr(native, "_LIB_PATH", d / "libtpurecord.so")
+    monkeypatch.setattr(native, "_lib", None)
+    monkeypatch.setattr(native, "_lib_error", None)
+    return d
+
+
+def test_library_older_than_its_source_is_rebuilt(native_dir):
+    lib = native_dir / "libtpurecord.so"
+    lib.write_bytes(b"a stale artefact that is not even a library")
+    src_mtime = (native_dir / "tpurecord.cc").stat().st_mtime
+    os.utime(lib, (src_mtime - 60, src_mtime - 60))
+    assert native._lib_is_stale()
+    assert native.native_available(), native._lib_error
+    assert native._lib_error is None
+    assert lib.read_bytes()[:4] == b"\x7fELF"
+    assert not native._lib_is_stale()
+
+
+def test_failed_build_sets_lib_error_and_falls_back(native_dir):
+    (native_dir / "build.sh").write_text(
+        "echo 'no toolchain on this host' >&2\nexit 1\n")
+    assert not native.native_available()
+    assert "no toolchain on this host" in native._lib_error
+    with pytest.raises(RuntimeError, match="native reader unavailable"):
+        native.NativeShardReader(native_dir / "missing.tpurec")

@@ -128,10 +128,15 @@ def test_input_partition_down_degrades_within_the_deadline(plane):
 
 def test_input_torn_frame_degrades_bit_identical(plane):
     shards, svc, proxy = plane
-    stream, ds = _resilient(shards, proxy)
     ref = list(_local(shards).batches(1))
-    got = [next(stream)]
-    proxy.inject("tear", after_bytes=100, direction="down")
+    # Armed BEFORE the stream connects, at a fixed offset into the
+    # ~14 KB epoch (handshake and the first two ~1.2 KB frames pass,
+    # the third tears).  The whole epoch crosses the proxy within
+    # milliseconds of the connect, so a tear injected after the first
+    # batch raced the producer and, on a loaded host, never fired.
+    proxy.inject("tear", after_bytes=3000, direction="down")
+    stream, ds = _resilient(shards, proxy)
+    got = [next(stream)]  # a healthy batch flowed before the fault
     got.extend(stream)
     assert stream.degraded
     _assert_streams_equal(got, ref)

@@ -900,6 +900,11 @@ def cmd_serve(args) -> int:
 
     cc_client = _cc_configure(registry=registry)
 
+    # Persistent XLA cache, before the first compile (jax arms it once).
+    from tpucfn.obs import enable_compile_cache as _enable_compile_cache
+
+    _enable_compile_cache()
+
     cfg, engine = demo_llama_engine(args.preset, seed=args.seed,
                                     max_batch=args.max_batch,
                                     cache_len=args.cache_len,

@@ -149,26 +149,33 @@ def lowered_fingerprint(lowered, *, label: str = "") -> str:
 def serialize_compiled(compiled) -> bytes | None:
     """One self-describing payload for a ``Compiled`` executable, or
     None when this backend/jax build cannot serialize (the caller then
-    simply skips publishing)."""
+    simply skips publishing).  The payload names the devices the program
+    was compiled for: loading must put it back on exactly those."""
     import pickle
 
     from jax.experimental.serialize_executable import serialize
 
     payload, in_tree, out_tree = serialize(compiled)
-    return pickle.dumps({"v": 1, "exe": payload,
+    devices = [d.id for d in compiled.runtime_executable().local_devices()]
+    return pickle.dumps({"v": 2, "exe": payload, "devices": devices,
                          "in_tree": in_tree, "out_tree": out_tree})
 
 
 def deserialize_compiled(payload: bytes, meta: dict):
     import pickle
 
+    import jax
     from jax.experimental.serialize_executable import deserialize_and_load
 
     obj = pickle.loads(payload)
-    if not isinstance(obj, dict) or obj.get("v") != 1:
+    if not isinstance(obj, dict) or obj.get("v") != 2:
         raise ValueError("unknown compile-cache payload format")
-    return deserialize_and_load(obj["exe"], obj["in_tree"],
-                                obj["out_tree"])
+    # Without execution_devices jax loads across every local device and a
+    # one-device program then fails at call time on a several-device host.
+    by_id = {d.id: d for d in jax.local_devices()}
+    return deserialize_and_load(
+        obj["exe"], obj["in_tree"], obj["out_tree"],
+        execution_devices=[by_id[i] for i in obj["devices"]])
 
 
 # -- the wrapper ------------------------------------------------------------

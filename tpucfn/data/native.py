@@ -2,15 +2,15 @@
 
 The C++ library owns the hot read path (offset indexing, CRC validation,
 batched contiguous copies, GIL released during calls); this module loads
-it, auto-building with g++ on first use, and degrades to the pure-Python
-reader in :mod:`tpucfn.data.records` when no toolchain is available —
-same format, same errors, ~10× slower.
+it, building with g++ when the library is missing or older than its
+source, and degrades to the pure-Python reader in
+:mod:`tpucfn.data.records` when no toolchain is available — same format,
+same errors, ~10× slower; ``_lib_error`` records why.
 """
 
 from __future__ import annotations
 
 import ctypes
-import os
 import subprocess
 from pathlib import Path
 from typing import Iterator, Sequence
@@ -23,48 +23,26 @@ _lib = None
 _lib_error: str | None = None
 
 
+def _lib_is_stale() -> bool:
+    """The library is built from what git holds: missing, or older than
+    its source or build script, means rebuild (before the first dlopen,
+    so the loader never sees two images of one path)."""
+    if not _LIB_PATH.exists():
+        return True
+    built = _LIB_PATH.stat().st_mtime_ns
+    return any((_NATIVE_DIR / src).stat().st_mtime_ns > built
+               for src in ("tpurecord.cc", "build.sh"))
+
+
 def _load_lib():
     global _lib, _lib_error
     if _lib is not None or _lib_error is not None:
         return _lib
     try:
-        if not _LIB_PATH.exists():
+        if _lib_is_stale():
             subprocess.run(["sh", str(_NATIVE_DIR / "build.sh")], check=True,
                            capture_output=True, text=True, timeout=120)
         lib = ctypes.CDLL(str(_LIB_PATH))
-        if not hasattr(lib, "tpurec_validate"):
-            # Stale .so from before the zero-copy entry points: rebuild,
-            # then load under a DIFFERENT path — dlopen caches by
-            # original path and re-CDLL'ing _LIB_PATH would return the
-            # old image even after the file on disk changed. The copy
-            # path is deterministic (keyed by mtime+size) so concurrent
-            # or repeated upgrades reuse one file instead of leaking a
-            # temp dir per process.
-            import shutil
-            import tempfile
-
-            subprocess.run(["sh", str(_NATIVE_DIR / "build.sh")], check=True,
-                           capture_output=True, text=True, timeout=120)
-            st = _LIB_PATH.stat()
-            # Per-uid 0700 cache dir (a world-writable /tmp path could be
-            # pre-planted by another local user); unique-name + rename so
-            # a concurrent upgrader never dlopens a half-written copy.
-            cache_dir = Path(tempfile.gettempdir()) / f"tpurec-{os.getuid()}"
-            try:
-                cache_dir.mkdir(mode=0o700, exist_ok=True)
-                dstat = cache_dir.stat()
-                if dstat.st_uid != os.getuid() or (dstat.st_mode & 0o077):
-                    raise OSError("cache dir not exclusively ours")
-            except OSError:
-                cache_dir = Path(tempfile.mkdtemp(prefix="tpurec-"))
-            fresh = cache_dir / f"{st.st_mtime_ns}-{st.st_size}.so"
-            if not fresh.exists():
-                tmp_fd, tmp_name = tempfile.mkstemp(dir=cache_dir,
-                                                    suffix=".so.part")
-                os.close(tmp_fd)
-                shutil.copyfile(_LIB_PATH, tmp_name)
-                os.replace(tmp_name, fresh)  # atomic publish
-            lib = ctypes.CDLL(str(fresh))
         lib.tpurec_open.restype = ctypes.c_void_p
         lib.tpurec_open.argtypes = [ctypes.c_char_p, ctypes.c_char_p, ctypes.c_int]
         lib.tpurec_count.restype = ctypes.c_long
@@ -83,8 +61,9 @@ def _load_lib():
         lib.tpurec_close.restype = None
         lib.tpurec_close.argtypes = [ctypes.c_void_p]
         _lib = lib
-    except Exception as e:  # no g++ / build failure → Python fallback
-        _lib_error = str(e)
+    except Exception as e:  # no g++ / build failure → Python fallback,
+        # never silent: _lib_error says why (chip_smoke.py fails on it)
+        _lib_error = f"{e!r} {getattr(e, 'stderr', None) or ''}".strip()
     return _lib
 
 

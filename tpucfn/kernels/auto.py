@@ -52,12 +52,11 @@ def untuned_flash_min_s() -> int:
 
 
 def _backend() -> str:
+    # A backend that fails to initialise raises: reading it as "cpu"
+    # would silently run dense on a machine whose chip did not start.
     import jax
 
-    try:
-        return jax.default_backend()
-    except Exception:  # noqa: BLE001 — backend init failure → be safe
-        return "cpu"
+    return jax.default_backend()
 
 
 _warned_untuned_kinds: set[str] = set()
@@ -73,10 +72,7 @@ def _warn_once_if_kind_untuned() -> None:
     operator to diff HLO dumps."""
     import jax
 
-    try:
-        kind = jax.devices()[0].device_kind
-    except Exception:  # noqa: BLE001 — backend init failure → stay quiet
-        return
+    kind = jax.devices()[0].device_kind
     if kind in _warned_untuned_kinds:
         return
     _warned_untuned_kinds.add(kind)  # scan the table once per kind

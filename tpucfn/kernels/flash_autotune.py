@@ -101,10 +101,7 @@ def _save(cache: dict[str, tuple[int, int]]) -> None:
 def _entry(s: int, d: int, dtype, causal: bool) -> tuple | None:
     import jax
 
-    try:
-        kind = jax.devices()[0].device_kind
-    except Exception:  # noqa: BLE001 — backend not initialized yet
-        return None
+    kind = jax.devices()[0].device_kind
     return _load().get(_key(kind, causal, s, d, dtype))
 
 

@@ -103,14 +103,10 @@ def ledger_path(d: str | Path, host_id: int) -> Path:
 # --------------------------------------------------------------------------
 
 def cost_analysis_value(cost, key: str) -> float | None:
-    """One value from a ``compiled.cost_analysis()`` result.
-
-    jax <= 0.4.x returns a per-device LIST of dicts, >= 0.5 a single
-    dict — the one unwrap the live gauges and bench.py share; ``None``
-    when the backend reports nothing (CPU fallback, mock devices).
+    """One value from a ``compiled.cost_analysis()`` result (a dict) —
+    shared by the live gauges and bench.py; ``None`` when the backend
+    reports nothing (CPU, mock devices).
     """
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else None
     try:
         v = cost.get(key) if cost else None
     except AttributeError:

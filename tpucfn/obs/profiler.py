@@ -46,8 +46,12 @@ def start_profiler_server(port: int = 9012):
 
 def enable_compile_cache(cache_dir: str | None = None,
                          min_compile_time_s: float | None = None) -> str:
-    """Point XLA's persistent compilation cache at ``cache_dir`` (default
-    ``$TPUCFN_XLA_CACHE`` or /tmp/tpucfn_xla_cache).  A relaunch of the
+    """Turn on XLA's persistent compilation cache and return its
+    directory (the rule is ``tpucfn.utils.env.xla_cache_dir``).  With
+    ``$JAX_COMPILATION_CACHE_DIR`` set, jax already reads that directory
+    and this sets no other; unset, the cache is the fixed
+    ``<checkout>/.cache/xla``.  An explicit ``cache_dir`` wins — for
+    tests and drills that measure cold against warm.  A relaunch of the
     same program — the restart supervisor's resume, or the second
     ``tpucfn launch`` on a pod — then skips recompilation, which is what
     keeps time_to_first_step from being compile-dominated (SURVEY.md §7.4
@@ -63,14 +67,16 @@ def enable_compile_cache(cache_dir: str | None = None,
 
     from tpucfn.utils.env import xla_cache_dir
 
+    explicit = bool(cache_dir)
     cache_dir = cache_dir or xla_cache_dir()
+    if explicit or not os.environ.get("JAX_COMPILATION_CACHE_DIR", "").strip():
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
     if min_compile_time_s is None:
         raw = os.environ.get("TPUCFN_XLA_CACHE_MIN_S", "").strip()
         try:
             min_compile_time_s = float(raw) if raw else 1.0
         except ValueError:
             min_compile_time_s = 1.0
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
     jax.config.update("jax_persistent_cache_min_compile_time_secs",
                       float(min_compile_time_s))
     return cache_dir
