@@ -340,7 +340,9 @@ def _train_loop_body(trainer, ds, mesh, args, items_per_step, extra_axes,
                     f"input plane degraded to local loading: {reason}",
                     flush=True))
             batches = iter(prefetch_to_mesh(stream, mesh,
-                                            extra_axes=extra_axes))
+                                            extra_axes=extra_axes,
+                                            tracer=obs.tracer,
+                                            first_step=step + 1))
             # Cross-host causality (ISSUE 20): the resilient stream
             # queues one wire context per batch it yields; popping
             # exactly one per batch CONSUMED here keeps the FIFO
@@ -363,8 +365,9 @@ def _train_loop_body(trainer, ds, mesh, args, items_per_step, extra_axes,
                 obs.record_data_wait(
                     step + 1, t0_wait, t_wait,
                     link=pop_link() if pop_link is not None else None)
-                with obs.step(step + 1):
+                with obs.step(step + 1) as mark:
                     state, metrics = trainer.step(state, batch)
+                    mark.dispatched()  # launched; from here the host waits
                     step = int(state.step)  # blocks -> honest step timing
                 if hb is not None:
                     hb.update_step(step)  # step-lag signal for the monitor

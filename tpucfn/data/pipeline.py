@@ -11,6 +11,7 @@ keeping the TPU fed from host memory without a host↔device sync bubble
 
 from __future__ import annotations
 
+import itertools
 import os
 import queue
 import threading
@@ -421,17 +422,32 @@ def prefetch_to_mesh(
     *,
     extra_axes: tuple[str | None, ...] = (),
     depth: int = 2,
+    tracer=None,
+    first_step: int = 1,
 ) -> Iterator[Any]:
     """Wrap a host-batch iterator so device transfer overlaps compute.
 
     A daemon thread stays ``depth`` global batches ahead; the consumer
     always finds its next batch already resident on the mesh.
 
+    With a ``tracer`` (:class:`tpucfn.obs.trace.Tracer`) the thread
+    writes two spans a batch, ``trace_id`` the step the batch feeds,
+    counted from ``first_step``: ``input_load`` around the pull from
+    ``it`` (read, transform, stack) and ``input_place`` around the
+    placement on the mesh (the host's part of it: the transfer goes on
+    after the call has returned), both with the batch's ``bytes``;
+    ``input_place`` also says how many batches were ``queued`` when this
+    one was ready (0: the loop is starved; ``depth``: the loader is
+    ahead).
+
     ``TPUCFN_INPUT_DEVICE_SHARDED=1`` opts into the device-layout
     placement (ISSUE 18 satellite): served rows go to their devices as
     numpy views, skipping the trainer-side staging copy.  Default off —
     the plain path is byte-identical to before the flag existed.
     """
+    import jax
+
+    from tpucfn.obs.trace import Tracer
     from tpucfn.parallel.sharding import (
         shard_batch,
         shard_batch_device_layout,
@@ -442,11 +458,26 @@ def prefetch_to_mesh(
              else shard_batch)
     q: queue.Queue = queue.Queue(maxsize=depth)
     _END = object()
+    it = iter(it)
+    if tracer is None:
+        tracer = Tracer(None)  # times, writes nothing
 
     def producer():
         try:
-            for host_batch in it:
-                q.put(place(mesh, host_batch, extra_axes))
+            for step in itertools.count(first_step):
+                with tracer.span("input_load", trace_id=step) as s:
+                    host_batch = next(it, _END)
+                    if host_batch is _END:
+                        s["end_of_stream"] = True
+                        break
+                    nbytes = sum(x.nbytes for x in
+                                 jax.tree_util.tree_leaves(host_batch))
+                    s["bytes"] = nbytes
+                with tracer.span("input_place", trace_id=step) as s:
+                    placed = place(mesh, host_batch, extra_axes)
+                    s["bytes"] = nbytes
+                    s["queued"] = q.qsize()
+                q.put(placed)
         except Exception as e:  # surface pipeline errors to the consumer
             q.put(e)
             return

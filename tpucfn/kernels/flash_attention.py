@@ -236,6 +236,7 @@ def _flash_fwd(q, k, v, q_seg, kv_seg, *, causal, q_offset, k_offset,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
+        name="flash_fwd",
     )(*[a for a in args if a is not None])
     return o, lse
 
@@ -430,6 +431,7 @@ def _flash_bwd(q, k, v, o, lse, do, q_seg, kv_seg, *, causal, q_offset,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
+        name="flash_dq",
     )(*args)[0]
 
     # ---- dK/dV: grid (b, hkv, ki, rep, qi) — for each KV block,
@@ -477,6 +479,7 @@ def _flash_bwd(q, k, v, o, lse, do, q_seg, kv_seg, *, causal, q_offset,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary", "arbitrary")),
         interpret=interpret,
+        name="flash_dkv",
     )(*args2)
     return dq, dk, dv
 

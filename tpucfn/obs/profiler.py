@@ -118,13 +118,8 @@ class ProfileCapture:
         if self._capture_fn is not None:
             self._capture_fn(d, seconds)
             return
-        import jax
-
-        jax.profiler.start_trace(str(d))
-        try:
+        with trace_to(d):
             self.sleep(seconds)
-        finally:
-            jax.profiler.stop_trace()
 
     def __call__(self, seconds: float) -> dict:
         if not math.isfinite(seconds) or not 0 < seconds <= self.MAX_SECONDS:
@@ -248,6 +243,31 @@ class CompileCacheProbe:
 
 
 @contextlib.contextmanager
+def trace_to(log_dir: str | Path):
+    """One profiler trace into ``log_dir``: the one place a capture of this
+    program starts (``--profile`` and ``POST /profile``).
+
+    Device events only.  The host's tracer perturbs what it captures on
+    the jobs this program runs: on a v5e chip, under JAX's defaults (host
+    level 2, Python call tracing), every other ResNet-50 step stalled for
+    up to 1.4 s and the device read 77% idle against 5% (PERF.md, PR 24);
+    at host level 1 without Python tracing the same job's steps took
+    0.21–1.33 s against 0.17 s, the device read 71% idle against 43%, and
+    stopping the trace took 194 s (PERF.md, PR 25).  The loop's own phases
+    are in its trace file (``obs.trace``), on the host's clock."""
+    import jax
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 0
+    jax.profiler.start_trace(str(log_dir), profiler_options=opts)
+    try:
+        yield
+    finally:
+        jax.profiler.stop_trace()
+
+
+@contextlib.contextmanager
 def profile_steps(log_dir: str | Path, *, enabled: bool = True):
     """Trace everything inside the context into ``log_dir`` (one trace per
     host). Use around a small steady-state step range, not the whole run —
@@ -255,12 +275,7 @@ def profile_steps(log_dir: str | Path, *, enabled: bool = True):
     if not enabled:
         yield
         return
-    import jax
-
     d = Path(log_dir)
     d.mkdir(parents=True, exist_ok=True)
-    jax.profiler.start_trace(str(d))
-    try:
+    with trace_to(d):
         yield
-    finally:
-        jax.profiler.stop_trace()

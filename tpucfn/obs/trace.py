@@ -7,13 +7,15 @@ the same shippable-file contract as the metrics JSONL), carrying:
     {"kind": "span", "name": "prefill", "trace_id": 7, "span_id": 3,
      "parent_id": null, "start": <monotonic>, "dur_s": 0.012,
      "ts": <wall clock>, "mono": <monotonic at write>, "host": 0,
-     "role": "server", "attrs": {...}}
+     "role": "server", "tid": "MainThread", "attrs": {...}}
 
 * ``trace_id`` groups one logical unit — a serve request (its req_id)
   or a training step (the step number).
 * ``start`` is ``time.monotonic()`` so spans from one process compare
   and sum exactly (the TTFT-decomposition acceptance check); ``ts`` is
   wall clock so hosts can be merged approximately on one timeline.
+* ``tid`` is the writing thread's name, so a reader can keep to one
+  thread (the train loop's, or its ``tpucfn-prefetch`` loader's).
 * Parent links propagate through a contextvar, so a span opened inside
   another nests without any plumbing (within one thread — a new
   ``threading.Thread`` starts with a fresh context, so hand it
@@ -38,7 +40,9 @@ op headers — see ``data.service`` for the wire layout.
 
 Wired into the serve request lifecycle in ``serve/frontend.py``
 (queue_wait → prefill → decode_round → request_done) and into the
-trainer loop via ``train.trainer.TrainerObs`` (data_wait / step / ckpt).
+trainer loop via ``train.trainer.TrainerObs`` (data_wait / step, split
+into step_dispatch and step_wait / ckpt) and its loader thread via
+``data.pipeline.prefetch_to_mesh`` (input_load / input_place).
 """
 
 from __future__ import annotations
@@ -169,6 +173,7 @@ class Tracer:
             "mono": time.monotonic(),
             "host": self.host_id,
             "role": self.role,
+            "tid": threading.current_thread().name,
             "attrs": attrs,
         }
         rp = _normalize_rp(remote_parent)
@@ -206,25 +211,11 @@ class Tracer:
         finally:
             end = time.monotonic()
             _current_span.reset(token)
-            if self._f is not None:
-                # span_id was pre-drawn so children could have pointed at
-                # us; write with it rather than drawing a fresh one.
-                self._write_span(name, span_id, parent, t0, end, trace_id,
-                                 {**attrs, **extra})
-
-    def _write_span(self, name, span_id, parent_id, start, end, trace_id,
-                    attrs) -> None:
-        line = json.dumps({
-            "kind": "span", "name": name, "trace_id": trace_id,
-            "span_id": span_id, "parent_id": parent_id,
-            "start": start, "dur_s": end - start,
-            "ts": time.time() - (time.monotonic() - start),
-            "mono": time.monotonic(),
-            "host": self.host_id, "role": self.role, "attrs": attrs,
-        })
-        with self._lock:
-            if self._f is not None:
-                self._f.write(line + "\n")
+            # span_id was pre-drawn so children could have pointed at
+            # us; write with it rather than drawing a fresh one.
+            self.record(name, start=t0, end=end, trace_id=trace_id,
+                        parent_id=parent, span_id=span_id,
+                        **{**attrs, **extra})
 
     def close(self) -> None:
         with self._lock:

@@ -337,6 +337,20 @@ class Trainer:
                                    self._opt_rank_mismatch)
 
 
+class StepMark:
+    """What :meth:`TrainerObs.step` yields: the loop calls
+    ``dispatched()`` once ``trainer.step`` has returned, and that one
+    clock reading divides the ``step`` span into ``step_dispatch`` and
+    ``step_wait``."""
+
+    def __init__(self, clock):
+        self._clock = clock
+        self.at: float | None = None
+
+    def dispatched(self) -> None:
+        self.at = self._clock()
+
+
 class TrainerObs:
     """Observability for the canonical train loop phases.
 
@@ -348,13 +362,15 @@ class TrainerObs:
     per step and name the straggler).  Phase timings are host-observed
     wall times: ``step`` includes the device dispatch AND the block on
     the result, which is the honest per-step number on an async runtime
-    (same rule as StepTimer).
+    (same rule as StepTimer); the trace divides it into ``step_dispatch``
+    and ``step_wait`` where the loop marks the hand-over.
 
     Usage (what examples/common.py's run_train_loop does)::
 
         obs = TrainerObs(registry, tracer)
         with obs.data_wait():   batch = next(it)
-        with obs.step(step_no): state, m = trainer.step(state, batch); ...
+        with obs.step(step_no) as s:
+            state, m = trainer.step(state, batch); s.dispatched(); ...
         with obs.ckpt(step_no): ckpt.save(step_no, state)
     """
 
@@ -437,10 +453,9 @@ class TrainerObs:
             dt = self.clock() - t0
             metric.observe(dt)
             self.tracer.record(name, start=t0, dur_s=dt, trace_id=step)
-            if name != "step":  # step attribution happens in step()
-                self.ledger.account(name, dt, step=step)
-                if self.flight is not None:
-                    self.flight.record(name, step=step, dur_s=dt)
+            self.ledger.account(name, dt, step=step)
+            if self.flight is not None:
+                self.flight.record(name, step=step, dur_s=dt)
 
     def _compile_bucket(self) -> str:
         """``compile`` vs ``compile_cached`` vs ``compile_fetched`` for
@@ -514,6 +529,12 @@ class TrainerObs:
             self.flight.record("data_wait", step=step, dur_s=dur_s)
 
     def step(self, step: int | None = None):
+        """Times one step; yields a :class:`StepMark` whose
+        ``dispatched()`` the loop calls once ``trainer.step`` has
+        returned, which divides the ``step`` span into its children
+        ``step_dispatch`` (the host launching the program) and
+        ``step_wait`` (the host waiting for the chip) at one clock
+        reading.  A loop that never calls it writes ``step`` alone."""
         @contextlib.contextmanager
         def _span():
             if self._steps_seen == 0 and self.compile_probe is not None:
@@ -525,12 +546,24 @@ class TrainerObs:
                     self.compile_probe.rearm()
                 except Exception:  # noqa: BLE001 — probe is best-effort
                     pass
+            mark = StepMark(self.clock)
             t0 = self.clock()
             try:
-                with self._phase("step", self.step_time, step):
-                    yield
+                yield mark
             finally:
-                self._record_step(step, self.clock() - t0)
+                t1 = self.clock()
+                self.step_time.observe(t1 - t0)
+                sid = self.tracer.next_span_id()
+                self.tracer.record("step", start=t0, end=t1, trace_id=step,
+                                   span_id=sid)
+                if mark.at is not None:
+                    # the children share the mark, so their durations sum
+                    # to the parent's
+                    self.tracer.record("step_dispatch", start=t0, end=mark.at,
+                                       trace_id=step, parent_id=sid)
+                    self.tracer.record("step_wait", start=mark.at, end=t1,
+                                       trace_id=step, parent_id=sid)
+                self._record_step(step, t1 - t0)
             self.steps_total.add()
             if step is not None:
                 self.last_step.set(step)
