@@ -373,3 +373,250 @@ def test_multiprocess_loader_get_after_close_raises_cleanly(tmp_path):
     loader.close()
     with pytest.raises(RuntimeError, match="closed"):
         loader._get(0, timeout_s=0.05)
+
+
+# ---- a large batch is assembled in pieces (ISSUE 26) ------------------------
+# Every path of ShardedDataset must yield, split, the bytes it yields whole.
+# The tests' rows are small, so the split is forced by taking the threshold
+# away and giving the process `cores` cores; the reference is the same dataset
+# under the module's own constants (one np.stack, today's statement).
+
+def _rows(n=50, ragged_at=None):
+    rs = np.random.RandomState(3)
+    return [{"image": rs.rand(6, 5, 3).astype(np.float32),
+             "mask": rs.rand(4 if i != ragged_at else 5) > 0.5,
+             "half": rs.rand(3).astype(np.float16),
+             "label": np.int64(i)} for i in range(n)]  # the label is 0-d
+
+
+def _force_split(monkeypatch, cores=3):
+    from tpucfn.data import pipeline
+
+    monkeypatch.setattr(pipeline, "_ASSEMBLE_PIECE_BYTES", 1)
+    monkeypatch.setattr(pipeline.os, "sched_getaffinity",
+                        lambda pid: set(range(cores)))
+
+
+def _assemble_threads():
+    import threading
+
+    return [t for t in threading.enumerate()
+            if t.name.startswith("tpucfn-assemble-")]
+
+
+def _jitter(ex, aug_rs):
+    return dict(ex, image=ex["image"] + aug_rs.rand(6, 5, 3).astype(np.float32))
+
+
+@pytest.mark.parametrize("case, kwargs", [
+    ("not_divisible", dict(batch_size_per_process=10)),  # 10 rows, 3 pieces
+    ("remainder", dict(batch_size_per_process=16, drop_remainder=False)),
+    ("unshuffled", dict(batch_size_per_process=7, shuffle=False)),
+    ("transform", dict(batch_size_per_process=10, transform=_jitter)),
+    ("transform_workers", dict(batch_size_per_process=10, transform=_jitter,
+                               num_workers=2)),
+    ("streaming", dict(batch_size_per_process=10, cache_in_memory=False,
+                       shuffle_buffer=8)),
+    ("streaming_remainder", dict(batch_size_per_process=16,
+                                 cache_in_memory=False, drop_remainder=False)),
+])
+def test_split_assembly_is_byte_identical_to_whole(tmp_path, monkeypatch,
+                                                   case, kwargs):
+    from tpucfn.data import pipeline
+
+    shards = write_dataset_shards(iter(_rows()), tmp_path, num_shards=2)
+
+    def epochs():
+        ds = ShardedDataset(shards, seed=11, process_index=0, process_count=1,
+                            **kwargs)
+        out = []
+        for batch in ds.batches(2):
+            out.append((batch, pipeline._take_pieces()))
+        return out
+
+    whole = epochs()
+    _force_split(monkeypatch, cores=3)
+    split = epochs()
+    assert len(split) == len(whole) == 2 * (
+        -(-50 // kwargs["batch_size_per_process"])
+        if kwargs.get("drop_remainder") is False
+        else 50 // kwargs["batch_size_per_process"])
+    assert {p for _, p in whole} == {1}
+    # a remainder of 2 rows cannot make 3 pieces
+    assert {p for _, p in split} <= {2, 3} and 3 in {p for _, p in split}
+    for (w, _), (s, _) in zip(whole, split):
+        assert list(w) == list(s) == ["image", "mask", "half", "label"]
+        for k in w:
+            assert s[k].dtype == w[k].dtype and s[k].shape == w[k].shape
+            assert s[k].tobytes() == w[k].tobytes(), (case, k)
+    assert split[0][0]["label"].shape == (kwargs["batch_size_per_process"],)
+
+
+def test_a_held_batch_is_never_written_again(tmp_path, monkeypatch):
+    """Depth 2 plus three more batches later the first is what it was, and no
+    two batches share memory: every output is a fresh array."""
+    shards = write_dataset_shards(iter(_rows(96)), tmp_path, num_shards=2)
+    _force_split(monkeypatch)
+    ds = ShardedDataset(shards, batch_size_per_process=8, seed=5,
+                        process_index=0, process_count=1)
+    it = ds.batches(1)
+    held = next(it)
+    before = {k: v.tobytes() for k, v in held.items()}
+    later = [next(it) for _ in range(2 + 3)]
+    assert {k: v.tobytes() for k, v in held.items()} == before
+    everything = [held] + later
+    for i, a in enumerate(everything):
+        for b in everything[i + 1:]:
+            assert not any(np.shares_memory(a[k], b[k]) for k in a)
+
+
+def test_small_batches_take_the_whole_path_and_make_no_pool(tmp_path,
+                                                            monkeypatch):
+    """The size rule under the module's own constants: the decoder cells'
+    32 KB of tokens, CIFAR's rows and these tests' stay whole and the pool
+    is never made; `rn50-cached`'s 154 MB splits, by the cores there are."""
+    from tpucfn.data import pipeline
+
+    monkeypatch.setattr(pipeline._AssemblePool, "_shared", None)
+    monkeypatch.setattr(pipeline.os, "sched_getaffinity",
+                        lambda pid: set(range(13)))
+    paths = write_dataset_shards(synthetic_cifar10(64), tmp_path, num_shards=2)
+    ds = ShardedDataset(paths, batch_size_per_process=32)
+    assert len(list(ds.epoch(0))) == 2
+    assert pipeline._take_pieces() == 1
+    assert pipeline._AssemblePool._shared is None
+    assert pipeline._pieces_for(1 * 8192 * 4, 1) == 1  # mistral7b-s8192
+    assert pipeline._pieces_for(8 * 1024 * 4, 8) == 1  # mistral7b-s1024
+    assert pipeline._pieces_for(256 * 32 * 32 * 3 * 4, 256) == 1  # CIFAR b256
+    rn50 = 256 * 224 * 224 * 3 * 4
+    assert pipeline._pieces_for(rn50, 256) == pipeline._ASSEMBLE_MAX_PIECES
+    monkeypatch.setattr(pipeline.os, "sched_getaffinity", lambda pid: {0, 1, 2})
+    assert pipeline._pieces_for(rn50, 256) == 3
+    monkeypatch.setattr(pipeline.os, "sched_getaffinity", lambda pid: {0})
+    assert pipeline._pieces_for(rn50, 256) == 1
+    # a MultiProcessLoader's workers share the host's cores
+    monkeypatch.setattr(pipeline.os, "sched_getaffinity",
+                        lambda pid: set(range(8)))
+    monkeypatch.setattr(pipeline, "_core_sharers", 4)
+    assert pipeline._pieces_for(rn50, 256) == 2
+    assert pipeline._AssemblePool._shared is None
+
+
+@pytest.mark.parametrize("fault", ["ragged", "a_piece_fails"])
+def test_a_failed_assembly_raises_from_next_and_does_not_hang(
+        tmp_path, monkeypatch, fault):
+    import threading
+
+    from tpucfn.data import pipeline
+
+    rows = _rows(40, ragged_at=13 if fault == "ragged" else None)
+    shards = write_dataset_shards(iter(rows), tmp_path, num_shards=2)
+    _force_split(monkeypatch)
+    if fault == "a_piece_fails":
+        real = np.stack
+
+        def stack(arrays, *a, **kw):
+            if threading.current_thread().name.startswith("tpucfn-assemble-"):
+                raise MemoryError("no room for this piece")
+            return real(arrays, *a, **kw)
+
+        monkeypatch.setattr(pipeline.np, "stack", stack)
+    ds = ShardedDataset(shards, batch_size_per_process=40, shuffle=False,
+                        process_index=0, process_count=1)
+    raised = []
+
+    def pull():
+        try:
+            next(ds.batches(1))
+        except Exception as e:  # noqa: BLE001 — the test looks at it
+            raised.append(e)
+
+    t = threading.Thread(target=pull)
+    t.start()
+    t.join(timeout=60)
+    assert not t.is_alive()
+    (e,) = raised
+    if fault == "ragged":  # np.stack's own complaint, as before the split
+        assert isinstance(e, ValueError) and "same shape" in str(e)
+    else:
+        assert isinstance(e, MemoryError)
+
+
+def test_two_datasets_share_one_pool(tmp_path, monkeypatch):
+    from tpucfn.data import pipeline
+
+    shards = write_dataset_shards(iter(_rows()), tmp_path, num_shards=2)
+    _force_split(monkeypatch)
+    kw = dict(batch_size_per_process=10, process_index=0, process_count=1)
+    list(ShardedDataset(shards, seed=1, **kw).epoch(0))
+    pool, threads = pipeline._AssemblePool._shared, _assemble_threads()
+    assert pool is not None
+    assert len(threads) >= pipeline._ASSEMBLE_MAX_PIECES - 1
+    assert all(t.daemon for t in threads)
+    list(ShardedDataset(shards, seed=2, **kw).epoch(0))
+    assert pipeline._AssemblePool._shared is pool
+    assert _assemble_threads() == threads
+
+
+def test_loaders_on_many_threads_share_the_pool_without_mixing_rows(
+        tmp_path, monkeypatch):
+    """More loader threads than cores over the one pool, the interpreter
+    switching threads every 10 us: every batch is still its own."""
+    import sys
+    import threading
+
+    shards = write_dataset_shards(iter(_rows(64)), tmp_path, num_shards=2)
+    kw = dict(batch_size_per_process=16, process_index=0, process_count=1)
+    want = {seed: [b["image"].tobytes() + b["label"].tobytes()
+                   for b in ShardedDataset(shards, seed=seed, **kw).batches(3)]
+            for seed in range(12)}
+    _force_split(monkeypatch, cores=8)
+    got, errors = {}, []
+
+    def load(seed):
+        try:
+            got[seed] = [b["image"].tobytes() + b["label"].tobytes() for b in
+                         ShardedDataset(shards, seed=seed, **kw).batches(3)]
+        except Exception as e:  # noqa: BLE001
+            errors.append(e)
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=load, args=(s,)) for s in want]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+        assert not any(t.is_alive() for t in threads)
+    finally:
+        sys.setswitchinterval(old)
+    assert not errors
+    assert got == want
+
+
+@pytest.mark.parametrize("stream, pieces", [
+    ("split", 3), ("whole", 1), ("not_a_local_dataset", None)])
+def test_prefetch_input_load_rows_carry_pieces(tmp_path, monkeypatch,
+                                               mesh_dp8, stream, pieces):
+    from tpucfn.obs.trace import Tracer, read_trace_file
+
+    shards = write_dataset_shards(iter(_rows(48)), tmp_path / "d", num_shards=2)
+    ds = ShardedDataset(shards, batch_size_per_process=16, process_index=0,
+                        process_count=1)
+    if stream == "split":
+        _force_split(monkeypatch, cores=3)
+    it = ds.batches(1)
+    if stream == "not_a_local_dataset":  # as over the input plane
+        it = iter([dict(b) for b in list(it)])
+    tracer = Tracer(tmp_path / "t.jsonl")
+    assert len(list(prefetch_to_mesh(it, mesh_dp8, tracer=tracer))) == 3
+    tracer.close()
+    rows = [r for r in read_trace_file(tmp_path / "t.jsonl")
+            if r.get("kind") == "span"]
+    loads = [r for r in rows if r["name"] == "input_load"]
+    assert len(loads) == 4 and {r["tid"] for r in loads} == {"tpucfn-prefetch"}
+    assert loads[-1]["attrs"] == {"end_of_stream": True}
+    assert [r["attrs"].get("pieces") for r in loads[:-1]] == [pieces] * 3
+    assert all("pieces" not in r["attrs"]
+               for r in rows if r["name"] == "input_place")

@@ -15,6 +15,8 @@ import itertools
 import os
 import queue
 import threading
+from concurrent.futures import Future
+from concurrent.futures import wait as futures_wait
 from pathlib import Path
 from typing import Any, Iterator, Sequence
 
@@ -34,6 +36,119 @@ def _jax_process_identity() -> tuple[int, int]:
     return jax.process_index(), jax.process_count()
 
 
+# ---- a large batch is assembled in pieces, on several cores -----------------
+# One np.stack of 256 cached 602 KB rows (154 MB) into a fresh array took one
+# thread of the chip's host (13 cores) 165 ms against a 99 ms step; 15.5 ms of
+# that is the copy and the rest the first touch of freshly mapped pages, and
+# both split across threads: 98, 70, 58, 53 and 47 ms in 2, 4, 6, 8 and 12
+# pieces, some 5 ms more beside a running train loop (PERF.md, PR 26).  So an
+# array of a batch that is large enough is filled in disjoint row ranges from
+# a small pool.  How many pieces is read from the array's bytes and the cores
+# this process may use; nothing sets it.
+# 8 MB of fresh pages cost one thread some 9 ms there.  An array under two
+# pieces is one np.stack, as ever; up to 32 MiB glibc mostly hands heap memory
+# back already touched and the split buys little (16 MB: 1.7-7.7 ms whole,
+# 1.9-3.7 in two), beyond that every output is mapped afresh (34 MB: 37.7 ms
+# whole, 17.2 in four).
+_ASSEMBLE_PIECE_BYTES = 8 << 20
+_ASSEMBLE_MAX_PIECES = 8  # 12 bought 5 ms more, and the loop wants cores too
+_core_sharers = 1  # loader processes that share this process's cores
+_assembled = threading.local()  # .pieces of the batch this thread made last
+
+
+class _AssemblePool:
+    """The process's one pool for batch assembly, made at the first large
+    batch: daemon threads ``tpucfn-assemble-N`` that run a call and hand its
+    outcome to a ``Future``.  One per process and not per dataset (an input
+    host builds a dataset per stream); its threads write no span
+    (``input_load`` has one writer, the thread that asked)."""
+
+    _shared: "_AssemblePool | None" = None
+    _lock = threading.Lock()
+
+    @classmethod
+    def shared(cls) -> "_AssemblePool":
+        with cls._lock:
+            if cls._shared is None:
+                # the asking thread takes a piece itself
+                cls._shared = cls(_ASSEMBLE_MAX_PIECES - 1)
+            return cls._shared
+
+    def __init__(self, threads: int):
+        self._work: queue.SimpleQueue = queue.SimpleQueue()
+        for i in range(threads):
+            threading.Thread(target=self._run, daemon=True,
+                             name=f"tpucfn-assemble-{i}").start()
+
+    def _run(self) -> None:
+        while True:
+            fut, fn, args, kwargs = self._work.get()
+            try:
+                fut.set_result(fn(*args, **kwargs))
+            except BaseException as e:  # noqa: BLE001 — result() raises it again
+                fut.set_exception(e)
+
+    def submit(self, fn, *args, **kwargs) -> Future:
+        fut: Future = Future()
+        self._work.put((fut, fn, args, kwargs))
+        return fut
+
+
+def _pieces_for(nbytes: int, rows: int) -> int:
+    """In how many pieces an array of ``nbytes`` and ``rows`` rows is
+    assembled: as many as it holds ``_ASSEMBLE_PIECE_BYTES``, bounded by the
+    cores this process may use (shared among a ``MultiProcessLoader``'s
+    workers) and the cap; 1 is whole, today's statement."""
+    by_bytes = nbytes // _ASSEMBLE_PIECE_BYTES
+    if by_bytes < 2:  # tokens, labels, small images: nothing else is asked
+        return 1
+    cores = len(os.sched_getaffinity(0)) // _core_sharers
+    return max(1, min(by_bytes, cores, _ASSEMBLE_MAX_PIECES, rows))
+
+
+def _stack(arrays: list) -> tuple[np.ndarray, int]:
+    """``np.stack(arrays)``, byte for byte, and in how many pieces it was
+    made: a large one is filled in pieces from the pool, into a fresh output
+    (never one that an earlier batch used: a caller, or a transfer still in
+    flight, may hold that batch)."""
+    first, n = arrays[0], len(arrays)
+    pieces = _pieces_for(getattr(first, "nbytes", 0) * n, n)
+    # mixed shapes must fail, and mixed dtypes promote, as np.stack has them
+    if pieces == 1 or not all(
+            isinstance(a, np.ndarray) and a.shape == first.shape
+            and a.dtype == first.dtype for a in arrays):
+        return np.stack(arrays), 1
+    out = np.empty((n,) + first.shape, first.dtype)
+    cuts = [n * i // pieces for i in range(pieces + 1)]
+    pool = _AssemblePool.shared()
+    futs = [pool.submit(np.stack, arrays[a:b], out=out[a:b])
+            for a, b in zip(cuts[1:-1], cuts[2:])]
+    try:
+        np.stack(arrays[:cuts[1]], out=out[:cuts[1]])
+    finally:
+        futures_wait(futs)  # nobody writes to `out` once this returns
+    for f in futs:
+        f.result()
+    return out, pieces
+
+
+def _assemble(chosen: list[dict]) -> dict[str, np.ndarray]:
+    """A list of examples as one batch; the most pieces any of its arrays
+    took is left for :func:`_take_pieces` on this thread."""
+    batch, most = {}, 1
+    for k in chosen[0]:
+        batch[k], pieces = _stack([ex[k] for ex in chosen])
+        most = max(most, pieces)
+    _assembled.pieces = most
+    return batch
+
+
+def _take_pieces() -> int | None:
+    """The pieces of the batch the calling thread's ``ShardedDataset`` made
+    last (1 = whole), read once: ``None`` where it made none since."""
+    return _assembled.__dict__.pop("pieces", None)
+
+
 class ShardedDataset:
     """Deterministic, per-process-sharded, shuffled batch iterator over
     tpurecord shards.
@@ -44,6 +159,12 @@ class ShardedDataset:
     from a common permutation schedule and global batches are reproducible
     run-to-run (the reference's implicit input order was not — SURVEY.md
     §7.4 item 1 calls out exactly this divergence risk).
+
+    An array of a batch that holds two or more ``_ASSEMBLE_PIECE_BYTES``
+    (8 MB) is assembled in pieces on the process's one small pool
+    (``tpucfn-assemble-N``), as many as its bytes and the cores this
+    process may use allow, up to 8; a smaller one by one ``np.stack``.
+    The bytes are the same either way, and every batch is a fresh array.
     """
 
     def __init__(
@@ -159,7 +280,7 @@ class ShardedDataset:
                         zip(chosen, seeds)))
                 else:
                     chosen = [self.transform(ex, aug_rs) for ex in chosen]
-            return {k: np.stack([ex[k] for ex in chosen]) for k in chosen[0]}
+            return _assemble(chosen)
 
         if not self.cache_in_memory:
             yield from self._epoch_streaming(epoch, emit)
@@ -240,6 +361,8 @@ def _mp_worker_main(out_q, shard_paths, ds_kwargs, worker_index,
     via ShardedDataset's process-sharding logic; streams
     ("batch", dict) items, an ("end", epoch) marker per epoch, and a
     final ("done", None) — or ("error", traceback)."""
+    global _core_sharers
+    _core_sharers = num_workers  # this host's cores are the workers' together
     try:
         ds = ShardedDataset(shard_paths, process_index=worker_index,
                             process_count=num_workers, **ds_kwargs)
@@ -435,10 +558,13 @@ def prefetch_to_mesh(
     counted from ``first_step``: ``input_load`` around the pull from
     ``it`` (read, transform, stack) and ``input_place`` around the
     placement on the mesh (the host's part of it: the transfer goes on
-    after the call has returned), both with the batch's ``bytes``;
-    ``input_place`` also says how many batches were ``queued`` when this
-    one was ready (0: the loop is starved; ``depth``: the loader is
-    ahead).
+    after the call has returned), both with the batch's ``bytes``.
+    ``input_load`` says in how many ``pieces`` a local
+    :class:`ShardedDataset` assembled the batch (1: whole, the batch is
+    small or the process has one core; absent where ``it`` is no local
+    dataset, as over the input plane); ``input_place`` says how many
+    batches were ``queued`` when this one was ready (0: the loop is
+    starved; ``depth``: the loader is ahead).
 
     ``TPUCFN_INPUT_DEVICE_SHARDED=1`` opts into the device-layout
     placement (ISSUE 18 satellite): served rows go to their devices as
@@ -473,6 +599,9 @@ def prefetch_to_mesh(
                     nbytes = sum(x.nbytes for x in
                                  jax.tree_util.tree_leaves(host_batch))
                     s["bytes"] = nbytes
+                    pieces = _take_pieces()
+                    if pieces is not None:
+                        s["pieces"] = pieces
                 with tracer.span("input_place", trace_id=step) as s:
                     placed = place(mesh, host_batch, extra_axes)
                     s["bytes"] = nbytes
