@@ -197,6 +197,7 @@ def run_train_loop(trainer, ds, mesh, args, *, items_per_step, extra_axes=(),
         sums, n = {}, 0
         for host_batch in eval_ds.epoch(0):
             m = trainer.eval_step(state, shard_batch(mesh, host_batch, extra_axes))
+            m.pop("counters", None)   # a training step's, not an average's
             for k, v in m.items():
                 sums[k] = sums.get(k, 0.0) + float(v)
             n += 1
@@ -369,6 +370,15 @@ def _train_loop_body(trainer, ds, mesh, args, items_per_step, extra_axes,
                     state, metrics = trainer.step(state, batch)
                     mark.dispatched()  # launched; from here the host waits
                     step = int(state.step)  # blocks -> honest step timing
+                # what the step counted beside its loss (the loss function's
+                # ``counters``, a sparse model's routing): ready on the device
+                # since the wait above, so one small transfer, then one trace
+                # line and a gauge each, and the log's line like the rest
+                if "counters" in metrics:
+                    counters = {k: float(v) for k, v in
+                                jax.device_get(metrics.pop("counters")).items()}
+                    obs.record_step_counters(step, counters)
+                    metrics.update(counters)
                 if hb is not None:
                     hb.update_step(step)  # step-lag signal for the monitor
                 timer.tick()

@@ -60,6 +60,9 @@ CASES = [
     ("s2048_d128_ring_hop_offsets", 2048, 128, 32, 8,
      dict(block_q=512, block_k=256, q_offset=2048, k_offset=0), False),
     ("s2000_d128_padded", 2000, 128, 32, 8, {}, False),
+    # the gated attention of models/hybrid.py: 16 query heads on 2 key heads
+    # of 256, the one head size past the 128 lanes
+    ("s8192_d256_gqa16x2_default", 8192, 256, 16, 2, {}, False),
 ]
 
 
@@ -85,3 +88,32 @@ def test_flash_fwd_bwd_compiles_for_v5e(one_chip, s, d, h, hkv, kwargs,
     text = step.lower(*args).compile().as_text()
     # forward, dq and dk/dv kernels — compiled, not interpreted
     assert text.count("tpu_custom_call") >= 3, text.count("tpu_custom_call")
+
+
+def test_hybrid_layer_ops_compile_for_v5e(one_chip):
+    """The two new ops of models/hybrid.py at the benchmark cell's widths,
+    forward and backward: the chunked delta rule (32 value heads of 128 on
+    16 key heads, 8,192 positions, bfloat16) and the expert layer's grouped
+    product, which the compiler turns into a kernel of its own."""
+    from tpucfn.ops.gated_delta import gated_delta_rule
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    b, s = 1, 8192
+    qk = sds((b, s, 16, 128), jnp.bfloat16)
+    v = sds((b, s, 32, 128), jnp.bfloat16)
+    gb = sds((b, s, 32), jnp.float32)
+    delta = jax.jit(jax.grad(
+        lambda q, k, v, g, beta: jnp.sum(gated_delta_rule(
+            q, k, v, g, beta).astype(jnp.float32)), argnums=(0, 1, 2, 3, 4)))
+    assert "while" in delta.lower(qk, qk, v, gb, gb).compile().as_text()
+
+    rows, experts, d, f = 8192, 64, 2048, 512
+    grouped = jax.jit(jax.grad(
+        lambda x, w, sizes: jnp.sum(jax.lax.ragged_dot(x, w, sizes).astype(
+            jnp.float32)), argnums=(0, 1)))
+    text = grouped.lower(sds((rows, d), jnp.bfloat16),
+                         sds((experts, d, f), jnp.bfloat16),
+                         sds((experts,), jnp.int32)).compile().as_text()
+    assert "ragged-dot" in text and "tpu_custom_call" in text

@@ -71,6 +71,23 @@ def test_llama_pipeline_composed_example(tmp_path):
     assert "bubble fraction" in r.stdout
 
 
+def test_qwen3_next_moe_example(tmp_path):
+    """The hybrid decoder through run_train_loop: the step's routing
+    counters reach the log and one ``step_metrics`` trace line a step."""
+    import json
+
+    r = _run("qwen3_next_moe.py", tmp_path, "--model", "tiny", "--seq-len", "32",
+             "--batch-size", "16", "--num-examples", "64")
+    _ok(r)
+    assert "moe_dropped=0 " in r.stdout and "moe_rows=" in r.stdout
+    rows = [json.loads(ln) for p in (tmp_path / "trace").glob("trace-*.jsonl")
+            for ln in p.read_text().splitlines()]
+    lines = [x for x in rows if x["name"] == "step_metrics"]
+    assert [x["trace_id"] for x in lines] == [1, 2, 3]
+    assert all(x["attrs"]["moe_dropped"] == 0.0 and x["attrs"]["moe_rows"] > 0
+               for x in lines)
+
+
 def test_sd15_unet_example(tmp_path):
     _ok(_run("sd15_unet.py", tmp_path, "--tiny", "--batch-size", "8",
              "--num-examples", "32"))

@@ -1,0 +1,128 @@
+"""``models/moe.RoutedExperts``: a chip's share of a sparse feed-forward
+layer, against a dense loop over the experts; no token is ever dropped; and
+the shares of all the chips add up to the whole layer."""
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from tpucfn.models.moe import RoutedExperts
+
+E, K, F, D, T = 8, 2, 12, 16, 40
+
+
+def layer(first, count, shared=F):
+    return RoutedExperts(E, K, F, (first, count), shared_dim=shared,
+                         dtype=jnp.float32)
+
+
+def make(first, count, *, skew=True, seed=0):
+    m = layer(first, count)
+    x = jax.random.normal(jax.random.key(seed + 1), (T, D))
+    params = jax.tree.map(lambda a: 10 * a,
+                          m.init(jax.random.key(seed), x)["params"])
+    if skew:   # expert 3 takes most tokens, expert 2 none
+        x = x.at[:, 0].set(1.0)
+        r = params["router"]["kernel"]
+        params["router"]["kernel"] = r.at[0, 3].add(3.0).at[0, 2].add(-100.0)
+    return m, params, x
+
+
+def swiglu(x, wg, wu, wd):
+    return (jax.nn.silu(x @ wg) * (x @ wu)) @ wd
+
+
+def dense_loop(params, x, first, count, shared=True):
+    """Every held expert on every token, the router's renormalised weights
+    as masks."""
+    p = jax.nn.softmax(x @ params["router"]["kernel"], -1)
+    g, idx = jax.lax.top_k(p, K)
+    g = g / g.sum(-1, keepdims=True)
+    ex = params["experts"]
+    out = jnp.zeros_like(x)
+    for e in range(count):
+        w = jnp.sum(jnp.where(idx == first + e, g, 0.0), -1)
+        out = out + w[:, None] * swiglu(
+            x, ex["gate_proj"]["kernel"][e], ex["up_proj"]["kernel"][e],
+            ex["down_proj"]["kernel"][e])
+    if shared:
+        se = params["shared_expert"]
+        out = out + jax.nn.sigmoid(x @ params["shared_expert_gate"]["kernel"]) \
+            * swiglu(x, se["gate_proj"]["kernel"], se["up_proj"]["kernel"],
+                     se["down_proj"]["kernel"])
+    return out
+
+
+@pytest.mark.parametrize("first,count", [(0, 8), (2, 4), (4, 4), (3, 1)])
+def test_values_and_gradients_match_a_dense_loop(first, count):
+    m, params, x = make(first, count)
+    out, stats = m.apply({"params": params}, x)
+    ref = dense_loop(params, x, first, count)
+    assert float(jnp.max(jnp.abs(out - ref))) <= 1e-5 * float(jnp.max(jnp.abs(ref)))
+    # expert 2 is held in three of the cases and receives no token; expert 3
+    # receives most
+    counts = jnp.bincount(jax.lax.top_k(jax.nn.softmax(
+        x @ params["router"]["kernel"], -1), K)[1].reshape(-1), length=E)
+    assert int(counts[2]) == 0 and int(counts[3]) == int(jnp.max(counts))
+    assert float(stats["rows"]) == float(jnp.sum(counts[first:first + count]))
+    assert float(stats["dropped"]) == 0.0
+    f = lambda fn: lambda p, x: jnp.sum(jnp.sin(fn(p, x)))  # noqa: E731
+    got = jax.grad(f(lambda p, x: m.apply({"params": p}, x)[0]), (0, 1))(params, x)
+    want = jax.grad(f(lambda p, x: dense_loop(p, x, first, count)), (0, 1))(params, x)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        # float32; a grouped product sums a group's rows in another order
+        assert float(jnp.max(jnp.abs(a - b))) <= 1e-4 * float(jnp.max(jnp.abs(b))) + 1e-6
+
+
+def test_no_token_is_dropped_at_any_load():
+    """Every assignment on one held expert: the static shapes are sized for
+    it, and the counters say so."""
+    m, params, x = make(0, 4, skew=False)
+    params["router"]["kernel"] = jnp.zeros((D, E)).at[0, 1].set(
+        100.0).at[0, 6].set(50.0)
+    x = x.at[:, 0].set(1.0)       # every token: expert 1 first, expert 6 second
+    out, stats = m.apply({"params": params}, x)
+    assert float(stats["rows"]) == T            # one of each token's two
+    assert float(stats["dropped"]) == 0.0
+    assert float(stats["load_max_over_mean"]) == pytest.approx(4.0)
+    ref = dense_loop(params, x, 0, 4)
+    assert float(jnp.max(jnp.abs(out - ref))) <= 1e-5 * float(jnp.max(jnp.abs(ref)))
+    # and a share that holds none of the chosen experts gives the shared part
+    m2 = layer(2, 3)
+    p2 = dict(params, experts=jax.tree.map(lambda a: a[:3], params["experts"]))
+    out2, stats2 = m2.apply({"params": p2}, x)
+    assert float(stats2["rows"]) == 0.0 and float(stats2["dropped"]) == 0.0
+    assert bool(jnp.all(jnp.isfinite(out2)))
+
+
+def test_the_shares_add_up_to_the_whole_layer():
+    """Eight chips hold one expert each of a small layer.  Their routed parts
+    summed, with the shared expert (which every chip computes alike) counted
+    once, are what the uncut reference gives for the whole layer."""
+    from benchmark.reference import qwen3_next as ref
+    from benchmark.reference.numerics import Numerics
+
+    _, params, x = make(0, E, skew=False, seed=3)
+    model = {"num_experts_per_tok": K}
+    whole = ref.sparse_ffn(model, Numerics(), x, params)   # all 8 held: uncut
+    shared = dense_loop(params, x, 0, 0)                   # the shared part alone
+    total = shared
+    for chip in range(E):
+        mine = dict(params, experts=jax.tree.map(
+            lambda a: a[chip:chip + 1], params["experts"]))
+        part, stats = layer(chip, 1).apply({"params": mine}, x)
+        assert float(stats["dropped"]) == 0.0
+        total = total + (part - shared)
+        # the reference given the same share agrees with the program's part
+        theirs = ref.sparse_ffn(model, Numerics(), x, mine, first=chip)
+        assert float(jnp.max(jnp.abs(part - theirs))) <= 1e-5 * float(
+            jnp.max(jnp.abs(theirs)))
+    assert float(jnp.max(jnp.abs(total - whole))) <= 1e-5 * float(
+        jnp.max(jnp.abs(whole)))
+
+
+def test_what_it_holds_has_to_lie_inside_the_routers_width():
+    x = jnp.zeros((4, D))
+    for held in ((6, 4), (-1, 2), (0, 0)):
+        with pytest.raises(ValueError):
+            layer(*held).init(jax.random.key(0), x)

@@ -369,6 +369,157 @@ class MoEMLP(nn.Module):
         return fn(router_logits, xt, wg, wu, wd)
 
 
+class KernelParam(nn.Module):
+    """One ``kernel`` leaf under the module's name, so that a layer which
+    multiplies by hand keeps the tree the dense layers have."""
+
+    shape: tuple[int, ...]
+    param_dtype: Any = jnp.float32
+    init: Any = nn.initializers.normal(0.02)
+
+    @nn.compact
+    def __call__(self):
+        return self.param("kernel", self.init, self.shape, self.param_dtype)
+
+
+class _ExpertKernels(nn.Module):
+    count: int
+    dim: int
+    ffn_dim: int
+    param_dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self):
+        up = (self.count, self.dim, self.ffn_dim)
+        down = (self.count, self.ffn_dim, self.dim)
+        return (KernelParam(up, self.param_dtype, name="gate_proj")(),
+                KernelParam(up, self.param_dtype, name="up_proj")(),
+                KernelParam(down, self.param_dtype, name="down_proj")())
+
+
+class RoutedExperts(nn.Module):
+    """A chip's share of a sparse feed-forward layer that keeps every token.
+
+    The layer is told which experts it holds: ``held = (first, count)`` of
+    the ``n_experts`` the router scores.  The router keeps its full width
+    and its ``top_k`` a token (softmax in float32 over all experts, the
+    chosen weights renormalised to sum 1); the ``T x top_k`` assignments are
+    sorted by expert, those that fall on held experts first, and three
+    grouped matrix products (``jax.lax.ragged_dot``) compute the SwiGLU
+    experts on exactly those rows.  The result is the held experts' part of
+    the layer's sum: what the other experts would add is another chip's
+    part, and no exchange is made here.  There is no capacity and no dropped
+    token: shapes are static and cover the case in which every assignment
+    falls here, a block of rows at a time, blocks past the last held row
+    skipped.  With ``shared_dim`` the shared expert and its sigmoid gate
+    are computed whole, as on every chip of the deployment.
+
+    Returns ``(out, stats)``; ``stats`` holds float32 scalars: ``rows``
+    (assignments that fell on held experts), ``load_max_over_mean`` (the
+    largest held expert's rows over the mean) and ``dropped`` (assignments
+    on held experts less the rows the grouped products of the blocks that
+    ran were given: 0 while every block that holds a row runs).
+    """
+
+    n_experts: int
+    top_k: int
+    ffn_dim: int
+    held: tuple[int, int]
+    shared_dim: int = 0
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        first, count = self.held
+        if not (0 <= first and count > 0 and first + count <= self.n_experts):
+            raise ValueError(f"held={self.held} of {self.n_experts} experts")
+        k = self.top_k
+        lead, d = x.shape[:-1], x.shape[-1]
+        xt = x.reshape(-1, d)
+        t = xt.shape[0]
+        router = KernelParam((d, self.n_experts), self.param_dtype, name="router")()
+        wg, wu, wd = _ExpertKernels(count, d, self.ffn_dim, self.param_dtype,
+                                    name="experts")()
+
+        # a rounded logit moves a token's last expert: float32, all passes
+        logits = jnp.matmul(xt.astype(jnp.float32), router.astype(jnp.float32),
+                            precision=lax.Precision.HIGHEST)
+        gates, chosen = lax.top_k(jax.nn.softmax(logits, axis=-1), k)
+        gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+
+        # assignments flat, slot-major (index j * t + token), sorted by
+        # expert with those on experts held elsewhere last
+        local = chosen.T.reshape(-1) - first
+        mine = (local >= 0) & (local < count)
+        key = jnp.where(mine, local, count)
+        order = jnp.argsort(key, stable=True)
+        sizes = jnp.bincount(key, length=count + 1)[:count].astype(jnp.int32)
+        ends = jnp.cumsum(sizes)
+        rows = ends[-1]
+        weight = gates.T.reshape(-1)
+        wg, wu, wd = (w.astype(self.dtype) for w in (wg, wu, wd))
+
+        # The sorted rows are worked off in blocks of twice the share a
+        # uniform router sends here; a block past the last held row is
+        # skipped.  Every assignment lies in some block, so none is dropped
+        # at any load; memory is a block's, and time follows the rows that
+        # came, a block at a time.
+        n = t * k
+        block = min(n, -(-2 * n * count // self.n_experts))
+        blocks = -(-n // block)
+        order = jnp.pad(order, (0, blocks * block - n))
+
+        def part(lo):
+            idx = lax.dynamic_slice(order, (lo,), (block,))
+            token = idx % t
+            # rows past the groups belong to no expert here, and what a
+            # grouped product leaves in them is not defined: zeros in, zeros
+            # out, so that neither pass carries anything of them
+            taken = (lo + jnp.arange(block) < rows)[:, None]
+            here = jnp.clip(ends, lo, lo + block) - jnp.clip(
+                ends - sizes, lo, lo + block)
+            xs = jnp.where(taken, xt[token], 0).astype(self.dtype)
+            h = nn.silu(lax.ragged_dot(xs, wg, here)) * lax.ragged_dot(xs, wu, here)
+            y = lax.ragged_dot(jnp.where(taken, h, 0), wd, here)
+            y = jnp.where(taken, y, 0) * weight[idx, None].astype(self.dtype)
+            return (jnp.zeros((t, d), jnp.float32).at[token].add(
+                y.astype(jnp.float32)), jnp.sum(here))
+
+        @jax.checkpoint        # around the branch: a block keeps its offset
+        def maybe(lo):
+            return lax.cond(
+                lo < rows, part,
+                lambda _: (jnp.zeros((t, d), jnp.float32), jnp.int32(0)), lo)
+
+        def step(carry, lo):
+            y, took = maybe(lo)
+            return (carry[0] + y, carry[1] + took), None
+
+        (out, took), _ = lax.scan(
+            step, (jnp.zeros((t, d), jnp.float32), jnp.int32(0)),
+            jnp.arange(blocks) * block)
+
+        if self.shared_dim:
+            from tpucfn.models.layers import SwiGLUMLP
+
+            shared = SwiGLUMLP(self.shared_dim, self.dtype, self.param_dtype,
+                               name="shared_expert")(xt)
+            gate = nn.sigmoid(nn.DenseGeneral(
+                1, use_bias=False, dtype=self.dtype,
+                param_dtype=self.param_dtype, name="shared_expert_gate")(xt))
+            out = out + (gate * shared).astype(jnp.float32)
+
+        sizes_f = sizes.astype(jnp.float32)
+        stats = {
+            "rows": rows.astype(jnp.float32),
+            "load_max_over_mean": jnp.max(sizes_f) / jnp.maximum(
+                jnp.mean(sizes_f), 1e-9),
+            "dropped": (jnp.sum(mine) - took).astype(jnp.float32),
+        }
+        return out.reshape(*lead, d).astype(self.dtype), stats
+
+
 def collect_moe_aux(variables: dict) -> jax.Array:
     """Sum all sown MoE aux losses (0.0 if the model has no MoE layers)."""
     losses = variables.get("losses", {})

@@ -120,6 +120,9 @@ class ServeEngine:
     def __init__(self, model: Any, params: Any, *, max_batch: int,
                  cache_len: int, rng: jax.Array | None = None,
                  prefill_width: int = 4):
+        from tpucfn.models.hybrid import refuse_recurrent_model
+
+        refuse_recurrent_model(model, "serve")
         self.model = model
         self.params = params
         self.max_batch = max_batch
@@ -177,8 +180,10 @@ class ServeEngine:
         weights once, host-side — serving then runs the plain decoder,
         no per-step merge cost."""
         from tpucfn.kernels.auto import serve_decode_attention_fn
+        from tpucfn.models.hybrid import refuse_recurrent_model
         from tpucfn.models.llama import Llama
 
+        refuse_recurrent_model(cfg, "serve")
         cache_len = cache_len or cfg.max_seq
         dcfg = dataclasses.replace(cfg, max_seq=cache_len)
         if lora_adapters is not None:

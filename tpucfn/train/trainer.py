@@ -401,6 +401,7 @@ class TrainerObs:
         r = self.registry = (registry if registry is not None
                              else default_registry())
         self.tracer = tracer if tracer is not None else Tracer(None)
+        self.prefix = prefix
         self.ledger = ledger if ledger is not None else GoodputLedger(None)
         self.clock = clock
         self.flight = flight
@@ -568,6 +569,19 @@ class TrainerObs:
             if step is not None:
                 self.last_step.set(step)
         return _span()
+
+    def record_step_counters(self, step: int | None,
+                             counters: dict[str, float]) -> None:
+        """What a step counted beside its loss (the ``counters`` of its
+        metrics): one ``step_metrics`` trace line a step with each counter
+        as an attribute, and a gauge ``{prefix}_{name}`` each.  The loop
+        calls it after the step's wait, with the values on the host."""
+        self.tracer.record("step_metrics", start=self.clock(), dur_s=0.0,
+                           trace_id=step, **counters)
+        for name, value in counters.items():     # get-or-create by name
+            self.registry.gauge(
+                f"{self.prefix}_{name}",
+                f"the step's counter {name} (its loss function's)").set(value)
 
     def ckpt(self, step: int | None = None):
         return self._phase("ckpt", self.ckpt_time, step)
