@@ -117,3 +117,26 @@ def test_hybrid_layer_ops_compile_for_v5e(one_chip):
                          sds((experts, d, f), jnp.bfloat16),
                          sds((experts,), jnp.int32)).compile().as_text()
     assert "ragged-dot" in text and "tpu_custom_call" in text
+
+
+def test_gdn_prep_compiles_for_v5e(one_chip):
+    """The delta rule's preparation kernels at the benchmark cell's shapes
+    (2 x 8,192 positions in chunks of 64, 16 key heads serving 32 value heads
+    of 128, bfloat16), forward and backward: compiled, not interpreted."""
+    from tpucfn.kernels.gated_delta import gdn_prep
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    b, hk, rep, n, c, d = 2, 16, 2, 128, 64, 128
+    qk = sds((b, n * c, hk * d), jnp.bfloat16)
+    v = sds((b, n * c, hk * rep * d), jnp.bfloat16)
+    row = sds((b, hk, rep, n, c), jnp.float32)
+
+    def loss(*a):
+        return sum(jnp.sum(o.astype(jnp.float32) ** 2)
+                   for o in gdn_prep(*a, interpret=False))
+
+    step = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4)))
+    text = step.lower(qk, qk, v, row, row).compile().as_text()
+    assert text.count("tpu_custom_call") == 2, text.count("tpu_custom_call")
