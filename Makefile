@@ -1,12 +1,13 @@
 # Build-time verification targets (ISSUE 11 satellite: `tpucfn check
 # --diff` belongs in the builder loop, not the review loop — it costs
-# ~2 s and is jax-free).  `make verify` is the full tier-1 recipe from
-# ROADMAP.md with the static gate in front.
+# ~2 s and is jax-free).  `make verify` is the tier-1 suite as the driver
+# runs it, with the static gate in front.  Speed is measured on the chip
+# by `python -m benchmark.run` (README "Developing"), not by a target here.
 
 # `set -o pipefail` in the tier1 recipe needs bash, not POSIX sh.
 SHELL := /bin/bash
 
-.PHONY: check tier1 verify chip-smoke bench-smoke bench-rl trace-smoke
+.PHONY: check tier1 verify chip-smoke bench-rl
 
 # Static analysis over the files changed vs origin/main (the whole
 # package is still parsed, so cross-module rules keep context).  Falls
@@ -19,12 +20,16 @@ check:
 		python -m tpucfn.cli check; \
 	fi
 
-# Tier-1 test suite (the ROADMAP.md recipe, verbatim semantics).
+# Tier-1 test suite, as the driver runs it after every PR: six workers
+# under 1,470 s (the note under ROADMAP.md's "Tier-1 verify" line).  One
+# process does not finish inside any limit on record; while working, run
+# the tests of what you touch.
 tier1:
 	set -o pipefail; rm -f /tmp/_t1.log; \
-	timeout -k 10 870 env JAX_PLATFORMS=cpu python -m pytest tests/ -q \
+	timeout -k 10 1470 env JAX_PLATFORMS=cpu ALLOW_MULTIPLE_LIBTPU_LOAD=1 \
+		python -m pytest tests/ -q \
 		-m 'not slow' --continue-on-collection-errors \
-		-p no:cacheprovider -p no:xdist -p no:randomly 2>&1 \
+		-p no:cacheprovider -p xdist -n 6 --dist load -p no:randomly 2>&1 \
 		| tee /tmp/_t1.log
 
 verify: check tier1
@@ -33,23 +38,6 @@ verify: check tier1
 # TPU; sent through the chip tool, one command per call).
 chip-smoke:
 	python chip_smoke.py
-
-# Flagship perf drill on the synthetic input-bound workload (ISSUE 18):
-# a real launch fan-out — 1 input host + trainer + compile-artifact
-# server — rc-gated on served-step and warm-TTFS ratios.  CPU-only,
-# ~1 min; `--repeat 3` is the acceptance run.
-bench-smoke:
-	timeout -k 10 600 env JAX_PLATFORMS=cpu \
-		python benches/flagship_bench.py --quick
-
-# Fleet timeline plane (ISSUE 20): launch fan-out (1 input host +
-# trainer), merged Perfetto export — rc-gated on >=95% of remote
-# data_wait spans resolving a cross-host parent link and critical-path
-# plane shares summing to within 10% of step wall.  CPU-only, ~15s;
-# `--repeat 3` is the acceptance run.
-trace-smoke:
-	timeout -k 10 300 env JAX_PLATFORMS=cpu \
-		python benches/trace_smoke.py --quick
 
 # Podracer RL plane (ISSUE 19): co-located act->learn->refresh vs the
 # host-roundtrip reference on the same mesh — rc-gated on the

@@ -12,10 +12,9 @@ involved. Prints one JSON line per phase:
   center-crop → stack), streaming mode, with the decoded-array path for
   comparison.
 
-The third leg — proof that training is NOT input-bound — lives inside
-``bench.py`` (detail.overlap): step time fed by the real
-ShardedDataset+prefetch loader vs the pre-staged batch, on the bench
-hardware itself.
+Whether training is input-bound on the chip is the benchmark's to say
+(``data_wait_share.*`` and ``idle_in_data_wait.*`` in ``BENCHMARK.json``),
+with the real ShardedDataset+prefetch loader feeding the real step.
 
 Usage: python benches/data_bench.py [--examples N] [--image-size S]
 """

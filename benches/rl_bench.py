@@ -24,7 +24,7 @@ Gates (rc 1 on violation):
   (regression alarm for the copy program growing a host bounce or a
   recompile; steady-state is sub-millisecond for the bench policy).
 
-Compile warmup is excluded from every timed window (bench.py's rule):
+Compile warmup is excluded from every timed window:
 each leg's programs run once on their exact shapes before timing.
 
 ``vs_baseline`` is 0.0: the reference repo was a supervised-training

@@ -20,8 +20,8 @@ JSON line out in the standard BENCH row schema:
 
 Compile warmup is excluded from every timed window: each phase's
 buckets (and the copy_prefix program) are compiled by throwaway servers
-on the SAME engine first, mirroring bench.py's warmup-exclusion rule
-for training steps.
+on the SAME engine first, as the training benchmark warms up every
+shape before its window opens.
 
 ``vs_baseline`` is 0.0: the reference repo was a training-only harness
 with no serving number to compare against (detail.baseline_note says
@@ -278,8 +278,7 @@ def run_spec(args) -> int:
 
     def leg(engine, fresh_controller=None):
         # compile warmup on the engine pair (buckets, decode, verify
-        # widths, rollback), excluded from the timed pass — bench.py's
-        # warmup-exclusion rule.
+        # widths, rollback), excluded from the timed pass.
         warm = Server(engine, num_blocks=args.num_blocks,
                       block_size=args.block_size, prefix_cache=False,
                       max_prefill_batch=args.max_prefill_batch)

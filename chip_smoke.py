@@ -30,7 +30,6 @@ import math
 import os
 import shutil
 import sys
-import threading
 import time
 
 ROOT = os.path.dirname(os.path.abspath(__file__))
@@ -143,8 +142,8 @@ def phase_kernel(rehearse: bool) -> dict:
 # ----------------------------------------------------------- train_llama
 
 def llama_trainer(cfg, mesh, seq: int):
-    """The decoder trainer as bench.py's llama worker builds it: Trainer +
-    sharding_rules + chunked CE + Adafactor, default (full) remat."""
+    """The decoder trainer of the one-chip job: Trainer + sharding_rules +
+    chunked CE + Adafactor, default (full) remat."""
     import jax.numpy as jnp
     import optax
 
@@ -345,12 +344,6 @@ def phase_train_example(rehearse: bool) -> dict:
         rc = example.main()
     finally:
         sys.argv = old_argv
-    # run_train_loop arms the live-MFU gauge from a daemon thread that
-    # AOT-compiles the step; let it end before the interpreter does.
-    for t in threading.enumerate():
-        if t.name == "mfu-cost-analysis":
-            t.join(300)
-            gate(not t.is_alive(), "mfu-cost-analysis thread still running")
     gate(rc == 0, f"examples/imagenet_resnet50.py main() returned {rc}")
 
     with open(os.path.join(run_dir, "logs", "train-host000.jsonl")) as f:

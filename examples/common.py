@@ -126,8 +126,12 @@ def run_train_loop(trainer, ds, mesh, args, *, items_per_step, extra_axes=(),
     top-1 north star (BASELINE.md)."""
     import jax
 
-    from tpucfn.ckpt import CheckpointManager
-    from tpucfn.data import prefetch_to_mesh
+    # Not used in this function, and not to be moved: importing orbax here,
+    # before the planes below start their threads, takes 12 s on a TPU host
+    # (google.api_core's version check walks site-packages' metadata several
+    # times) and 22-26 s from _train_loop_body (PERF.md, Findings, PR 29).
+    from tpucfn.ckpt import CheckpointManager  # noqa: F401
+    from tpucfn.data import prefetch_to_mesh  # noqa: F401
     from tpucfn.obs import (
         MetricLogger,
         StepTimer,
@@ -387,34 +391,6 @@ def _train_loop_body(trainer, ds, mesh, args, items_per_step, extra_axes,
                     logger.log(step, {"time_to_first_step": round(
                         time.perf_counter() - t_start, 2)})
                     t_start = None
-                    # Live MFU (ISSUE 5): cost-analysis FLOPs captured
-                    # ONCE, right after the first step.  AOT
-                    # lower/compile does NOT share the jit call's
-                    # executable cache and can recompile the whole
-                    # program, so capture off-thread — the train loop
-                    # never blocks, the gauge arms when analysis lands.
-                    # lower() only needs avals: hand the thread an
-                    # abstract batch so the closure doesn't pin the real
-                    # step-1 device buffers in HBM for the whole compile.
-                    import threading
-
-                    from tpucfn.obs.goodput import device_peak_flops
-
-                    peak = device_peak_flops(jax.devices()[0].device_kind)
-                    # No peak entry (CPU fallback, unknown device) means
-                    # the gauge can never arm — skip the duplicate AOT
-                    # compile entirely rather than burn a core on it.
-                    if peak is not None:
-                        abstract_batch = jax.tree_util.tree_map(
-                            lambda x: jax.ShapeDtypeStruct(
-                                x.shape, x.dtype,
-                                sharding=getattr(x, "sharding", None)),
-                            batch)
-                        threading.Thread(
-                            target=lambda: obs.set_model_flops(
-                                trainer.step_cost_flops(abstract_batch),
-                                peak),
-                            daemon=True, name="mfu-cost-analysis").start()
                 if step % args.log_every == 0 or step == halt:
                     logger.log(step, {**{k: float(v) for k, v in metrics.items()},
                                       "step_time": timer._last or 0.0,
@@ -451,6 +427,9 @@ def _train_loop_body(trainer, ds, mesh, args, items_per_step, extra_axes,
                     # for) closes through its own object, not the
                     # generator protocol.
                     pass
+            # ... and the prefetcher itself: its thread sits in a put with
+            # the batches it placed ahead still on the devices.
+            batches.close()
         run_eval(state, int(state.step))
         t0_ckpt = time.monotonic()
         if ckpt.save(int(state.step), state, force=True):

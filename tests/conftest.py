@@ -31,51 +31,6 @@ jax.config.update("jax_platforms", "cpu")
 
 import pytest  # noqa: E402
 
-# ---------------------------------------------------------------------------
-# Tier-1 duration artifact (ISSUE 5 satellite): the 25 slowest test phases
-# land in runs/tier1_durations.txt — the equivalent of `--durations=25`
-# captured to a file, so PR-over-PR runtime drift toward the 870s tier-1
-# budget is visible in the repo without re-running anything.  Only
-# UNFILTERED runs (no -k / --deselect / explicit paths) rewrite it: the
-# artifact is committed, and a `pytest -k foo` run's totals would read
-# as full-suite drift numbers.
-# Best-effort by design: writing a debug artifact must never fail a test run.
-# ---------------------------------------------------------------------------
-
-_PHASE_DURATIONS: list[tuple[float, str, str]] = []
-_PHASE_TOTAL_S = [0.0]  # ALL phases, including the ones filtered below
-_TESTS_RUN: set[str] = set()
-
-
-def pytest_runtest_logreport(report):
-    _PHASE_TOTAL_S[0] += report.duration
-    _TESTS_RUN.add(report.nodeid)
-    if report.duration >= 0.005:  # keep the accumulator small
-        _PHASE_DURATIONS.append((report.duration, report.when, report.nodeid))
-
-
-def pytest_terminal_summary(terminalreporter, exitstatus, config):
-    try:
-        opt = config.option
-        if (getattr(opt, "keyword", "") or getattr(opt, "deselect", None)
-                or getattr(opt, "file_or_dir", [])
-                # tier-1 itself is `-m 'not slow'`; any other markexpr
-                # (e.g. `-m slow`) is a selective run
-                or getattr(opt, "markexpr", "") not in ("", "not slow")):
-            return  # filtered/selective run: keep the full-suite numbers
-        out = Path(__file__).resolve().parent.parent / "runs"
-        out.mkdir(exist_ok=True)
-        top = sorted(_PHASE_DURATIONS, reverse=True)[:25]
-        argv = " ".join(config.invocation_params.args) or "<all>"
-        lines = [f"# pytest args: {argv}",
-                 f"# {len(_TESTS_RUN)} tests ran; slowest 25 phases (of "
-                 f"{len(_PHASE_DURATIONS)} >=5ms; sum of all phases "
-                 f"{_PHASE_TOTAL_S[0]:.1f}s; tier-1 budget 870s)"]
-        lines += [f"{d:8.2f}s {when:8s} {nodeid}" for d, when, nodeid in top]
-        (out / "tier1_durations.txt").write_text("\n".join(lines) + "\n")
-    except OSError:
-        pass
-
 
 @pytest.fixture(scope="session", autouse=True)
 def _assert_fake_devices():

@@ -1,84 +1,57 @@
-"""bench.py is one process on whatever device JAX gives it: it prints
-exactly one parseable JSON row that names the device, measures the CPU
-only when ``JAX_PLATFORMS=cpu`` asks for it by name, and fails otherwise
-when there is no chip."""
+"""The tools under ``benches/`` that stay: each prints parseable rows with
+its documented keys.  Counts and schemas are pinned here; a time or a ratio of
+times read on a shared CPU is not (speed is ``benchmark/``'s, on the chip)."""
 
 import json
+import math
 import os
 import subprocess
 import sys
 from pathlib import Path
 
+import pytest
+
 REPO = Path(__file__).resolve().parent.parent
 
+# The builders' on-chip tools at the smallest sizes their arguments allow: a
+# rename in ops/gated_delta.py or kernels/flash_attention.py is found here and
+# not on chip time.  (rows, keys every row carries, arguments)
+KERNEL_TOOLS = {
+    "gdn_bench.py": (
+        4, ("path", "pass", "pallas", "median_ms", "batch", "seq",
+            "key_heads", "value_heads", "head_dim", "chunk", "device"),
+        ["--batch", "1", "--seq", "64", "--key-heads", "1", "--value-heads",
+         "2", "--head-dim", "16", "--chunk", "16", "--iters", "1"]),
+    "flash_bench.py": (
+        1, ("s", "heads", "kv_heads", "d", "fwd_flash_ms", "fwd_dense_ms",
+            "bwd_flash_ms", "bwd_dense_ms", "speedup_fwd", "speedup_bwd"),
+        ["--seqs", "128", "--batch", "1", "--heads", "2", "--kv-heads", "1",
+         "--head-dim", "32", "--iters", "1"]),
+}
 
-def _run_bench(extra_env=None, timeout=1200):
+
+@pytest.mark.parametrize("tool", sorted(KERNEL_TOOLS))
+def test_kernel_tool_prints_its_documented_rows(tool):
+    n_rows, keys, argv = KERNEL_TOOLS[tool]
     env = dict(os.environ)
-    env["PYTHONPATH"] = str(REPO) + os.pathsep + env.get("PYTHONPATH", "")
-    # the CPU by name, at the tiny preset (conftest's XLA_FLAGS give it
-    # 8 fake devices)
-    env.update({"JAX_PLATFORMS": "cpu", "TPUCFN_BENCH_PRESET": "tiny"})
-    for k, v in (extra_env or {}).items():
-        if v is None:
-            env.pop(k, None)
-        else:
-            env[k] = v
-    return subprocess.run([sys.executable, str(REPO / "bench.py")],
-                          env=env, capture_output=True, text=True,
-                          timeout=timeout)
-
-
-def test_bench_emits_contract_json_line():
-    r = _run_bench()
+    env["JAX_PLATFORMS"] = "cpu"
+    r = subprocess.run([sys.executable, str(REPO / "benches" / tool), *argv],
+                       env=env, capture_output=True, text=True, timeout=600)
     assert r.returncode == 0, f"stderr:\n{r.stderr[-2000:]}"
-    line = r.stdout.strip().splitlines()[-1]
-    rec = json.loads(line)
-    for key in ("metric", "value", "unit", "vs_baseline", "detail"):
-        assert key in rec, rec
-    assert rec["value"] > 0
-    d = rec["detail"]
-    assert d["platform"] == "cpu" and d["device_kind"]
-    assert "mean_step_s" in d and "time_to_first_step_s" in d
-    # MFU machinery ran (flops measured; mfu itself is None off-TPU)
-    assert d["flops_per_dev_step_g"] is not None
-    assert d["mfu"] is None
-    # ISSUE 18 first-class columns: warm TTFS, the served input leg, and
-    # the goodput bucket decomposition ride every emitted row.
-    assert isinstance(d["warm_time_to_first_step_s"], (int, float))
-    assert d["warm_time_to_first_step_s"] > 0
-    ov = d["overlap"]
-    for k in ("loader_step_s", "served_step_s"):
-        assert isinstance(ov[k], (int, float)) and ov[k] > 0, (k, ov)
-    assert ov["served_source"] in ("in-process", "input-hosts"), ov
-    gp = d["goodput"]
-    assert gp["wall_s"] > 0
-    assert 0.0 <= gp["goodput_ratio"] <= 1.0
-    shares = gp["shares"]
-    for k in ("step", "compile", "data_wait", "idle"):
-        assert k in shares, shares
-    assert all(0.0 <= v <= 1.0 for v in shares.values()), shares
-    # the decomposition covers the wall: shares (idle filler included)
-    # sum to 1 within rounding noise
-    assert abs(sum(shares.values()) - 1.0) < 0.02, shares
-
-
-def test_bench_llama_preset():
-    r = _run_bench({"TPUCFN_BENCH_MODEL": "llama"})
-    assert r.returncode == 0, f"stderr:\n{r.stderr[-2000:]}"
-    rec = json.loads(r.stdout.strip().splitlines()[-1])
-    assert rec["metric"] == "tiny_llama_train_tokens_per_sec_per_chip"
-    assert rec["unit"] == "tokens/sec/chip"
-    assert rec["value"] > 0
-
-
-def test_bench_without_a_chip_fails_and_prints_no_row():
-    # No JAX_PLATFORMS: jax finds no TPU here and would fall back to the
-    # CPU; a measurement path that finds no chip must fail instead.
-    r = _run_bench({"JAX_PLATFORMS": None, "TPU_LOG_DIR": "disabled"},
-                   timeout=300)
-    assert r.returncode != 0, r.stdout[-2000:]
-    assert r.stdout.strip() == "", r.stdout[-2000:]
-    assert "no TPU" in r.stderr
+    rows = [json.loads(line) for line in r.stdout.strip().splitlines()]
+    assert len(rows) == n_rows, rows
+    for row in rows:
+        assert set(keys) <= set(row), (sorted(row), keys)
+        for k, v in row.items():
+            if k.endswith("_ms"):
+                assert isinstance(v, float) and math.isfinite(v) and v > 0, row
+    if tool == "gdn_bench.py":
+        assert [(row["path"], row["pass"]) for row in rows] == [
+            ("jnp", "fwd"), ("jnp", "fwd_bwd"),
+            ("kernel", "fwd"), ("kernel", "fwd_bwd")]
+        # off a TPU both paths are the jnp preparation, and the row says so
+        assert not any(row["pallas"] for row in rows)
+        assert {row["device"] for row in rows} == {"cpu"}
 
 
 def test_serve_bench_row_carries_prefix_and_batch_stats():
@@ -87,8 +60,6 @@ def test_serve_bench_row_carries_prefix_and_batch_stats():
     cache-off/on comparison) with sane values — a row missing them fails
     here instead of producing unreadable trajectory files.  Small run on
     CPU; the count-based numbers are deterministic."""
-    import math
-
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = str(REPO) + os.pathsep + env.get("PYTHONPATH", "")
@@ -140,8 +111,6 @@ def test_serve_bench_availability_row_schema():
     detail carries availability (accepted requests completing within
     deadline across a mid-trace replica kill), the retry success rate,
     and the hedge win rate.  Small run on CPU."""
-    import pytest
-
     pytest.importorskip("jax")
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
@@ -202,10 +171,12 @@ def test_data_bench_service_row_schema():
 
 def test_serve_bench_spec_row_schema():
     """ISSUE 14 CI satellite: `serve_bench --spec` emits the
-    speculative-decoding BENCH row and rc-gates the two acceptance
-    numbers — >= 1.5x tokens_per_target_step on the high-acceptance
-    self-draft leg, worst-case TPOT within 1.3x of plain on the
-    adversarial leg — with every leg's output bit-identical."""
+    speculative-decoding BENCH row with every leg's output bit-identical
+    and >= 1.5x tokens_per_target_step on the high-acceptance self-draft
+    leg: counts, the same on any machine.  The script also gates its exit
+    code on worst-case TPOT within 1.3x of plain, a ratio of two wall times:
+    that gate is for a quiet machine, so here the number must be there and
+    finite, and an exit code of 1 is allowed when that gate alone is false."""
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = str(REPO) + os.pathsep + env.get("PYTHONPATH", "")
@@ -213,7 +184,7 @@ def test_serve_bench_spec_row_schema():
         [sys.executable, str(REPO / "benches" / "serve_bench.py"),
          "--spec", "--cache-len", "192", "--prompt-len-hi", "64"],
         env=env, capture_output=True, text=True, timeout=1200)
-    assert r.returncode == 0, f"stderr:\n{r.stderr[-2000:]}"
+    assert r.returncode in (0, 1), f"stderr:\n{r.stderr[-2000:]}"
     rec = json.loads(r.stdout.strip().splitlines()[-1])
     assert rec["metric"] == "serve_spec_tokens_per_target_step"
     d = rec["detail"]
@@ -224,9 +195,11 @@ def test_serve_bench_spec_row_schema():
     gates = d["gates"]
     assert gates["bit_identical"] is True
     assert gates["tokens_per_target_step_gate"] is True
-    assert gates["worst_case_tpot_gate"] is True
     assert gates["tokens_per_target_step_gain"] >= 1.5
-    assert gates["worst_case_tpot_ratio"] <= 1.3
+    ratio = gates["worst_case_tpot_ratio"]
+    assert isinstance(ratio, float) and math.isfinite(ratio) and ratio > 0
+    # the exit code says what the gates say, the timing gate among them
+    assert (r.returncode == 0) == gates["worst_case_tpot_gate"], r.stderr[-2000:]
     # The high-acceptance leg really speculated; the adversarial leg's
     # controller really reached its floor (off).
     assert d["spec_high_acceptance"]["acceptance_rate"] == 1.0

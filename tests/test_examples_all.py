@@ -3,6 +3,7 @@
 (Config 1, CIFAR-10, has its own deeper test in test_example_cifar10.py.)
 """
 
+import json
 import os
 import subprocess
 import sys
@@ -74,8 +75,6 @@ def test_llama_pipeline_composed_example(tmp_path):
 def test_qwen3_next_moe_example(tmp_path):
     """The hybrid decoder through run_train_loop: the step's routing
     counters reach the log and one ``step_metrics`` trace line a step."""
-    import json
-
     r = _run("qwen3_next_moe.py", tmp_path, "--model", "tiny", "--seq-len", "32",
              "--batch-size", "16", "--num-examples", "64")
     _ok(r)
@@ -172,3 +171,62 @@ def test_imagenet_multiprocess_loader_example(tmp_path):
     _ok(_run("imagenet_resnet50.py", tmp_path, "--network", "resnet18",
              "--image-size", "64", "--batch-size", "8", "--augment",
              "--loader-workers", "-2", "--num-examples", "64"))
+
+
+# ---- what only the process sees of run_train_loop ------------------------
+# (tests/loop_probe_worker.py runs an example's main() and reports)
+
+PROBED = {
+    "resnet": ("imagenet_resnet50.py", [
+        "--network", "resnet18", "--image-size", "32", "--batch-size", "8",
+        "--num-examples", "32", "--num-classes", "10"]),
+    "decoder": ("llama3_8b_fsdp.py", [
+        "--model", "tiny", "--seq-len", "32", "--batch-size", "8",
+        "--num-examples", "32"]),
+}
+_probes: dict = {}
+
+
+def _probe(name, tmp_path_factory):
+    if name not in _probes:
+        script, extra = PROBED[name]
+        d = tmp_path_factory.mktemp(f"probe-{name}")
+        env = dict(os.environ)
+        env["JAX_PLATFORMS"] = "cpu"
+        env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
+        env["PYTHONPATH"] = str(REPO) + os.pathsep + env.get("PYTHONPATH", "")
+        # the heartbeat's thread is one of the loop's only under a coordinator
+        env["TPUCFN_FT_DIR"] = str(d / "ft")
+        env["TPUCFN_FT_HEARTBEAT_S"] = "0.2"
+        r = subprocess.run(
+            [sys.executable, str(REPO / "tests" / "loop_probe_worker.py"),
+             str(REPO / "examples" / script), "--run-dir", str(d / "run"),
+             "--steps", "4", "--ckpt-every", "100", "--log-every", "1", *extra],
+            env=env, capture_output=True, text=True, timeout=600)
+        assert r.returncode == 0, f"stdout:\n{r.stdout}\nstderr:\n{r.stderr[-3000:]}"
+        assert "final: step=4" in r.stdout
+        _probes[name] = (json.loads(r.stdout.strip().splitlines()[-1]), d)
+    return _probes[name]
+
+
+@pytest.mark.parametrize("name", sorted(PROBED))
+def test_the_loop_compiles_its_step_once(name, tmp_path_factory):
+    """Four steps through run_train_loop lower and compile ``_step_fn`` once:
+    no retrace at step 2 (a state whose avals or shardings moved), no second
+    lowering from anywhere (a thread that wants the step's cost analysis)."""
+    seen, _ = _probe(name, tmp_path_factory)
+    msgs = seen["step_fn_messages"]
+    assert sum(m.startswith("Compiling jit(_step_fn)") for m in msgs) == 1, msgs
+    assert sum(m.startswith("Finished XLA compilation of jit(_step_fn)")
+               for m in msgs) == 1, msgs
+
+
+def test_the_loop_leaves_no_thread_behind(tmp_path_factory):
+    """Once run_train_loop has returned, every thread it started has ended:
+    the prefetcher (left in its put it holds three batches on the devices),
+    the heartbeat, the obs endpoint, whatever a later PR starts.  (At these
+    sizes no batch is large enough to start the process's assembly pool,
+    which is the process's and stays.)"""
+    seen, d = _probe("resnet", tmp_path_factory)
+    assert list((d / "ft").glob("hb-host*.jsonl")), "no heartbeat was written"
+    assert seen["threads_left"] == []

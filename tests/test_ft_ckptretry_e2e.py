@@ -7,7 +7,7 @@ blacklists the bad step and relaunches to resume from the PREVIOUS
 finalized step, finishing with the correct trajectory.
 
 Own slow-marked file on purpose: stacked multi-second drills flake on
-this container (see runs/tier1_durations.txt discipline).
+this container.
 """
 
 import json

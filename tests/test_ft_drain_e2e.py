@@ -6,7 +6,7 @@ report, ``planned=true`` on the incident row, and zero restart budget
 consumed.
 
 Own slow-marked file on purpose: stacked multi-second drills flake on
-this container (see runs/tier1_durations.txt discipline).
+this container.
 """
 
 import json

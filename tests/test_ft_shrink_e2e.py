@@ -7,7 +7,7 @@ force-saved step and finishes, its loss curve bit-identical to the
 deterministic trajectory.
 
 Own slow-marked file on purpose: stacked multi-second drills flake on
-this container (see runs/tier1_durations.txt discipline).
+this container.
 """
 
 import json

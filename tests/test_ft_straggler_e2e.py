@@ -7,8 +7,7 @@ clean.
 
 Stdlib-only workers (no jax import) so the drill measures the
 eviction plane, not interpreter+XLA startup.  Own slow-marked file on
-purpose: stacked multi-second drills flake on this container (see
-runs/tier1_durations.txt discipline).
+purpose: stacked multi-second drills flake on this container.
 """
 
 import json

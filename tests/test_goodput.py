@@ -1,7 +1,7 @@
 """Goodput accounting (ISSUE 5 tentpole): the per-host ledger
 decomposes wall clock into buckets that SUM to wall time, re-run steps
 land in lost_work, inter-window gaps in restart_downtime — and the
-trainer's live efficiency gauges (train_mfu / train_step_time_s /
+trainer's live efficiency gauges (train_step_time_s /
 train_goodput_ratio) are pinned with a fake clock, no TPU involved."""
 
 import json
@@ -12,8 +12,6 @@ import pytest
 from tpucfn.obs import MetricRegistry
 from tpucfn.obs.goodput import (
     GoodputLedger,
-    cost_analysis_flops,
-    device_peak_flops,
     goodput_report,
     host_goodput,
     host_id_from_path,
@@ -328,7 +326,7 @@ def test_host_id_from_path():
 
 # ---- live efficiency gauges (acceptance: fake clock, no TPU) -------------
 
-def test_trainer_obs_exports_live_mfu_on_metrics_endpoint(tmp_path):
+def test_trainer_obs_exports_live_gauges_on_metrics_endpoint(tmp_path):
     from tpucfn.obs.server import ObsServer
     from tpucfn.train.trainer import TrainerObs
 
@@ -336,15 +334,12 @@ def test_trainer_obs_exports_live_mfu_on_metrics_endpoint(tmp_path):
     reg = MetricRegistry(labels={"host": "0", "role": "trainer"})
     led = GoodputLedger(tmp_path, 0, clock=clk)
     obs = TrainerObs(reg, ledger=led, clock=clk)
-    # 2 TFLOP per device-step at 200 TFLOP/s peak, 0.1 s steps -> MFU 0.1
-    obs.set_model_flops(2.0e12, 200e12)
     for i in range(1, 4):
         with obs.data_wait(i):
             clk.advance(0.05)
         with obs.step(i):
             clk.advance(0.1)
     m = reg.varz()["metrics"]
-    assert m["train_mfu"] == pytest.approx(2.0e12 / 0.1 / 200e12)
     assert m["train_step_time_s"] == pytest.approx(0.1)
     # productive 0.2 (first step is compile) over 0.45 wall
     assert m["train_goodput_ratio"] == pytest.approx(0.2 / 0.45)
@@ -354,9 +349,11 @@ def test_trainer_obs_exports_live_mfu_on_metrics_endpoint(tmp_path):
                                       timeout=5).read().decode()
     finally:
         srv.close()
-    for name in ("train_mfu", "train_step_time_s", "train_goodput_ratio"):
+    for name in ("train_step_time_s", "train_goodput_ratio"):
         assert any(line.startswith(name + "{") for line
                    in body.splitlines()), name
+    # no utilization gauge: a count of operations is the benchmark's
+    assert "train_mfu" not in body
     led.close()
     # and the same phases landed in the goodput ledger
     rep = goodput_report(tmp_path)
@@ -367,41 +364,22 @@ def test_trainer_obs_exports_live_mfu_on_metrics_endpoint(tmp_path):
     assert rep["accounted_s"] == pytest.approx(rep["wall_s"])
 
 
-def test_mfu_gauge_stays_unset_without_flops_or_peak():
+def test_trainer_obs_registers_exactly_these_names():
+    """The scrape interface, pinned: a dashboard or an alert names these.  A
+    new metric is added here with the code; one that goes is taken out of
+    whatever reads it first.  (``record_step_counters`` adds a gauge for each
+    counter a loss function returns, by name, when the first one arrives.)"""
     from tpucfn.train.trainer import TrainerObs
 
-    clk = FakeClock()
     reg = MetricRegistry()
-    obs = TrainerObs(reg, clock=clk)
-    for i in (1, 2):
-        with obs.step(i):
-            clk.advance(0.1)
-    assert reg.varz()["metrics"]["train_mfu"] == 0.0  # never armed
-
-
-# ---- cost-analysis helpers ----------------------------------------------
-
-def test_cost_analysis_flops_reads_dict_or_nothing():
-    assert cost_analysis_flops({"flops": 5.0}) == 5.0
-    assert cost_analysis_flops({}) is None
-    assert cost_analysis_flops(None) is None
-    assert cost_analysis_flops({"bytes accessed": 1.0}) is None
-    assert cost_analysis_flops("garbage") is None
-
-
-def test_device_peak_flops_table():
-    assert device_peak_flops("TPU v5e") == pytest.approx(197e12)
-    assert device_peak_flops("TPU v4") == pytest.approx(275e12)
-    assert device_peak_flops("cpu") is None
-
-
-def test_trainer_step_cost_flops_is_none_before_compile():
-    # no _jit_step yet -> None, no raise (the best-effort contract)
-    from tpucfn.train.trainer import Trainer
-
-    t = Trainer.__new__(Trainer)
-    t._jit_step = None
-    assert Trainer.step_cost_flops(t, batch=None) is None
+    TrainerObs(reg, clock=FakeClock())
+    assert set(reg.varz()["metrics"]) == {
+        "train_step_seconds", "train_data_wait_seconds", "train_ckpt_seconds",
+        "train_steps_total", "train_last_step", "train_step_time_s",
+        "train_goodput_ratio"}
+    reg = MetricRegistry()
+    TrainerObs(reg, prefix="learner", clock=FakeClock())
+    assert all(n.startswith("learner_") for n in reg.varz()["metrics"])
 
 
 # ---- compile-bucket refinement (ISSUE 6 satellite) ------------------------

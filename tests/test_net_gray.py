@@ -42,6 +42,31 @@ def _local(shards, trainer=0, pc=1, batch=4, seed=3, **kw):
                           process_index=trainer, process_count=pc, **kw)
 
 
+# A fault here lasts FAULT_S; the client's deadline is 1 s.  "Noticed by the
+# deadline, not by the fault's end" is a bound far from both: a tier-1 worker
+# on a shared CPU reads 1 s of deadline as anything up to several seconds, so
+# a bound near the deadline tests the machine and not the client.
+FAULT_S = 120.0
+NOTICED_WITHIN_S = FAULT_S / 4
+
+
+# The whole epoch is 15 KB in twelve frames: once the first batch is read the
+# rest may already lie in socket buffers, out of reach of a fault injected
+# then, and the stream ends without degrading (seen under six workers).  So
+# the proxy holds the down direction after a few frames, the fault is injected
+# after the first batch as before, and the hold is let go: the fault lands
+# mid-stream whatever the scheduler does.
+HOLD_AFTER_BYTES = 4096
+
+
+def _hold_after_head(proxy):
+    proxy.inject("stall", direction="down", after_bytes=HOLD_AFTER_BYTES)
+
+
+def _let_go(proxy):
+    proxy.inject("stall", direction="down", duration_s=1e-3)
+
+
 def _assert_streams_equal(got, ref):
     assert len(got) == len(ref)
     for a, b in zip(got, ref):
@@ -81,32 +106,38 @@ def test_input_trickle_degrades_within_the_deadline(plane):
     stream degrade to local at the exact cursor, bit-identical."""
     shards, svc, proxy = plane
     registry = MetricRegistry()
+    _hold_after_head(proxy)
     stream, ds = _resilient(shards, proxy, registry=registry)
     ref = list(_local(shards).batches(1))
     got = [next(stream)]  # healthy first batch through the proxy
-    proxy.inject("throttle", rate_bps=64.0, duration_s=120.0)
+    proxy.inject("throttle", rate_bps=64.0, duration_s=FAULT_S)
+    _let_go(proxy)
     t0 = time.monotonic()
     got.extend(stream)
     detect = time.monotonic() - t0
+    # what the deadline guarantees: the stream went local at the exact
+    # cursor, because a deadline fired and not because the fault ended
     assert stream.degraded
-    # detection latency: the 1 s frame deadline + slack, never the
-    # multi-minute per-chunk worst case this PR retires
-    assert detect < 5.0, f"degradation took {detect:.1f}s"
+    assert stream.cursor == len(ref)
     _assert_streams_equal(got, ref)
     v = registry.varz()["metrics"]
     assert v["net_input_deadline_exceeded_total"] >= 1
+    # never the multi-minute per-chunk worst case the deadline retires
+    assert detect < NOTICED_WITHIN_S, f"degradation took {detect:.1f}s"
 
 
 def test_input_stall_degrades_within_the_deadline(plane):
     shards, svc, proxy = plane
+    _hold_after_head(proxy)
     stream, ds = _resilient(shards, proxy)
     ref = list(_local(shards).batches(1))
     got = [next(stream)]
-    proxy.inject("stall", duration_s=120.0)
+    proxy.inject("stall", duration_s=FAULT_S)  # takes the hold's place
     t0 = time.monotonic()
     got.extend(stream)
-    assert time.monotonic() - t0 < 5.0
+    assert time.monotonic() - t0 < NOTICED_WITHIN_S
     assert stream.degraded
+    assert stream.cursor == len(ref)
     _assert_streams_equal(got, ref)
 
 
@@ -115,14 +146,17 @@ def test_input_partition_down_degrades_within_the_deadline(plane):
     host's bytes never arrive — asymmetric reachability, the half-open
     class."""
     shards, svc, proxy = plane
+    _hold_after_head(proxy)
     stream, ds = _resilient(shards, proxy)
     ref = list(_local(shards).batches(1))
     got = [next(stream)]
-    proxy.inject("partition", direction="down", duration_s=120.0)
+    proxy.inject("partition", direction="down", duration_s=FAULT_S)
+    _let_go(proxy)
     t0 = time.monotonic()
     got.extend(stream)
-    assert time.monotonic() - t0 < 5.0
+    assert time.monotonic() - t0 < NOTICED_WITHIN_S
     assert stream.degraded
+    assert stream.cursor == len(ref)
     _assert_streams_equal(got, ref)
 
 

@@ -73,6 +73,21 @@ def echo():
     s.close()
 
 
+def _counted(proxy, *, up, down, timeout=10.0):
+    """A pump counts a chunk after it has sent it, so a client can hold its
+    echo before the proxy has counted those bytes, and an offset or a counter
+    read then is short by them: wait until the proxy has counted what the
+    client already holds."""
+    t_end = time.monotonic() + timeout
+    while time.monotonic() < t_end:
+        with proxy.state.lock:
+            if (proxy._fwd_bytes["up"] >= up
+                    and proxy._fwd_bytes["down"] >= down):
+                return
+        time.sleep(0.005)
+    raise AssertionError(f"the proxy never counted {up} up, {down} down")
+
+
 def _client(proxy, timeout=5.0):
     c = socket.create_connection(("127.0.0.1", proxy.port), timeout=5.0)
     c.settimeout(timeout)
@@ -171,6 +186,7 @@ def test_tear_forwards_exactly_after_bytes_then_closes(echo):
         c = _client(p)
         c.sendall(b"hi")
         assert c.recv(2) == b"hi"
+        _counted(p, up=2, down=2)  # the tear's offset counts from here
         p.inject("tear", after_bytes=7, direction="down")
         c.sendall(b"y" * 100)
         got = bytearray()
@@ -294,6 +310,7 @@ def test_proxy_metrics_and_fired_audit_trail(echo):
         assert c.recv(3) == b"abc"
         p.inject("latency", delay_s=0.01, duration_s=1.0)
         c.close()
+        _counted(p, up=3, down=3)
         v = reg.varz()["metrics"]
         assert v["net_proxy_connections_total"] == 1
         assert v["net_proxy_forwarded_bytes_total"] >= 6  # echo: up + down

@@ -20,7 +20,7 @@ ship a write-only span).  Two rots, both silent at runtime:
 * **unpinned cross-host span** (ISSUE 20) — an emission passing
   ``remote_parent=`` (a cross-host causal link) whose name is not in
   the package's ``CROSS_HOST_SPAN_NAMES`` tuple: the merged timeline's
-  link stats and the trace-smoke gate select carriers by that
+  link stats select carriers by that
   vocabulary, so an unpinned carrier's flow arrows silently vanish
   from the coverage accounting.  The reverse drifts too: a name pinned
   in the tuple that no emission site carries is a stale vocabulary

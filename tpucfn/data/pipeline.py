@@ -11,6 +11,7 @@ keeping the TPU fed from host memory without a host↔device sync bubble
 
 from __future__ import annotations
 
+import contextlib
 import itertools
 import os
 import queue
@@ -551,7 +552,9 @@ def prefetch_to_mesh(
     """Wrap a host-batch iterator so device transfer overlaps compute.
 
     A daemon thread stays ``depth`` global batches ahead; the consumer
-    always finds its next batch already resident on the mesh.
+    always finds its next batch already resident on the mesh.  Closing the
+    generator (or dropping it) ends the thread after the batch it is making
+    and frees the batches it had placed ahead.
 
     With a ``tracer`` (:class:`tpucfn.obs.trace.Tracer`) the thread
     writes two spans a batch, ``trace_id`` the step the batch feeds,
@@ -584,6 +587,7 @@ def prefetch_to_mesh(
              else shard_batch)
     q: queue.Queue = queue.Queue(maxsize=depth)
     _END = object()
+    stopped = threading.Event()  # the consumer has gone
     it = iter(it)
     if tracer is None:
         tracer = Tracer(None)  # times, writes nothing
@@ -607,6 +611,8 @@ def prefetch_to_mesh(
                     s["bytes"] = nbytes
                     s["queued"] = q.qsize()
                 q.put(placed)
+                if stopped.is_set():
+                    return
         except Exception as e:  # surface pipeline errors to the consumer
             q.put(e)
             return
@@ -614,13 +620,23 @@ def prefetch_to_mesh(
 
     t = threading.Thread(target=producer, daemon=True, name="tpucfn-prefetch")
     t.start()
-    while True:
-        item = q.get()
-        if item is _END:
-            return
-        if isinstance(item, Exception):
-            raise item
-        yield item
+    try:
+        while True:
+            item = q.get()
+            if item is _END:
+                return
+            if isinstance(item, Exception):
+                raise item
+            yield item
+    finally:
+        # a consumer that stops early leaves the thread blocked in ``q.put``
+        # with ``depth`` + 1 batches held on the devices: make room, so that
+        # it reads ``stopped`` after the put, and wait for the batch in hand
+        stopped.set()
+        with contextlib.suppress(queue.Empty):
+            while True:
+                q.get_nowait()
+        t.join(timeout=5.0)
 
 
 # The disaggregated-input client (ISSUE 11) is part of the pipeline's

@@ -1,11 +1,10 @@
 """Goodput accounting: where did the fleet's paid TPU-seconds go?
 
-The harness's north star is "as fast as the hardware allows", but until
-now the repo could only say so *after the fact* (bench.py's offline MFU)
-and could not say at all how much fleet time a run lost to compiles,
-input stalls, checkpoint pauses, or the ft plane's restart/rewind
-cycles.  This module is the per-run ledger that decomposes wall-clock
-into named buckets (ISSUE 5 tentpole):
+The harness's north star is "as fast as the hardware allows", which
+says nothing of how much fleet time a run lost to compiles, input
+stalls, checkpoint pauses, or the ft plane's restart/rewind cycles.
+This module is the per-run ledger that decomposes wall-clock into named
+buckets (ISSUE 5 tentpole):
 
     productive_step  optimizer steps that advanced the run
     compile          the first step of each process incarnation (jit
@@ -96,51 +95,6 @@ LEDGER_ROW_KINDS = ("goodput_run",)
 
 def ledger_path(d: str | Path, host_id: int) -> Path:
     return Path(d) / f"goodput-host{host_id:03d}.jsonl"
-
-
-# --------------------------------------------------------------------------
-# cost-analysis helpers (the live-MFU side)
-# --------------------------------------------------------------------------
-
-def cost_analysis_value(cost, key: str) -> float | None:
-    """One value from a ``compiled.cost_analysis()`` result (a dict) —
-    shared by the live gauges and bench.py; ``None`` when the backend
-    reports nothing (CPU, mock devices).
-    """
-    try:
-        v = cost.get(key) if cost else None
-    except AttributeError:
-        return None
-    return float(v) if v else None
-
-
-def cost_analysis_flops(cost) -> float | None:
-    """Per-device FLOPs from a ``compiled.cost_analysis()`` result."""
-    return cost_analysis_value(cost, "flops")
-
-
-# Peak dense bf16 TFLOP/s per chip by device_kind substring (public
-# specs) — bench.py's table, exposed here so the LIVE gauge and the
-# offline bench agree on the denominator.
-PEAK_BF16_TFLOPS = (
-    ("v6", 918.0), ("trillium", 918.0),
-    ("v5p", 459.0),
-    ("v5 lite", 197.0), ("v5e", 197.0), ("v5litepod", 197.0),
-    ("v4", 275.0),
-    ("v3", 123.0),
-    ("v2", 45.0),
-)
-
-
-def device_peak_flops(device_kind: str) -> float | None:
-    """Peak FLOP/s (not TFLOP/s) for ``device_kind``, or None for
-    devices without a published peak (CPU hosts: MFU stays unset rather
-    than lying)."""
-    kind = device_kind.lower()
-    for key, tflops in PEAK_BF16_TFLOPS:
-        if key in kind:
-            return tflops * 1e12
-    return None
 
 
 # --------------------------------------------------------------------------

@@ -207,8 +207,8 @@ def resolve_links(events: list[dict]) -> tuple[list[tuple[int, int]], dict]:
     Returns ``(links, stats)``: ``links`` is a list of
     ``(parent_index, child_index)`` pairs into ``events`` (the parent
     is the remote span the child's ``rp`` names), deterministic order;
-    ``stats`` counts carriers and resolutions per span name — the
-    trace-smoke gate reads ``stats["by_name"]["data_wait"]``."""
+    ``stats`` counts carriers and resolutions per span name
+    (``stats["by_name"]["data_wait"]`` is the coverage of the input plane)."""
     index: dict[tuple[int, int], int] = {}
     for i, e in enumerate(events):
         if e.get("kind") != "span" or e.get("span_id") is None:

@@ -21,8 +21,8 @@ already exist:
 The actuation-latency model is fetch-warm spin-up (ISSUE 13): a grown
 input host costs ``spinup_s`` to fan out plus the trainers' warm
 time-to-first-step after the drain-relaunch — ``warm_ttfs_frac *
-cold_ttfs_s``, the measured 0.35x bound from compile_bench — not a full
-cold compile.  That is what makes growing *worth it* mid-run at all.
+cold_ttfs_s``, 0.35 as a CPU drill of PR 13 read it at toy size (no
+fetch has been timed on the chip) — not a full cold compile.  That is what makes growing *worth it* mid-run at all.
 
 Same discipline as :mod:`tpucfn.ft.policy`, which this mirrors: pure
 and jax-free (the coordinator imports it; so does the analyzer), no
@@ -96,8 +96,8 @@ class PolicyConfig:
     spinup_s: float = 5.0
     # Cold time-to-first-step the relaunched trainers would pay bare...
     cold_ttfs_s: float = 60.0
-    # ...discounted to the fetch-warm fraction (compile_bench's 0.35x
-    # acceptance bound) because the artifact cache serves the relaunch.
+    # ...discounted to the fetch-warm fraction (0.35x: a CPU drill's
+    # reading at toy size) because the artifact cache serves the relaunch.
     warm_ttfs_frac: float = 0.35
     # Horizon the projected data_wait savings must amortize the
     # actuation latency over.

@@ -285,25 +285,6 @@ class Trainer:
     def batch_sharding(self) -> NamedSharding:
         return NamedSharding(self.mesh, batch_spec(self.config.batch_extra_axes))
 
-    def step_cost_flops(self, batch: Any) -> float | None:
-        """Per-device FLOPs of one compiled step via XLA cost analysis,
-        fed to the live ``train_mfu`` gauge (ISSUE 5).  The AOT
-        lower/compile here does NOT share the jit call's executable
-        cache and may recompile the program — call it off the hot path
-        (examples/common.py arms the gauge from a daemon thread).
-        Best-effort: None when the backend reports no cost model (CPU
-        fallback, mocked devices)."""
-        if self._jit_step is None:
-            return None
-        from tpucfn.obs.goodput import cost_analysis_flops
-
-        try:
-            cost = (self._jit_step.lower(self.abstract_state(), batch)
-                    .compile().cost_analysis())
-            return cost_analysis_flops(cost)
-        except Exception:  # noqa: BLE001 — cost analysis is best-effort
-            return None
-
     def step(self, state: TrainState, batch: Any):
         if self._jit_step is None:
             shardings = self.state_shardings()
@@ -375,15 +356,13 @@ class TrainerObs:
     """
 
     def __init__(self, registry=None, tracer=None, *, prefix: str = "train",
-                 ledger=None, peak_flops: float | None = None,
-                 clock=time.monotonic, flight=None, compile_probe=None):
+                 ledger=None, clock=time.monotonic, flight=None,
+                 compile_probe=None):
         """``ledger`` is a :class:`tpucfn.obs.goodput.GoodputLedger` (or
         None): every phase the loop reports is also attributed to the
         per-host goodput JSONL so ``tpucfn obs goodput`` can decompose
-        the run's wall clock (ISSUE 5).  ``peak_flops``/:meth:`
-        set_model_flops` arm the live ``{prefix}_mfu`` gauge; ``clock``
-        is injectable so the gauges are pinned with a fake clock and no
-        TPU.
+        the run's wall clock (ISSUE 5).  ``clock`` is injectable so the
+        gauges are pinned with a fake clock and no TPU.
 
         ``flight`` is a :class:`tpucfn.obs.flight.FlightRecorder` (or
         None): every phase also lands one sample in the in-memory ring,
@@ -417,33 +396,16 @@ class TrainerObs:
             f"{prefix}_steps_total", "completed optimizer steps")
         self.last_step = r.gauge(
             f"{prefix}_last_step", "most recent global step")
-        # The live efficiency plane (ISSUE 5): what bench.py computed
-        # offline, exported per step on the existing /metrics endpoint.
+        # The live efficiency plane (ISSUE 5), exported per step on the
+        # existing /metrics endpoint.
         self.step_time_g = r.gauge(
             f"{prefix}_step_time_s", "last host-observed step wall time")
-        self.mfu_g = r.gauge(
-            f"{prefix}_mfu",
-            "model FLOPs utilization of the last step (cost-analysis "
-            "FLOPs / step time / device peak)")
         self.goodput_ratio_g = r.gauge(
             f"{prefix}_goodput_ratio",
             "productive step seconds / wall seconds since loop start")
-        self._flops_per_dev_step: float | None = None
-        self._peak_flops = peak_flops
         self._t0 = clock()
         self._productive_s = 0.0
         self._steps_seen = 0
-
-    def set_model_flops(self, flops_per_dev_step: float | None,
-                        peak_flops: float | None = None) -> None:
-        """Arm the MFU gauge: per-device FLOPs of one step (from
-        :meth:`Trainer.step_cost_flops`, captured once at compile) and
-        the device's peak FLOP/s (``goodput.device_peak_flops``).
-        Either None leaves the gauge unset — no number beats a wrong
-        number."""
-        self._flops_per_dev_step = flops_per_dev_step
-        if peak_flops is not None:
-            self._peak_flops = peak_flops
 
     @contextlib.contextmanager
     def _phase(self, name: str, metric, step: int | None):
@@ -502,10 +464,6 @@ class TrainerObs:
         elapsed = self.clock() - self._t0
         if elapsed > 0:
             self.goodput_ratio_g.set(self._productive_s / elapsed)
-        if (self._flops_per_dev_step and self._peak_flops
-                and dur_s > 0):
-            self.mfu_g.set(self._flops_per_dev_step
-                           / dur_s / self._peak_flops)
 
     def data_wait(self, step: int | None = None):
         return self._phase("data_wait", self.data_wait_time, step)
