@@ -54,6 +54,51 @@ def test_kernel_tool_prints_its_documented_rows(tool):
         assert {row["device"] for row in rows} == {"cpu"}
 
 
+def test_flash_bench_presets_are_the_flash_cells_shapes():
+    """``--preset`` names a benchmark cell and times the kernels at its
+    shape: the shapes are the cell's configuration and traffic files'."""
+    sys.path.insert(0, str(REPO))
+    from benches import flash_bench
+
+    from benchmark import run
+
+    for name, shape in flash_bench.PRESETS.items():
+        cell = run.load_cell(name, False)
+        model, mix = cell["config"]["model"], cell["mix"]["shape"]
+        assert shape == dict(
+            batch=mix["batch"], seq=mix["seq_len"],
+            heads=model["num_attention_heads"],
+            kv_heads=model["num_key_value_heads"],
+            head_dim=model["head_dim"]), name
+
+
+def test_flash_bench_times_each_kernel_beside_its_roofline():
+    """The preset mode's row at a size the interpreter can run: a time for
+    each of the three kernels, the least time from ``benchmark/flops``
+    (imported, the cells' yardstick), and the grid steps by class."""
+    sys.path.insert(0, str(REPO))
+    from benches import flash_bench
+    from benchmark import flops
+
+    shape = dict(batch=1, seq=256, heads=2, kv_heads=1, head_dim=32)
+    times = flash_bench.kernel_times(**shape, blocks=(64, 128), iters=1)
+    assert times["blocks"] == [64, 128]
+    # 4 query blocks x 2 key blocks: (0,0) (1,0) are cut by the diagonal's
+    # first key block, (2,1) (3,1) by its second; (2,0) (3,0) lie under it
+    assert times["steps"] == {"interior": 2, "edge": 4, "skipped": 2}
+    peak = json.loads((REPO / "benchmark" / "peaks.json").read_text())[
+        "TPU v5 lite"]
+    row = flash_bench.beside_roofline(shape, times, peak)
+    for kind in ("fwd", "dkv", "dq"):
+        ms = row[kind]["ms"]
+        assert isinstance(ms, float) and math.isfinite(ms) and ms > 0
+        least, bound = flops.roofline_seconds(
+            *flops.flash_call(kind, 1, 256, 2, 1, 32), peak)
+        assert row[kind]["least_ms"] == round(least * 1e3, 3)
+        assert row[kind]["bound"] == bound
+    assert row["steps"] == times["steps"]
+
+
 def test_serve_bench_row_carries_prefix_and_batch_stats():
     """ISSUE 3 CI satellite: the serve_bench BENCH row must carry the
     shared-prefix block (hit rate, prefill calls per request, TTFT, the

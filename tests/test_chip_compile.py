@@ -52,6 +52,12 @@ CASES = [
      dict(block_q=256, block_k=512), False),
     ("s8192_d128_b256x512", 8192, 128, 32, 8,
      dict(block_q=256, block_k=512), False),
+    # the blocks mistral7b-s8192 and qwen3next-ep8-s8192 read from the table
+    # since PR 30: the largest score tile, 4 MB in float32
+    ("s8192_d128_b1024x1024", 8192, 128, 32, 8,
+     dict(block_q=1024, block_k=1024), False),
+    ("s8192_d256_gqa16x2_b1024x1024", 8192, 256, 16, 2,
+     dict(block_q=1024, block_k=1024), False),
     ("s2048_d64_default", 2048, 64, 32, 8, {}, False),
     ("s8192_d64_default", 8192, 64, 32, 8, {}, False),
     ("s4096_d40_unet_full", 4096, 40, 8, 8, dict(causal=False), False),

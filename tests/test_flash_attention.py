@@ -321,3 +321,255 @@ def test_flash_property_sweep_random_shapes_vs_dense():
             np.asarray(out), np.asarray(ref), atol=3e-6,
             err_msg=f"trial={trial} sq={sq} skv={skv} h={h}/{hkv} d={d} "
                     f"causal={causal} seg={seg is not None}")
+
+
+# ---- PR 30: every block class, both orientations, the dtype rule ----
+
+
+def _grads(fn, q, k, v, w):
+    """Gradients of sum(fn(q, k, v) * w): a cotangent that differs by row."""
+    return jax.grad(lambda q, k, v: jnp.sum(fn(q, k, v) * w),
+                    argnums=(0, 1, 2))(q, k, v)
+
+
+def _rand(shape, seed, dtype=jnp.float32):
+    return jnp.asarray(np.random.RandomState(seed).randn(*shape), dtype)
+
+
+# (id, S, q heads, kv heads, D, kwargs of flash_attention, segment ids?)
+BLOCK_CLASS_CASES = [
+    # interior, edge and skipped blocks with the query block the taller ...
+    ("bq_gt_bk", 256, 4, 2, 16, dict(block_q=128, block_k=32), False),
+    # ... and the wider: several query blocks end inside one key block
+    ("bk_gt_bq", 256, 4, 2, 16, dict(block_q=32, block_k=128), False),
+    # 200 keys padded to 256: the last key block is an edge for its padding
+    ("padded_last_key_block", 200, 2, 2, 16, dict(block_q=64, block_k=64),
+     False),
+    # every block computed, none masked but the padded one
+    ("noncausal_padded", 200, 2, 1, 16,
+     dict(causal=False, block_q=64, block_k=64), False),
+    # no mask anywhere: one body, no branch
+    ("noncausal_all_interior", 192, 2, 1, 16,
+     dict(causal=False, block_q=64, block_k=32), False),
+    # segment ids with GQA: every computed block is an edge, guard on
+    ("segments_gqa", 192, 4, 1, 16, dict(block_q=32, block_k=64), True),
+    ("segments_gqa_padded", 176, 4, 2, 16, dict(block_q=64, block_k=32), True),
+    # blocks taller than _ROWS: a score tile worked off in chunks of rows,
+    # query rows in the forward and the query backward, key rows in the
+    # key/value backward
+    ("row_chunks", 1024, 2, 1, 16, dict(block_q=512, block_k=512), False),
+    ("row_chunks_segments", 512, 2, 1, 16, dict(block_q=512, block_k=512),
+     True),
+]
+
+
+@pytest.mark.parametrize("s,hq,hkv,d,kwargs,segments",
+                         [c[1:] for c in BLOCK_CLASS_CASES],
+                         ids=[c[0] for c in BLOCK_CLASS_CASES])
+def test_block_classes_match_dense(s, hq, hkv, d, kwargs, segments):
+    """Forward and gradients against the dense path with every class of
+    block on the grid, in the forward's orientation (queries as rows) and
+    the key/value backward's (keys as rows)."""
+    q, w = _rand((2, s, hq, d), 0), _rand((2, s, hq, d), 3)
+    k, v = _rand((2, s, hkv, d), 1), _rand((2, s, hkv, d), 2)
+    causal = kwargs.get("causal", True)
+    segs = mask = None
+    if segments:
+        segs = jnp.asarray(np.sort(
+            np.random.RandomState(4).randint(0, 4, (2, s))).astype(np.int32))
+        mask = _seg_mask(segs, segs)
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, segment_ids=segs, interpret=True,
+                               **{"causal": True, **kwargs})
+
+    def dense(q, k, v):
+        return dot_product_attention(q, k, v, causal=causal, mask=mask)
+
+    np.testing.assert_allclose(np.asarray(flash(q, k, v)),
+                               np.asarray(dense(q, k, v)), atol=2e-5)
+    for a, b, name in zip(_grads(flash, q, k, v, w),
+                          _grads(dense, q, k, v, w), "qkv"):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=3e-4,
+                                   err_msg=f"d{name}")
+
+
+@pytest.mark.parametrize("q_offset,k_offset,blocks", [
+    (40, 0, (32, 32)),    # diagonal 40 keys off the block boundary
+    (40, 0, (64, 16)),
+    (0, 24, (32, 32)),    # the first 24 queries see no key at all: guard on
+    (8, 20, (16, 64)),
+    (96, 0, (32, 32)),    # a past hop under the causal flag: all interior
+], ids=["q40_32x32", "q40_64x16", "k24_32x32", "q8k20_16x64", "q96_all_past"])
+def test_ring_hop_offsets_with_dlse_match_dense(q_offset, k_offset, blocks):
+    """Static offsets shift the diagonal off the block boundary; the LSE is
+    an output with a cotangent of its own, as a ring hop's merge has it."""
+    from tpucfn.kernels import flash_attention_with_lse
+    from tpucfn.ops.attention import dot_product_attention_with_lse
+
+    s, h, hkv, d = 96, 4, 2, 16
+    q, w = _rand((1, s, h, d), 10), _rand((1, s, h, d), 13)
+    k, v = _rand((1, s, hkv, d), 11), _rand((1, s, hkv, d), 12)
+    u = _rand((1, s, h), 14)
+
+    def loss(fn, **kw):
+        def f(q, k, v):
+            o, lse = fn(q, k, v, causal=True, q_offset=q_offset,
+                        k_offset=k_offset, **kw)
+            # a row that sees no key has lse = NEG_INF on both paths
+            return jnp.sum(o * w) + jnp.sum(
+                jnp.where(lse > -1e29, lse, 0.0) * u), (o, lse)
+        return jax.grad(f, argnums=(0, 1, 2), has_aux=True)
+
+    gf, (of, lf) = loss(flash_attention_with_lse, block_q=blocks[0],
+                        block_k=blocks[1], interpret=True)(q, k, v)
+    gd, (od, ld) = loss(dot_product_attention_with_lse)(q, k, v)
+    np.testing.assert_allclose(np.asarray(of), np.asarray(od), atol=2e-5)
+    np.testing.assert_allclose(np.asarray(lf), np.asarray(ld), rtol=1e-5,
+                               atol=2e-5)
+    for a, b, name in zip(gf, gd, "qkv"):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=3e-4,
+                                   err_msg=f"d{name}")
+
+
+@pytest.mark.parametrize("d,hq,hkv", [(128, 4, 2), (256, 4, 1)],
+                         ids=["d128_gqa", "d256_gqa"])
+def test_bfloat16_is_held_to_the_dense_paths_bfloat16(d, hq, hkv):
+    """The dtype rule: bfloat16 operands to every product, float32
+    accumulation and statistics, as the dense path computes. The kernel's
+    distance from the float32 answer stays within the dense path's own."""
+    s = 256
+    q32, w = _rand((1, s, hq, d), 20), _rand((1, s, hq, d), 23)
+    k32, v32 = _rand((1, s, hkv, d), 21), _rand((1, s, hkv, d), 22)
+    q, k, v = (x.astype(jnp.bfloat16) for x in (q32, k32, v32))
+    # the float32 answer for the rounded inputs
+    exact = (q.astype(jnp.float32), k.astype(jnp.float32),
+             v.astype(jnp.float32))
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, causal=True, block_q=64, block_k=128,
+                               interpret=True).astype(jnp.float32)
+
+    def dense(q, k, v):
+        return dot_product_attention(q, k, v, causal=True).astype(jnp.float32)
+
+    assert flash_attention(q, k, v, interpret=True).dtype == jnp.bfloat16
+    ref = [dense(*exact), *_grads(dense, *exact, w)]
+    got_flash = [flash(q, k, v), *_grads(flash, q, k, v, w)]
+    got_dense = [dense(q, k, v), *_grads(dense, q, k, v, w)]
+    for r, f, dn, name in zip(ref, got_flash, got_dense,
+                              ("o", "dq", "dk", "dv")):
+        assert f.dtype == dn.dtype
+        r = np.asarray(r, np.float32)
+        err_flash = np.linalg.norm(np.asarray(f, np.float32) - r)
+        err_dense = np.linalg.norm(np.asarray(dn, np.float32) - r)
+        assert err_flash <= 1.25 * err_dense + 1e-6, (name, err_flash,
+                                                     err_dense)
+
+
+def test_float32_inputs_compute_what_the_parent_computed():
+    """float32 inputs take no part in the dtype rule: forward, LSE and
+    gradients equal the kernels' results before PR 30 (recorded from that
+    tree, interpret mode, same inputs) to float32 tolerance, for the three
+    ways a block can be an edge."""
+    import pathlib
+
+    want = np.load(pathlib.Path(__file__).parent / "data"
+                   / "flash_f32_parent.npz")
+    from tpucfn.kernels import flash_attention_with_lse
+
+    rs = np.random.RandomState(30)
+    b, s, hq, hkv, d = 1, 72, 2, 1, 16
+    q = jnp.asarray(rs.randn(b, s, hq, d).astype(np.float32))
+    k = jnp.asarray(rs.randn(b, s, hkv, d).astype(np.float32))
+    v = jnp.asarray(rs.randn(b, s, hkv, d).astype(np.float32))
+    w = jnp.asarray(rs.randn(b, s, hq, d).astype(np.float32))
+    u = jnp.asarray(rs.randn(b, s, hq).astype(np.float32))
+    segs = jnp.asarray(np.sort(rs.randint(0, 3, (b, s))).astype(np.int32))
+
+    def causal(q, k, v):
+        o = flash_attention(q, k, v, causal=True, block_q=32, block_k=16,
+                            interpret=True)
+        return jnp.sum(o * w), {"o": o}
+
+    def hop(q, k, v):
+        o, lse = flash_attention_with_lse(
+            q, k, v, causal=True, q_offset=8, k_offset=20, block_q=16,
+            block_k=32, interpret=True)
+        return (jnp.sum(o * w) + jnp.sum(jnp.where(lse > -1e29, lse, 0.0) * u),
+                {"o": o, "lse": lse})
+
+    def seg(q, k, v):
+        o = flash_attention(q, k, v, causal=True, segment_ids=segs,
+                            block_q=16, block_k=32, interpret=True)
+        return jnp.sum(o * w), {"o": o}
+
+    for name, fn in (("causal", causal), ("hop", hop), ("seg", seg)):
+        grads, outs = jax.grad(fn, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+        outs.update(dq=grads[0], dk=grads[1], dv=grads[2])
+        for key, got in outs.items():
+            np.testing.assert_allclose(
+                np.asarray(got), want[f"{name}_{key}"], rtol=2e-6, atol=2e-6,
+                err_msg=f"{name}_{key}")
+
+
+GEOMETRIES = [
+    # (causal, block_q, block_k, q_offset, k_offset, kv_len, sk_pad, sq_pad)
+    (True, 256, 512, 0, 0, 8192, 8192, 8192),
+    (True, 128, 32, 0, 0, 256, 256, 256),
+    (True, 32, 128, 0, 0, 200, 256, 224),
+    (True, 32, 32, 40, 0, 96, 96, 96),
+    (True, 16, 64, 8, 20, 72, 128, 80),
+    (True, 32, 32, 0, 1000, 32, 32, 32),
+    (False, 64, 64, 0, 0, 200, 256, 256),
+    (False, 64, 32, 0, 0, 192, 192, 192),
+]
+
+
+@pytest.mark.parametrize("geometry", GEOMETRIES,
+                         ids=["-".join(map(str, g)) for g in GEOMETRIES])
+def test_grid_classes_and_clamps_against_the_mask_itself(geometry):
+    """A block's class and the index maps' clamps, held to the mask written
+    out pair by pair: interior blocks mask nothing, skipped blocks keep
+    nothing, and a skipped step's clamped index is a block that is needed
+    (so it is resident and the step fetches nothing)."""
+    from tpucfn.kernels.flash_attention import _Grid
+
+    causal, bq, bk, qoff, koff, kv_len, sk_pad, sq_pad = geometry
+    grid = _Grid(causal, bq, bk, qoff, koff, kv_len, sk_pad, False)
+    qpos = qoff + np.arange(sq_pad)[:, None]
+    kloc = np.arange(sk_pad)[None, :]
+    keep = np.broadcast_to(kloc < kv_len, (sq_pad, sk_pad))
+    if causal:
+        keep = keep & (qpos >= koff + kloc)
+    nq, nk = sq_pad // bq, sk_pad // bk
+    counted = {"interior": 0, "edge": 0, "skipped": 0}
+    for qi in range(nq):
+        for ki in range(nk):
+            block = keep[qi * bq:(qi + 1) * bq, ki * bk:(ki + 1) * bk]
+            needed = bool(grid.needed(qi, ki))
+            interior = bool(grid.interior(qi, ki))
+            assert not interior or block.all(), (qi, ki)
+            assert needed or not block.any(), (qi, ki)
+            assert needed or not interior
+            # where causality skips, it is the only thing that masks
+            if causal and not needed:
+                assert (qoff + (qi + 1) * bq - 1) < koff + ki * bk
+            counted["interior" if interior else
+                    "edge" if needed else "skipped"] += 1
+            # a needed step's index maps are the identity; a skipped step's
+            # hold the nearest needed block of its row (column), which the
+            # step before fetched or the step after will
+            k_held = int(grid.last_needed_k(qi, ki))
+            q_held = int(grid.first_needed_q(qi, ki, nq))
+            if needed:
+                assert (k_held, q_held) == (ki, qi)
+            if not needed and any(grid.needed(qi, j) for j in range(nk)):
+                assert grid.needed(qi, k_held)
+                assert not grid.needed(qi, k_held + 1)
+            if not needed and any(grid.needed(i, ki) for i in range(nq)):
+                assert grid.needed(q_held, ki)
+                assert q_held == 0 or not grid.needed(q_held - 1, ki)
+    assert grid.count(sq_pad) == counted
+    if geometry == GEOMETRIES[0]:
+        assert counted == {"interior": 240, "edge": 32, "skipped": 240}

@@ -6,6 +6,7 @@ the policy logic and the numerics equivalence are what is under test.
 """
 
 import json
+import pathlib
 
 import jax
 import jax.numpy as jnp
@@ -194,9 +195,12 @@ def test_builtin_tune_table_layering(tmp_path, monkeypatch):
     monkeypatch.setattr(fa, "_MEM_CACHE", None)
     table = fa._load()
     key = "TPU v5 lite|causal|8192|128|bfloat16"
-    # blocks measured on chip round 3; speedup vs dense recorded round 5
-    assert table[key][:2] == (256, 512)
-    assert table[key][2] == 15.11
+    # the row mistral7b-s8192 reads: blocks and the dense/flash ratio as
+    # last measured on the chip (PR 30 re-measured both)
+    bq, bk, ratio = json.loads((pathlib.Path(fa.__file__).parent
+                                / "flash_tune_builtin.json").read_text())[key]
+    assert table[key] == (bq, bk, ratio)
+    assert ratio > 1
 
     (tmp_path / "user.json").write_text(json.dumps({key: [128, 128]}))
     monkeypatch.setattr(fa, "_MEM_CACHE", None)
@@ -209,9 +213,9 @@ def test_builtin_tune_table_layering(tmp_path, monkeypatch):
     # A LEGACY user entry agreeing with the builtin blocks keeps the
     # builtin measured speedup (must not flip a measured-winning family
     # back to the no-evidence rule).
-    (tmp_path / "user.json").write_text(json.dumps({key: [256, 512]}))
+    (tmp_path / "user.json").write_text(json.dumps({key: [bq, bk]}))
     monkeypatch.setattr(fa, "_MEM_CACHE", None)
-    assert fa._load()[key] == (256, 512, 15.11)
+    assert fa._load()[key] == (bq, bk, ratio)
 
 
 def test_full_attention_auto_dispatch_policy(monkeypatch):

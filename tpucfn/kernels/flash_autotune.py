@@ -31,7 +31,8 @@ from pathlib import Path
 _MEM_CACHE: dict[str, tuple[int, int]] | None = None
 
 DEFAULT_CANDIDATES = ((128, 128), (128, 256), (256, 128), (256, 256),
-                      (128, 512), (512, 128), (256, 512), (512, 256))
+                      (128, 512), (512, 128), (256, 512), (512, 256),
+                      (512, 512), (512, 1024), (1024, 512), (1024, 1024))
 
 
 def _cache_path() -> Path:
