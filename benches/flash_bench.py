@@ -49,6 +49,9 @@ PRESETS = {
                             head_dim=128),
     "qwen3next-ep8-s8192": dict(batch=2, seq=8192, heads=16, kv_heads=2,
                                 head_dim=256),
+    # latent attention: keys of 192 (128 + 64 rotary), values of 128
+    "joyai-mla-s8192": dict(batch=2, seq=8192, heads=32, kv_heads=32,
+                            head_dim=192, value_dim=128),
 }
 
 
@@ -63,8 +66,8 @@ def _time(fn, *args, iters=10):
     return (time.perf_counter() - t0) / iters * 1e3
 
 
-def kernel_times(batch, seq, heads, kv_heads, head_dim, *, blocks=None,
-                 iters=10) -> dict:
+def kernel_times(batch, seq, heads, kv_heads, head_dim, value_dim=None, *,
+                 blocks=None, iters=10) -> dict:
     """ms a call of each kernel at this shape, causal, bfloat16, with the
     blocks the wrapper would choose (or ``blocks``), and the grid steps of
     one head by class."""
@@ -75,14 +78,15 @@ def kernel_times(batch, seq, heads, kv_heads, head_dim, *, blocks=None,
     fa = importlib.import_module("tpucfn.kernels.flash_attention")
 
     interpret = jax.default_backend() != "tpu"  # a rehearsal, not a timing
+    value_dim = value_dim or head_dim
     block_q, block_k = blocks or fa._choose_blocks(
-        seq, head_dim, jnp.bfloat16, True)
+        seq, head_dim, jnp.bfloat16, True, value_dim)
     grid = fa._Grid(True, block_q, block_k, 0, 0, seq, seq, False)
     keys = jax.random.split(jax.random.key(0), 4)
-    q, do = (jax.random.normal(key, (batch, heads, seq, head_dim),
-                               jnp.bfloat16) for key in keys[:2])
-    k, v = (jax.random.normal(key, (batch, kv_heads, seq, head_dim),
-                              jnp.bfloat16) for key in keys[2:])
+    q, do, k, v = (
+        jax.random.normal(key, (batch, h, seq, d), jnp.bfloat16)
+        for key, h, d in zip(keys, (heads, heads, kv_heads, kv_heads),
+                             (head_dim, value_dim, head_dim, value_dim)))
 
     fwd = jax.jit(lambda q, k, v: fa._flash_fwd(
         q, k, v, None, None, causal=True, q_offset=0, k_offset=0, kv_len=seq,
@@ -106,13 +110,15 @@ def kernel_times(batch, seq, heads, kv_heads, head_dim, *, blocks=None,
 def beside_roofline(shape: dict, times: dict, peak: dict) -> dict:
     """Each kernel's ms a call beside the least the chip could take for the
     call's shape (``benchmark/flops.flash_call``, the cells' yardstick)."""
-    from benchmark import flops
+    from benchmark import flops, flops_joyai_llm_flash
 
     row = {**shape, "blocks": times["blocks"], "steps": times["steps"]}
     for kind in ("fwd", "dkv", "dq"):
-        least, bound = flops.roofline_seconds(*flops.flash_call(
+        # equal head sizes count as ``flops.flash_call`` does
+        least, bound = flops.roofline_seconds(*flops_joyai_llm_flash.flash_call(
             kind, shape["batch"], shape["seq"], shape["heads"],
-            shape["kv_heads"], shape["head_dim"]), peak)
+            shape["kv_heads"], shape["head_dim"],
+            shape.get("value_dim", shape["head_dim"])), peak)
         row[kind] = {"ms": round(times[kind], 3),
                      "least_ms": round(least * 1e3, 3), "bound": bound,
                      "roofline_pct": round(100 * least * 1e3 / times[kind], 1)}

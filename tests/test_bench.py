@@ -65,11 +65,15 @@ def test_flash_bench_presets_are_the_flash_cells_shapes():
     for name, shape in flash_bench.PRESETS.items():
         cell = run.load_cell(name, False)
         model, mix = cell["config"]["model"], cell["mix"]["shape"]
-        assert shape == dict(
+        want = dict(
             batch=mix["batch"], seq=mix["seq_len"],
             heads=model["num_attention_heads"],
             kv_heads=model["num_key_value_heads"],
-            head_dim=model["head_dim"]), name
+            head_dim=model["head_dim"])
+        if "qk_head_dim" in model:   # latent attention: keys and values differ
+            want.update(head_dim=model["qk_head_dim"],
+                        value_dim=model["v_head_dim"])
+        assert shape == want, name
 
 
 def test_flash_bench_times_each_kernel_beside_its_roofline():

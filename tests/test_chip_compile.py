@@ -96,6 +96,30 @@ def test_flash_fwd_bwd_compiles_for_v5e(one_chip, s, d, h, hkv, kwargs,
     assert text.count("tpu_custom_call") >= 3, text.count("tpu_custom_call")
 
 
+@pytest.mark.parametrize("blocks", [(1024, 1024), (512, 512)],
+                         ids=["b1024x1024", "b512x512"])
+def test_flash_latent_head_sizes_compile_for_v5e(one_chip, blocks):
+    """Latent attention's call at the benchmark cell's shape: 32 heads with
+    keys of 192 (128 + 64 rotary; the first head size that is no multiple of
+    the 128 lanes) and values of 128, 8,192 positions, bfloat16; forward and
+    both backward kernels, compiled, not interpreted."""
+    from tpucfn.kernels.flash_attention import flash_attention
+
+    def sds(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+    def loss(q, k, v):
+        out = flash_attention(q, k, v, causal=True, interpret=False,
+                              block_q=blocks[0], block_k=blocks[1])
+        assert out.shape == (1, 8192, 32, 128)
+        return jnp.sum(out.astype(jnp.float32))
+
+    qk = sds((1, 8192, 32, 192))
+    step = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2)))
+    text = step.lower(qk, qk, sds((1, 8192, 32, 128))).compile().as_text()
+    assert text.count("tpu_custom_call") >= 3, text.count("tpu_custom_call")
+
+
 def test_hybrid_layer_ops_compile_for_v5e(one_chip):
     """The two new ops of models/hybrid.py at the benchmark cell's widths,
     forward and backward: the chunked delta rule (32 value heads of 128 on

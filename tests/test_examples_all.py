@@ -87,6 +87,21 @@ def test_qwen3_next_moe_example(tmp_path):
                for x in lines)
 
 
+def test_joyai_llm_flash_example(tmp_path):
+    """The latent-attention decoder through run_train_loop: both losses and
+    the routing counters reach the log and the ``step_metrics`` lines."""
+    r = _run("joyai_llm_flash.py", tmp_path, "--model", "tiny", "--seq-len", "32",
+             "--batch-size", "16", "--num-examples", "64")
+    _ok(r)
+    assert "moe_dropped=0 " in r.stdout and "mtp_loss=" in r.stdout
+    rows = [json.loads(ln) for p in (tmp_path / "trace").glob("trace-*.jsonl")
+            for ln in p.read_text().splitlines()]
+    lines = [x for x in rows if x["name"] == "step_metrics"]
+    assert [x["trace_id"] for x in lines] == [1, 2, 3]
+    assert all(x["attrs"]["moe_dropped"] == 0.0 and x["attrs"]["mtp_loss"] > 0
+               and x["attrs"]["lm_loss"] > 0 for x in lines)
+
+
 def test_sd15_unet_example(tmp_path):
     _ok(_run("sd15_unet.py", tmp_path, "--tiny", "--batch-size", "8",
              "--num-examples", "32"))

@@ -573,3 +573,86 @@ def test_grid_classes_and_clamps_against_the_mask_itself(geometry):
     assert grid.count(sq_pad) == counted
     if geometry == GEOMETRIES[0]:
         assert counted == {"interior": 240, "edge": 32, "skipped": 240}
+
+
+# ---- values narrower (or wider) than keys: latent attention's 192 / 128 ----
+
+# (id, S, query heads, key heads, key size, value size, kwargs, segments?)
+VALUE_WIDTH_CASES = [
+    # the latent attention's sizes themselves: 192 is no multiple of the lanes
+    ("mla_192v128", 64, 2, 2, 192, 128, dict(block_q=32, block_k=32), False),
+    ("narrow_48v32_gqa", 96, 4, 2, 48, 32, dict(block_q=32, block_k=32), False),
+    ("wider_values_16v40", 64, 2, 1, 16, 40, dict(block_q=16, block_k=32), False),
+    ("padded_keys_24v8", 50, 2, 2, 24, 8, dict(block_q=16, block_k=16), False),
+    ("segments_32v16", 64, 2, 2, 32, 16, dict(block_q=16, block_k=16), True),
+    ("full_40v24", 48, 2, 2, 40, 24, dict(causal=False), False),
+]
+
+
+@pytest.mark.parametrize("s,hq,hkv,d,dv,kwargs,segments",
+                         [c[1:] for c in VALUE_WIDTH_CASES],
+                         ids=[c[0] for c in VALUE_WIDTH_CASES])
+def test_value_width_differs_from_key_width(s, hq, hkv, d, dv, kwargs, segments):
+    """Forward and all three gradients against the dense path when ``v`` (and
+    so ``o``, ``do``, ``dv``) has another head size than ``q`` and ``k``: the
+    scale is the keys', every accumulator has its own array's width."""
+    rng = jax.random.key(d * 1000 + dv)
+    q = jax.random.normal(jax.random.fold_in(rng, 0), (2, s, hq, d))
+    k = jax.random.normal(jax.random.fold_in(rng, 1), (2, s, hkv, d))
+    v = jax.random.normal(jax.random.fold_in(rng, 2), (2, s, hkv, dv))
+    w = jax.random.normal(jax.random.fold_in(rng, 3), (2, s, hq, dv))
+    kwargs = {"causal": True, **kwargs}
+    seg = mask = None
+    if segments:
+        seg = jnp.asarray(np.concatenate(
+            [np.zeros((2, 24), np.int32), np.ones((2, s - 24), np.int32)], 1))
+        mask = _seg_mask(seg, seg)
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, segment_ids=seg, interpret=True, **kwargs)
+
+    def dense(q, k, v):
+        return dot_product_attention(q, k, v, causal=kwargs["causal"], mask=mask)
+
+    out, ref = flash(q, k, v), dense(q, k, v)
+    assert out.shape == ref.shape == (2, s, hq, dv)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
+    gf = jax.grad(lambda *a: jnp.sum(flash(*a) * w), argnums=(0, 1, 2))(q, k, v)
+    gd = jax.grad(lambda *a: jnp.sum(dense(*a) * w), argnums=(0, 1, 2))(q, k, v)
+    for a, b, name in zip(gf, gd, "qkv"):
+        assert a.shape == b.shape, name
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=3e-4,
+                                   err_msg=f"d{name} mismatch")
+
+
+def test_value_width_with_lse_and_its_cotangent():
+    from tpucfn.kernels.flash_attention import flash_attention_with_lse
+    from tpucfn.ops.attention import dot_product_attention_with_lse
+
+    rng = jax.random.key(5)
+    q = jax.random.normal(jax.random.fold_in(rng, 0), (1, 64, 2, 48))
+    k = jax.random.normal(jax.random.fold_in(rng, 1), (1, 64, 2, 48))
+    v = jax.random.normal(jax.random.fold_in(rng, 2), (1, 64, 2, 32))
+
+    def loss(fn, **kw):
+        def f(q, k, v):
+            o, lse = fn(q, k, v, causal=True, **kw)
+            return jnp.sum(o ** 2) + jnp.sum(jnp.sin(lse))
+        return jax.value_and_grad(f, argnums=(0, 1, 2))(q, k, v)
+
+    (lf, gf) = loss(flash_attention_with_lse, interpret=True, block_q=32,
+                    block_k=32)
+    (ld, gd) = loss(dot_product_attention_with_lse)
+    assert float(lf) == pytest.approx(float(ld), rel=1e-5)
+    for a, b in zip(gf, gd):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=3e-4)
+
+
+def test_the_table_key_learns_the_value_size_and_keeps_its_rows():
+    from tpucfn.kernels import flash_autotune as ft
+
+    assert ft._key("TPU v5 lite", True, 8192, 128, jnp.bfloat16) \
+        == ft._key("TPU v5 lite", True, 8192, 128, jnp.bfloat16, 128) \
+        == "TPU v5 lite|causal|8192|128|bfloat16"
+    assert ft._key("TPU v5 lite", True, 8000, 192, jnp.bfloat16, 128) \
+        == "TPU v5 lite|causal|8192|192v128|bfloat16"

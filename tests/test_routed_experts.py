@@ -126,3 +126,102 @@ def test_what_it_holds_has_to_lie_inside_the_routers_width():
     for held in ((6, 4), (-1, 2), (0, 0)):
         with pytest.raises(ValueError):
             layer(*held).init(jax.random.key(0), x)
+
+
+# ---- the second router: sigmoid scores, a selection bias, a weight scale ----
+
+def sigmoid_layer(first, count):
+    return RoutedExperts(E, K, F, (first, count), shared_dim=F,
+                         dtype=jnp.float32, score="sigmoid", select_bias=True,
+                         weight_scale=2.5, shared_gate=False)
+
+
+def make_sigmoid(first, count, seed=0):
+    m = sigmoid_layer(first, count)
+    x = jax.random.normal(jax.random.key(seed + 1), (T, D))
+    params = jax.tree.map(lambda a: 10 * a,
+                          m.init(jax.random.key(seed), x)["params"])
+    # scores spread over (0, 1), and a bias wide enough to move the chosen
+    # set of most tokens
+    params["router"]["kernel"] = 0.5 * jax.random.normal(
+        jax.random.key(seed + 3), (D, E))
+    params["e_score_correction_bias"] = 0.5 * jax.random.normal(
+        jax.random.key(seed + 2), (E,))
+    return m, params, x
+
+
+def sigmoid_loop(params, x, first, count, *, choose_by="biased",
+                 weigh_by="unbiased", shared=True):
+    """Every held expert on every token; the chosen set by score plus bias,
+    the weights the unbiased scores over their sum, times 2.5."""
+    s = jax.nn.sigmoid(x @ params["router"]["kernel"])
+    biased = s + params["e_score_correction_bias"]
+    _, idx = jax.lax.top_k(biased if choose_by == "biased" else s, K)
+    g = jnp.take_along_axis(biased if weigh_by == "biased" else s, idx, -1)
+    g = 2.5 * g / (g.sum(-1, keepdims=True) + 1e-20)
+    ex = params["experts"]
+    out = jnp.zeros_like(x)
+    for e in range(count):
+        w = jnp.sum(jnp.where(idx == first + e, g, 0.0), -1)
+        out = out + w[:, None] * swiglu(
+            x, ex["gate_proj"]["kernel"][e], ex["up_proj"]["kernel"][e],
+            ex["down_proj"]["kernel"][e])
+    if shared:
+        se = params["shared_expert"]
+        out = out + swiglu(x, se["gate_proj"]["kernel"], se["up_proj"]["kernel"],
+                           se["down_proj"]["kernel"])
+    return out
+
+
+@pytest.mark.parametrize("first,count", [(0, 8), (2, 4), (3, 1)])
+def test_the_sigmoid_router_matches_a_dense_loop(first, count):
+    m, params, x = make_sigmoid(first, count)
+    assert "shared_expert_gate" not in params
+    out, stats = m.apply({"params": params}, x)
+    ref = sigmoid_loop(params, x, first, count)
+    scale = float(jnp.max(jnp.abs(ref)))
+    assert float(jnp.max(jnp.abs(out - ref))) <= 1e-5 * scale
+    assert float(stats["dropped"]) == 0.0
+    # the bias is no decoration: dropping it from the choice, or weighing by
+    # the biased score, is another layer
+    routed = float(jnp.max(jnp.abs(ref - sigmoid_loop(params, x, 0, 0))))
+    for wrong in (dict(choose_by="unbiased"), dict(weigh_by="biased")):
+        other = sigmoid_loop(params, x, first, count, **wrong)
+        assert float(jnp.max(jnp.abs(out - other))) > 1e-2 * routed, wrong
+    f = lambda fn: lambda p, x: jnp.sum(jnp.sin(fn(p, x)))  # noqa: E731
+    got = jax.grad(f(lambda p, x: m.apply({"params": p}, x)[0]), (0, 1))(params, x)
+    want = jax.grad(f(lambda p, x: sigmoid_loop(p, x, first, count)), (0, 1))(params, x)
+    assert float(jnp.max(jnp.abs(got[0]["e_score_correction_bias"]))) == 0.0
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert float(jnp.max(jnp.abs(a - b))) <= 1e-4 * float(jnp.max(jnp.abs(b))) + 1e-6
+
+
+def test_the_sigmoid_shares_add_up_to_the_whole_layer():
+    """All 8 shares of one sparse layer of the latent decoder (one expert a
+    chip): their routed parts summed, with the ungated shared expert counted
+    once, are what the uncut reference gives for the whole layer."""
+    from benchmark.reference import joyai_llm_flash as ref
+    from benchmark.reference.numerics import Numerics
+
+    _, params, x = make_sigmoid(0, E, seed=3)
+    model = {"num_experts_per_tok": K, "routed_scaling_factor": 2.5}
+    whole = ref.sparse_ffn(model, Numerics(), x, params)   # all 8 held: uncut
+    shared = sigmoid_loop(params, x, 0, 0)                 # the shared part alone
+    total = shared
+    for chip in range(E):
+        mine = dict(params, experts=jax.tree.map(
+            lambda a: a[chip:chip + 1], params["experts"]))
+        part, stats = sigmoid_layer(chip, 1).apply({"params": mine}, x)
+        assert float(stats["dropped"]) == 0.0
+        total = total + (part - shared)
+        theirs = ref.sparse_ffn(model, Numerics(), x, mine, first=chip)
+        assert float(jnp.max(jnp.abs(part - theirs))) <= 1e-5 * float(
+            jnp.max(jnp.abs(theirs)))
+    assert float(jnp.max(jnp.abs(total - whole))) <= 1e-5 * float(
+        jnp.max(jnp.abs(whole)))
+
+
+def test_an_unknown_score_function_is_refused():
+    with pytest.raises(ValueError):
+        RoutedExperts(E, K, F, (0, E), score="tanh").init(
+            jax.random.key(0), jnp.zeros((4, D)))

@@ -8,9 +8,10 @@ share one rule, and since round 5 the rule is MEASUREMENT-BACKED per
 shape family (VERDICT r4 #5 — the round-4 UNet regression showed a
 size threshold alone dispatches flash where it loses):
 
-* ``should_use_flash(s, d=..., dtype=...)`` — False off-TPU or below
-  ``flash_threshold()``; above it, consult the tune table's measured
-  dense/flash ratio for the (S, D, dtype) family
+* ``should_use_flash(s, d=..., dtype=..., dv=...)`` — False off-TPU or
+  below ``flash_threshold()``; above it, consult the tune table's measured
+  dense/flash ratio for the (S, D, dtype) family, ``D`` read ``<D>v<DV>``
+  where values and keys differ in head size
   (``flash_autotune.lookup_speedup``): tuned-and-winning → flash,
   tuned-and-losing → dense, never-measured → flash only at
   ``untuned_flash_min_s()`` and beyond (where dense is 15x slower or
@@ -90,10 +91,10 @@ def _warn_once_if_kind_untuned() -> None:
             "it wins.", stacklevel=3)
 
 
-def _evidence_says_flash(s: int, d, dtype, causal: bool) -> bool:
+def _evidence_says_flash(s: int, d, dtype, causal: bool, dv=None) -> bool:
     """Measurement-backed dispatch core (VERDICT r4 #5): consult the
     tune table's measured dense/flash ratio for this (S, D, dtype)
-    family. Tuned and winning (>=5%) → flash; tuned and losing → dense;
+    family (``dv``: the values' head size where it is not ``d``). Tuned and winning (>=5%) → flash; tuned and losing → dense;
     never measured → flash only past ``untuned_flash_min_s``."""
     if d is None:
         # Legacy call sites without a head-dim: length threshold only
@@ -101,7 +102,7 @@ def _evidence_says_flash(s: int, d, dtype, causal: bool) -> bool:
         return True
     from tpucfn.kernels.flash_autotune import lookup_speedup
 
-    speedup = lookup_speedup(int(s), int(d), dtype, causal)
+    speedup = lookup_speedup(int(s), int(d), dtype, causal, dv)
     if speedup is not None:
         return speedup >= 1.05
     if int(s) < untuned_flash_min_s():
@@ -111,7 +112,8 @@ def _evidence_says_flash(s: int, d, dtype, causal: bool) -> bool:
 
 
 def should_use_flash(s: int, *, causal: bool = True, mask=None,
-                     d: int | None = None, dtype=None) -> bool:
+                     d: int | None = None, dtype=None,
+                     dv: int | None = None) -> bool:
     """One policy for every dispatch site. ``s`` must be a static int
     (trace-time shape). Pass ``d``/``dtype`` (the head dim and element
     type) so the decision can consult MEASURED per-family evidence —
@@ -120,11 +122,12 @@ def should_use_flash(s: int, *, causal: bool = True, mask=None,
         return False  # kernel supports causal/segment masking only
     if _backend() != "tpu" or int(s) < flash_threshold():
         return False
-    return _evidence_says_flash(s, d, dtype, causal=True)
+    return _evidence_says_flash(s, d, dtype, causal=True, dv=dv)
 
 
 def should_use_flash_full(s_q: int, s_kv: int, *, mask=None,
-                          d: int | None = None, dtype=None) -> bool:
+                          d: int | None = None, dtype=None,
+                          dv: int | None = None) -> bool:
     """Non-causal (full) attention policy: the dense path materializes a
     (B, H, s_q, s_kv) score tensor, so flash pays when BOTH sides are
     long (a 77-key cross-attention's scores are tiny — dense wins).
@@ -137,14 +140,14 @@ def should_use_flash_full(s_q: int, s_kv: int, *, mask=None,
     t = flash_threshold()
     if _backend() != "tpu" or int(s_q) < t or int(s_kv) < t:
         return False
-    return _evidence_says_flash(s_q, d, dtype, causal=False)
+    return _evidence_says_flash(s_q, d, dtype, causal=False, dv=dv)
 
 
 def full_attention_auto(q, k, v, *, mask=None):
     """Dense↔flash dispatch for non-causal attention call sites (UNet
     spatial/cross attention). Layout (B, S, H, D) like every AttentionFn."""
     if should_use_flash_full(q.shape[1], k.shape[1], mask=mask,
-                             d=q.shape[-1], dtype=q.dtype):
+                             d=q.shape[-1], dtype=q.dtype, dv=v.shape[-1]):
         from tpucfn.kernels.flash_attention import flash_attention
 
         return flash_attention(q, k, v, causal=False)
@@ -162,7 +165,8 @@ def auto_attention_static_zero(q, k, v, *, causal=True, mask=None,
     static offsets. The caller is responsible for only installing this
     where q_offset/k_offset are provably zero."""
     if mask is None and should_use_flash(q.shape[1], causal=causal,
-                                         d=q.shape[-1], dtype=q.dtype):
+                                         d=q.shape[-1], dtype=q.dtype,
+                                         dv=v.shape[-1]):
         from tpucfn.kernels.flash_attention import flash_attention
 
         return flash_attention(q, k, v, causal=causal)
@@ -184,7 +188,7 @@ def auto_attention(q, k, v, *, causal=True, mask=None, q_offset=0,
     static_offsets = isinstance(q_offset, int) and isinstance(k_offset, int)
     if static_offsets and should_use_flash(q.shape[1], causal=causal,
                                            mask=mask, d=q.shape[-1],
-                                           dtype=q.dtype):
+                                           dtype=q.dtype, dv=v.shape[-1]):
         return flash_attention(q, k, v, causal=causal, q_offset=q_offset,
                                k_offset=k_offset, segment_ids=segment_ids)
     if segment_ids is not None:

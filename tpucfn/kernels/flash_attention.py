@@ -64,6 +64,17 @@ are sliced off — their backward contributions are provably zero because
 TPUCFN_FLASH_BLOCK_Q/_K, else the measured table of
 :mod:`tpucfn.kernels.flash_autotune`, else 128/128 (:func:`_choose_blocks`).
 
+**Values narrower than keys.** ``v`` (and so ``o``, ``do``, ``dv``) may
+have another head size than ``q`` and ``k`` (latent attention: keys of 192,
+values of 128): every array has block specs and accumulators of its own
+width, and the scale is the keys'. A key size past the lanes that is no
+multiple of them (192 = 128 + 64) goes to the MXU as it is, one 192-wide
+product: on a v5e the 128 and the 64 as two products into one score read
+the same, and keys zero-padded to 256 make the three kernels 10% faster
+(the re-tiling of a half-filled lane tile goes) but cost more than that in
+the padding of q and k and the slicing of their gradients around every
+call (PERF.md, PR 31).
+
 Layout: (B, H, S, D) inside the kernels — S×D trailing tiles are what
 the MXU wants. The public wrapper takes the framework-standard
 (B, S, H, D).
@@ -288,7 +299,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, grid: _Grid, scale):
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     def step(masked: bool):
-        k, v = k_ref[0, 0], v_ref[0, 0]                     # (BK, D)
+        k, v = k_ref[0, 0], v_ref[0, 0]                # (BK, D), (BK, DV)
         for rows in _row_chunks(grid.block_q):
             s = _product(q_ref[0, 0, rows, :], k, _NT) * scale  # (rows, BK)
             if masked:
@@ -324,19 +335,20 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, grid: _Grid, scale):
 
 def _flash_fwd(q, k, v, q_seg, kv_seg, *, causal, q_offset, k_offset,
                kv_len, block_sizes, interpret):
-    """q: (B, H, SQ, D); k/v: (B, HKV, SK, D) → (o, lse[B,H,SQ,LANES]).
+    """q: (B, H, SQ, D); k: (B, HKV, SK, D); v: (B, HKV, SK, DV) →
+    (o[B,H,SQ,DV], lse[B,H,SQ,LANES]).
 
     SQ/SK already padded to block multiples; kv_len = true key count."""
     b, h, sq, d = q.shape
-    hkv, sk = k.shape[1], k.shape[2]
+    hkv, sk, dv = k.shape[1], k.shape[2], v.shape[-1]
     rep = h // hkv
     block_q, block_k = block_sizes
     have_segs = q_seg is not None
     grid = _Grid(causal, block_q, block_k, q_offset, k_offset, kv_len, sk,
                  have_segs)
 
-    qspec, kspec, qrow = _query_major_specs(grid, rep, d)
-    in_specs = [qspec, kspec, kspec]
+    qspec, kspec, vspec, ospec, qrow = _query_major_specs(grid, rep, d, dv)
+    in_specs = [qspec, kspec, vspec]
     args = [q, k, v]
     if have_segs:
         seg_specs, seg_args = _seg_operands(grid, q_seg, kv_seg)
@@ -347,13 +359,13 @@ def _flash_fwd(q, k, v, q_seg, kv_seg, *, causal, q_offset, k_offset,
         functools.partial(_fwd_kernel, grid=grid, scale=d ** -0.5),
         grid=(b, h, sq // block_q, sk // block_k),
         in_specs=in_specs,
-        out_specs=[qspec, qrow],
+        out_specs=[ospec, qrow],
         out_shape=[
-            jax.ShapeDtypeStruct((b, h, sq, d), q.dtype),
+            jax.ShapeDtypeStruct((b, h, sq, dv), q.dtype),
             jax.ShapeDtypeStruct((b, h, sq, LANES), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, dv), jnp.float32),
             pltpu.VMEM((block_q, LANES), jnp.float32),
             pltpu.VMEM((block_q, LANES), jnp.float32),
         ],
@@ -379,19 +391,21 @@ def _sublanes(x):
                             (*x.shape[:-1], SUBLANES, x.shape[-1]))
 
 
-def _query_major_specs(grid: _Grid, rep: int, d: int):
+def _query_major_specs(grid: _Grid, rep: int, d: int, dv: int):
     """Block specs on the grid (b, h, qi, ki) of the forward and the query
-    backward: a q-shaped array, a k-shaped one (GQA: head ``hi // rep``,
-    its block held at the last one needed) and a (SQ, LANES) row
-    quantity."""
-    qspec = pl.BlockSpec((1, 1, grid.block_q, d),
-                         lambda bi, hi, qi, ki: (bi, hi, qi, 0))
-    kspec = pl.BlockSpec(
-        (1, 1, grid.block_k, d),
-        lambda bi, hi, qi, ki: (bi, hi // rep, grid.last_needed_k(qi, ki), 0))
-    qrow = pl.BlockSpec((1, 1, grid.block_q, LANES),
-                        lambda bi, hi, qi, ki: (bi, hi, qi, 0))
-    return qspec, kspec, qrow
+    backward: a q-shaped array, a k-shaped and a v-shaped one (GQA: head
+    ``hi // rep``, its block held at the last one needed), an o-shaped one
+    (a query's rows at the values' width) and a (SQ, LANES) row quantity."""
+    def query(width):
+        return pl.BlockSpec((1, 1, grid.block_q, width),
+                            lambda bi, hi, qi, ki: (bi, hi, qi, 0))
+
+    def key(width):
+        return pl.BlockSpec(
+            (1, 1, grid.block_k, width), lambda bi, hi, qi, ki: (
+                bi, hi // rep, grid.last_needed_k(qi, ki), 0))
+
+    return query(d), key(d), key(dv), query(dv), query(LANES)
 
 
 def _seg_operands(grid: _Grid, q_seg, kv_seg):
@@ -501,8 +515,9 @@ def _flash_dq(q, k, v, do, lse, delta, q_seg, kv_seg, *, grid: _Grid,
     b, h, sq, d = q.shape
     rep, sk = h // k.shape[1], k.shape[2]
     block_q, block_k = grid.block_q, grid.block_k
-    qspec, kspec, qrow = _query_major_specs(grid, rep, d)
-    in_specs = [qspec, kspec, kspec, qspec, qrow, qrow]
+    qspec, kspec, vspec, ospec, qrow = _query_major_specs(
+        grid, rep, d, v.shape[-1])
+    in_specs = [qspec, kspec, vspec, ospec, qrow, qrow]
     args = [q, k, v, do, lse, delta]
     if grid.have_segs:
         seg_specs, seg_args = _seg_operands(grid, q_seg, kv_seg)
@@ -530,7 +545,7 @@ def _flash_dkv(q, k, v, do, lse, delta, q_seg, kv_seg, *, grid: _Grid,
     stays resident for its whole accumulation. ``lse`` and ``delta``:
     (B, H, SUBLANES, SQ), sublane-replicated."""
     b, h, sq, d = q.shape
-    hkv, sk = k.shape[1], k.shape[2]
+    hkv, sk, dv = k.shape[1], k.shape[2], v.shape[-1]
     rep = h // hkv
     block_q, block_k = grid.block_q, grid.block_k
     nq = sq // block_q
@@ -541,11 +556,15 @@ def _flash_dkv(q, k, v, do, lse, delta, q_seg, kv_seg, *, grid: _Grid,
     def q_row_map(bi, hk, ki, ri, qi):
         return (bi, hk * rep + ri, 0, grid.first_needed_q(qi, ki, nq))
 
+    def k_map(bi, hk, ki, ri, qi):
+        return (bi, hk, ki, 0)
+
     qspec = pl.BlockSpec((1, 1, block_q, d), q_map)
-    kspec = pl.BlockSpec((1, 1, block_k, d),
-                         lambda bi, hk, ki, ri, qi: (bi, hk, ki, 0))
+    dospec = pl.BlockSpec((1, 1, block_q, dv), q_map)
+    kspec = pl.BlockSpec((1, 1, block_k, d), k_map)
+    vspec = pl.BlockSpec((1, 1, block_k, dv), k_map)
     qrow = pl.BlockSpec((1, 1, SUBLANES, block_q), q_row_map)
-    in_specs = [qspec, kspec, kspec, qspec, qrow, qrow]
+    in_specs = [qspec, kspec, vspec, dospec, qrow, qrow]
     args = [q, k, v, do, lse, delta]
     if grid.have_segs:
         in_specs += [
@@ -560,14 +579,14 @@ def _flash_dkv(q, k, v, do, lse, delta, q_seg, kv_seg, *, grid: _Grid,
         functools.partial(_dkv_kernel, grid=grid, scale=d ** -0.5),
         grid=(b, hkv, sk // block_k, rep, nq),
         in_specs=in_specs,
-        out_specs=[kspec, kspec],
+        out_specs=[kspec, vspec],
         out_shape=[
             jax.ShapeDtypeStruct((b, hkv, sk, d), k.dtype),
-            jax.ShapeDtypeStruct((b, hkv, sk, d), v.dtype),
+            jax.ShapeDtypeStruct((b, hkv, sk, dv), v.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
+            pltpu.VMEM((block_k, dv), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
@@ -579,10 +598,10 @@ def _flash_dkv(q, k, v, do, lse, delta, q_seg, kv_seg, *, grid: _Grid,
 
 def _flash_bwd(q, k, v, o, lse, do, q_seg, kv_seg, *, causal, q_offset,
                k_offset, kv_len, block_sizes, interpret, dlse=None):
-    """q/do: (B, H, SQ, D); k/v: (B, HKV, SK, D) — KV stays un-repeated;
-    ``lse``: (B, H, SQ, LANES) as the forward wrote it. ``dlse`` (B, H, SQ)
-    is the LSE-output cotangent for the with-lse variant (ring hops); None
-    when only O was consumed."""
+    """q: (B, H, SQ, D); k: (B, HKV, SK, D); v: (B, HKV, SK, DV); o/do:
+    (B, H, SQ, DV) — KV stays un-repeated; ``lse``: (B, H, SQ, LANES) as
+    the forward wrote it. ``dlse`` (B, H, SQ) is the LSE-output cotangent
+    for the with-lse variant (ring hops); None when only O was consumed."""
     grid = _Grid(causal, *block_sizes, q_offset, k_offset, kv_len,
                  k.shape[2], q_seg is not None)
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
@@ -685,7 +704,8 @@ def _prep_inputs(q, k, v, block_q, block_k, interpret, causal=True):
     if block_q is None or block_k is None:
         # Only consult env/tuned defaults when actually needed — a bad
         # cached entry must not break calls that pinned their blocks.
-        bq0, bk0 = _choose_blocks(sq, q.shape[-1], q.dtype, causal)
+        bq0, bk0 = _choose_blocks(sq, q.shape[-1], q.dtype, causal,
+                                  v.shape[-1])
     else:
         bq0 = bk0 = None
     blk_q, sq_pad = _block_and_pad(sq, block_q or bq0)
@@ -699,7 +719,7 @@ def _prep_inputs(q, k, v, block_q, block_k, interpret, causal=True):
 def flash_attention_with_lse(
     q: jax.Array,  # (B, SQ, H, D)
     k: jax.Array,  # (B, SK, HKV, D)
-    v: jax.Array,
+    v: jax.Array,  # (B, SK, HKV, DV); DV may differ from D
     *,
     causal: bool = True,
     q_offset: int = 0,
@@ -708,7 +728,7 @@ def flash_attention_with_lse(
     block_k: int | None = None,
     interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array]:
-    """(out (B,SQ,H,D), lse (B,SQ,H)) — the flash counterpart of
+    """(out (B,SQ,H,DV), lse (B,SQ,H)) — the flash counterpart of
     :func:`tpucfn.ops.attention.dot_product_attention_with_lse`, for
     ring-attention hops (rows attending to nothing give lse = NEG_INF).
     Differentiable in both outputs."""
@@ -738,7 +758,8 @@ def _check_block(value: int, origin: str) -> int:
     return value
 
 
-def _choose_blocks(sq: int, d: int, dtype, causal: bool) -> tuple[int, int]:
+def _choose_blocks(sq: int, d: int, dtype, causal: bool,
+                   dv: int | None = None) -> tuple[int, int]:
     """Default block selection when the caller passed none: env override
     (explicit experiment control) > the measured table (flash_autotune:
     the packaged rows of ``flash_tune_builtin.json`` under a user's own
@@ -754,7 +775,7 @@ def _choose_blocks(sq: int, d: int, dtype, causal: bool) -> tuple[int, int]:
                 _check_block(envk or 128, "TPUCFN_FLASH_BLOCK_K"))
     from tpucfn.kernels import flash_autotune
 
-    hit = flash_autotune.lookup(sq, d, dtype, causal)
+    hit = flash_autotune.lookup(sq, d, dtype, causal, dv)
     if hit:
         return (_check_block(hit[0], "tuned block_q"),
                 _check_block(hit[1], "tuned block_k"))
@@ -764,7 +785,7 @@ def _choose_blocks(sq: int, d: int, dtype, causal: bool) -> tuple[int, int]:
 def flash_attention(
     q: jax.Array,  # (B, SQ, H, D) — framework-standard layout
     k: jax.Array,  # (B, SK, HKV, D)
-    v: jax.Array,
+    v: jax.Array,  # (B, SK, HKV, DV) → out (B, SQ, H, DV)
     *,
     causal: bool = True,
     mask: jax.Array | None = None,

@@ -13,7 +13,9 @@ and no way to learn better ones. This module adds the missing piece:
 
 The table is keyed by (device_kind, causal, S-bucket, D, dtype) where
 the S bucket is the next power of two — one tuning run covers the
-nearby shape family. Cache file: ``~/.tpucfn/flash_tune.json``
+nearby shape family. ``D`` is the head size; where the values are
+narrower or wider than the keys (latent attention: 192 / 128) it reads
+``<D>v<DV>``, so the rows of equal sizes keep their names. Cache file: ``~/.tpucfn/flash_tune.json``
 (``TPUCFN_FLASH_TUNE_CACHE`` overrides; delete it to re-tune).
 
 The reference delegated this entirely to cuDNN's internal heuristics
@@ -48,11 +50,13 @@ def _bucket(s: int) -> int:
     return b
 
 
-def _key(device_kind: str, causal: bool, s: int, d: int, dtype) -> str:
+def _key(device_kind: str, causal: bool, s: int, d: int, dtype,
+         dv: int | None = None) -> str:
     import numpy as np
 
+    head = str(d) if dv in (None, d) else f"{d}v{dv}"
     return "|".join([device_kind, "causal" if causal else "full",
-                     str(_bucket(s)), str(d), str(np.dtype(dtype))])
+                     str(_bucket(s)), head, str(np.dtype(dtype))])
 
 
 def _read_table(path: Path) -> dict[str, tuple]:
@@ -99,11 +103,12 @@ def _save(cache: dict[str, tuple[int, int]]) -> None:
     os.replace(tmp, p)
 
 
-def _entry(s: int, d: int, dtype, causal: bool) -> tuple | None:
+def _entry(s: int, d: int, dtype, causal: bool,
+           dv: int | None = None) -> tuple | None:
     import jax
 
     kind = jax.devices()[0].device_kind
-    return _load().get(_key(kind, causal, s, d, dtype))
+    return _load().get(_key(kind, causal, s, d, dtype, dv))
 
 
 def kind_has_entries(device_kind: str) -> bool:
@@ -116,19 +121,22 @@ def kind_has_entries(device_kind: str) -> bool:
     return any(k.startswith(prefix) for k in _load())
 
 
-def lookup(s: int, d: int, dtype, causal: bool) -> tuple[int, int] | None:
+def lookup(s: int, d: int, dtype, causal: bool,
+           dv: int | None = None) -> tuple[int, int] | None:
     """Best known (block_q, block_k) for this shape family on the
-    current device, or None. Trace-time safe (no device work)."""
-    e = _entry(s, d, dtype, causal)
+    current device, or None. Trace-time safe (no device work). ``dv`` is
+    the values' head size where it is not ``d``."""
+    e = _entry(s, d, dtype, causal, dv)
     return None if e is None else tuple(e[:2])
 
 
-def lookup_speedup(s: int, d: int, dtype, causal: bool) -> float | None:
+def lookup_speedup(s: int, d: int, dtype, causal: bool,
+                   dv: int | None = None) -> float | None:
     """MEASURED fwd+bwd speedup of tuned flash over XLA dense for this
     shape family on the current device — the evidence
     ``kernels.auto``'s dispatch consults (VERDICT r4 #5). None when the
     family was never tuned against dense (incl. legacy 2-entry rows)."""
-    e = _entry(s, d, dtype, causal)
+    e = _entry(s, d, dtype, causal, dv)
     if e is None or len(e) < 3 or e[2] is None:
         return None
     return float(e[2])
@@ -138,6 +146,7 @@ def tune(
     s: int,
     d: int = 128,
     *,
+    dv: int | None = None,
     heads: int = 8,
     kv_heads: int = 8,
     batch: int = 1,
@@ -164,7 +173,7 @@ def tune(
     kq, kk, kv = jax.random.split(jax.random.key(0), 3)
     q = jax.random.normal(kq, (batch, s, heads, d), dtype)
     k = jax.random.normal(kk, (batch, s, kv_heads, d), dtype)
-    v = jax.random.normal(kv, (batch, s, kv_heads, d), dtype)
+    v = jax.random.normal(kv, (batch, s, kv_heads, dv or d), dtype)
 
     def timed(fn, *args):
         jax.block_until_ready(fn(*args))  # compile
@@ -226,7 +235,7 @@ def tune(
         except Exception as e:  # noqa: BLE001 — dense OOM at long S
             dense_ms = f"error: {repr(e)[:160]}"
 
-    key = _key(jax.devices()[0].device_kind, causal, s, d, dtype)
+    key = _key(jax.devices()[0].device_kind, causal, s, d, dtype, dv)
     if persist:
         global _MEM_CACHE
         user = _read_table(_cache_path())

@@ -90,6 +90,9 @@ class HybridConfig:
     def periods(self) -> int:
         return self.n_layers // self.full_attention_interval
 
+    def layer_plan(self) -> "LayerPlan":
+        return LayerPlan(HybridPeriod, self.periods, ZeroCentredRMSNorm)
+
     @classmethod
     def tiny(cls, vocab: int = 256) -> "HybridConfig":
         return cls(vocab_size=vocab, dim=64, n_layers=4, n_heads=4,
@@ -251,8 +254,30 @@ class HybridPeriod(nn.Module):
         return x, jax.tree.map(lambda a, b: jnp.append(a, b), linear, full)
 
 
-class HybridDecoder(nn.Module):
-    cfg: HybridConfig
+@dataclasses.dataclass(frozen=True)
+class LayerPlan:
+    """What a decoder is made of between its embedding and its head: layers
+    of their own before the trunk, a scanned run of one period, and a block
+    after the trunk.  Every entry is a module class called ``(cfg,
+    attention_fn, name=...)``; a leading layer and the period take and return
+    scan's ``(carry, _) -> (carry, counters)``."""
+
+    period: Any                     # scanned ``periods`` times under ``trunk``
+    periods: int
+    norm: Any                       # ``(eps, dtype, name=...)``: the final norm
+    trunk: str = "periods"
+    leading: tuple[tuple[str, Any], ...] = ()   # (name, module), in order
+    # ``(x, tokens, embed) -> (hidden, counters)`` from the trunk's output
+    # before the final norm, the tokens and the embedding module itself
+    after: tuple[str, Any] | None = None
+
+
+class PlannedDecoder(nn.Module):
+    """Embedding, the layers of ``cfg.layer_plan()``, final norm, untied head:
+    the one decoder ``HybridDecoder`` and ``models/latent.LatentDecoder``
+    are."""
+
+    cfg: Any
     # None = the automatic dense/flash dispatch of kernels/auto.py
     attention_fn: AttentionFn | None = None
 
@@ -261,29 +286,50 @@ class HybridDecoder(nn.Module):
         """tokens (B, S) -> (logits (B, S, vocab) float32, counters), or the
         final hidden states in place of the logits with ``return_hidden``
         (pair it with ``chunked_causal_lm_loss``).  ``counters`` maps each of
-        the feed-forward's routing counters to one value a layer, in order."""
+        the feed-forward's routing counters to one value a sparse layer, in
+        order.  Where the plan has a block after the trunk, the hidden states
+        are a pair (the trunk's, that block's) and so are the logits: the
+        head is one module, called twice."""
         cfg = self.cfg
+        plan: LayerPlan = cfg.layer_plan()
         attention_fn = self.attention_fn
         if attention_fn is None:
             from tpucfn.kernels.auto import auto_attention_static_zero
 
             attention_fn = auto_attention_static_zero
-        x = nn.Embed(cfg.vocab_size, cfg.dim, dtype=cfg.dtype,
-                     param_dtype=cfg.param_dtype, name="embed_tokens",
-                     embedding_init=nn.initializers.normal(0.02))(tokens)
-        x, counters = nn.scan(
-            HybridPeriod, variable_axes={"params": 0},
-            split_rngs={"params": True}, length=cfg.periods,
-        )(cfg, attention_fn, name="periods")(x)
-        counters = jax.tree.map(lambda a: a.reshape(-1), counters)
-        x = ZeroCentredRMSNorm(cfg.norm_eps, cfg.dtype, name="final_norm")(x)
+        embed = nn.Embed(cfg.vocab_size, cfg.dim, dtype=cfg.dtype,
+                         param_dtype=cfg.param_dtype, name="embed_tokens",
+                         embedding_init=nn.initializers.normal(0.02))
+        x = embed(tokens)
+        found = []
+        for name, layer in plan.leading:
+            x, c = layer(cfg, attention_fn, name=name)(x)
+            found.append(c)
+        x, c = nn.scan(
+            plan.period, variable_axes={"params": 0},
+            split_rngs={"params": True}, length=plan.periods,
+        )(cfg, attention_fn, name=plan.trunk)(x)
+        found.append(c)
+        hidden = plan.norm(cfg.norm_eps, cfg.dtype, name="final_norm")(x)
+        if plan.after is not None:
+            name, block = plan.after
+            second, c = block(cfg, attention_fn, name=name)(x, tokens, embed)
+            found.append(c)
+            hidden = (hidden, second)
+        counters = jax.tree.map(
+            lambda *parts: jnp.concatenate([p.reshape(-1) for p in parts]),
+            *[c for c in found if c])
         if return_hidden:
-            return x, counters
-        logits = nn.DenseGeneral(
+            return hidden, counters
+        head = nn.DenseGeneral(
             cfg.vocab_size, use_bias=False, dtype=jnp.float32,
             param_dtype=cfg.param_dtype, name="lm_head",
-            kernel_init=nn.initializers.normal(0.02))(x.astype(jnp.float32))
-        return logits, counters
+            kernel_init=nn.initializers.normal(0.02))
+        return jax.tree.map(lambda h: head(h.astype(jnp.float32)), hidden), counters
+
+
+class HybridDecoder(PlannedDecoder):
+    """The period-scanned decoder of this module (``HybridConfig``)."""
 
 
 def make_loss_fn(model: HybridDecoder, *, ce_chunk: int = 512):

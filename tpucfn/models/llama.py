@@ -277,8 +277,14 @@ def chunked_causal_lm_loss(
     *,
     chunk_size: int = 512,
     z_loss: float = 0.0,
+    ahead: int = 1,
 ) -> tuple[jax.Array, jax.Array]:
     """Next-token CE + accuracy WITHOUT materializing (B, S, V) logits.
+
+    ``ahead`` is how many positions on a position's target lies: 1 is the
+    next token; a multi-token-prediction head passes 2 for the token after
+    next, and the last ``ahead`` positions, which have no target, are left
+    out of the mean.
 
     Numerically equal to ``causal_lm_loss(hidden @ W, tokens)`` (tests
     assert values and grads): a ``lax.scan`` over sequence chunks
@@ -293,9 +299,9 @@ def chunked_causal_lm_loss(
     import optax
 
     b, s, _ = hidden.shape
-    n = s - 1
-    pred = hidden[:, :-1]
-    targets = tokens[:, 1:]
+    n = s - ahead
+    pred = hidden[:, :-ahead]
+    targets = tokens[:, ahead:]
     c = max(1, min(chunk_size, n))
     pad = (-n) % c
     if pad:

@@ -402,17 +402,28 @@ class RoutedExperts(nn.Module):
 
     The layer is told which experts it holds: ``held = (first, count)`` of
     the ``n_experts`` the router scores.  The router keeps its full width
-    and its ``top_k`` a token (softmax in float32 over all experts, the
-    chosen weights renormalised to sum 1); the ``T x top_k`` assignments are
-    sorted by expert, those that fall on held experts first, and three
-    grouped matrix products (``jax.lax.ragged_dot``) compute the SwiGLU
+    and its ``top_k`` a token, in float32 at all passes, and is one of two,
+    as a model's configuration says:
+
+    * ``score="softmax"`` (Qwen3-Next): softmax over all experts, the
+      ``top_k`` largest chosen and renormalised to sum 1;
+    * ``score="sigmoid"`` (DeepSeek-V3, JoyAI-LLM-Flash): a sigmoid of each
+      logit; with ``select_bias`` the chosen set is the ``top_k`` largest of
+      score plus a per-expert bias (the leaf ``e_score_correction_bias``,
+      which enters the choice only, so no gradient reaches it), and the
+      weights are the *unbiased* scores of the chosen over their sum.
+
+    Either way the weights are multiplied by ``weight_scale``.  The
+    ``T x top_k`` assignments are sorted by expert, those that fall on held
+    experts first, and three grouped matrix products (``jax.lax.ragged_dot``) compute the SwiGLU
     experts on exactly those rows.  The result is the held experts' part of
     the layer's sum: what the other experts would add is another chip's
     part, and no exchange is made here.  There is no capacity and no dropped
     token: shapes are static and cover the case in which every assignment
     falls here, a block of rows at a time, blocks past the last held row
-    skipped.  With ``shared_dim`` the shared expert and its sigmoid gate
-    are computed whole, as on every chip of the deployment.
+    skipped.  With ``shared_dim`` the shared expert is computed whole, as
+    on every chip of the deployment, times a sigmoid gate of its own
+    (``shared_gate``, Qwen3-Next) or as it is (DeepSeek-V3).
 
     Returns ``(out, stats)``; ``stats`` holds float32 scalars: ``rows``
     (assignments that fell on held experts), ``load_max_over_mean`` (the
@@ -428,12 +439,18 @@ class RoutedExperts(nn.Module):
     shared_dim: int = 0
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
+    score: str = "softmax"          # "softmax" | "sigmoid"
+    select_bias: bool = False
+    weight_scale: float = 1.0
+    shared_gate: bool = True
 
     @nn.compact
     def __call__(self, x):
         first, count = self.held
         if not (0 <= first and count > 0 and first + count <= self.n_experts):
             raise ValueError(f"held={self.held} of {self.n_experts} experts")
+        if self.score not in ("softmax", "sigmoid"):
+            raise ValueError(f"unknown router score {self.score!r}")
         k = self.top_k
         lead, d = x.shape[:-1], x.shape[-1]
         xt = x.reshape(-1, d)
@@ -445,8 +462,23 @@ class RoutedExperts(nn.Module):
         # a rounded logit moves a token's last expert: float32, all passes
         logits = jnp.matmul(xt.astype(jnp.float32), router.astype(jnp.float32),
                             precision=lax.Precision.HIGHEST)
-        gates, chosen = lax.top_k(jax.nn.softmax(logits, axis=-1), k)
-        gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+        if self.score == "softmax":
+            scores = jax.nn.softmax(logits, axis=-1)
+        else:
+            scores = jax.nn.sigmoid(logits)
+        if self.select_bias:
+            bias = self.param("e_score_correction_bias", nn.initializers.zeros,
+                              (self.n_experts,), jnp.float32)
+            _, chosen = lax.top_k(scores + bias, k)
+            gates = jnp.take_along_axis(scores, chosen, axis=-1)
+        else:
+            gates, chosen = lax.top_k(scores, k)
+        if self.score == "softmax":
+            gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+        else:   # as published: a guard under the sum of sigmoids
+            gates = gates / (jnp.sum(gates, axis=-1, keepdims=True) + 1e-20)
+        if self.weight_scale != 1.0:
+            gates = gates * self.weight_scale
 
         # assignments flat, slot-major (index j * t + token), sorted by
         # expert with those on experts held elsewhere last
@@ -505,10 +537,12 @@ class RoutedExperts(nn.Module):
 
             shared = SwiGLUMLP(self.shared_dim, self.dtype, self.param_dtype,
                                name="shared_expert")(xt)
-            gate = nn.sigmoid(nn.DenseGeneral(
-                1, use_bias=False, dtype=self.dtype,
-                param_dtype=self.param_dtype, name="shared_expert_gate")(xt))
-            out = out + (gate * shared).astype(jnp.float32)
+            if self.shared_gate:
+                shared = shared * nn.sigmoid(nn.DenseGeneral(
+                    1, use_bias=False, dtype=self.dtype,
+                    param_dtype=self.param_dtype,
+                    name="shared_expert_gate")(xt))
+            out = out + shared.astype(jnp.float32)
 
         sizes_f = sizes.astype(jnp.float32)
         stats = {
