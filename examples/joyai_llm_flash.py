@@ -15,8 +15,8 @@ another chip's part), one of eight slices of the vocabulary.  ``ep8`` holds 32
 experts and needs 14.8 of one v5e chip's 15.75 GB at 2 x 8,192 tokens.  The
 expert layer makes no exchange here.  ``--model tiny`` runs the identical
 program shape on CPU/CI.  The step's counters (``lm_loss``, ``mtp_loss``,
-``moe_rows``, ``moe_load_max_over_mean``, ``moe_dropped``) go to the log, the
-trace (``step_metrics``) and the ``train_*`` gauges.
+``moe_rows``, ``moe_load_max_over_mean``, ``moe_dropped``, ``moe_blocks_run``)
+go to the log, the trace (``step_metrics``) and the ``train_*`` gauges.
 """
 
 from __future__ import annotations

@@ -12,8 +12,8 @@ router keeps its 512 outputs and its 10 a token, and what the other experts
 would add is another chip's part), one of eight slices of the vocabulary.
 The expert layer makes no exchange here.  ``--model tiny`` runs the
 identical program shape on CPU/CI.  The step's routing counters
-(``moe_rows``, ``moe_load_max_over_mean``, ``moe_dropped``) go to the log, the
-trace (``step_metrics``) and the ``train_moe_*`` gauges.
+(``moe_rows``, ``moe_load_max_over_mean``, ``moe_dropped``, ``moe_blocks_run``)
+go to the log, the trace (``step_metrics``) and the ``train_moe_*`` gauges.
 """
 
 from __future__ import annotations

@@ -14,8 +14,8 @@ import pytest
 REPO = Path(__file__).resolve().parent.parent
 
 # The builders' on-chip tools at the smallest sizes their arguments allow: a
-# rename in ops/gated_delta.py or kernels/flash_attention.py is found here and
-# not on chip time.  (rows, keys every row carries, arguments)
+# rename in ops/gated_delta.py, kernels/flash_attention.py or models/moe.py is
+# found here and not on chip time.  (rows, keys every row carries, arguments)
 KERNEL_TOOLS = {
     "gdn_bench.py": (
         4, ("path", "pass", "pallas", "median_ms", "batch", "seq",
@@ -27,6 +27,12 @@ KERNEL_TOOLS = {
             "bwd_flash_ms", "bwd_dense_ms", "speedup_fwd", "speedup_bwd"),
         ["--seqs", "128", "--batch", "1", "--heads", "2", "--kv-heads", "1",
          "--head-dim", "32", "--iters", "1"]),
+    "moe_bench.py": (
+        6, ("preset", "load", "pass", "median_ms", "rows", "blocks_run",
+            "dropped", "blocks", "block", "tokens", "dim", "held", "experts",
+            "ffn_dim", "top_k", "device"),
+        ["--preset", "joyai-mla-s8192", "--tokens", "64", "--dim", "32",
+         "--ffn-dim", "16", "--iters", "1"]),
 }
 
 
@@ -52,6 +58,15 @@ def test_kernel_tool_prints_its_documented_rows(tool):
         # off a TPU both paths are the jnp preparation, and the row says so
         assert not any(row["pallas"] for row in rows)
         assert {row["device"] for row in rows} == {"cpu"}
+    if tool == "moe_bench.py":
+        # 64 tokens x 8 in blocks of 64: half a block, one and a half, all 8
+        assert [(row["load"], row["pass"], row["rows"], row["blocks_run"])
+                for row in rows] == [
+            (load, name, n, run) for load, n, run in (
+                ("one_block", 32, 1), ("two_blocks", 96, 2), ("all_blocks", 512, 8))
+            for name in ("fwd", "fwd_bwd")]
+        assert {(row["blocks"], row["block"], row["dropped"]) for row in rows} == {
+            (8, 64, 0)}
 
 
 def test_flash_bench_presets_are_the_flash_cells_shapes():
@@ -74,6 +89,27 @@ def test_flash_bench_presets_are_the_flash_cells_shapes():
             want.update(head_dim=model["qk_head_dim"],
                         value_dim=model["v_head_dim"])
         assert shape == want, name
+
+
+def test_moe_bench_presets_are_the_sparse_cells_shapes():
+    """``--preset`` names a benchmark cell and times the expert layer at its
+    shape: the shapes are the cell's configuration and traffic files'."""
+    sys.path.insert(0, str(REPO))
+    from benches import moe_bench
+
+    from benchmark import run
+
+    held = {"joyai-mla-s8192": "n_routed_experts",
+            "qwen3next-ep8-s8192": "num_experts"}
+    for name, shape in moe_bench.PRESETS.items():
+        cell = run.load_cell(name, False)
+        model, mix = cell["config"]["model"], cell["mix"]["shape"]
+        assert shape["tokens"] == mix["batch"] * mix["seq_len"], name
+        assert (shape["dim"], shape["ffn_dim"], shape["top_k"]) == (
+            model["hidden_size"], model["moe_intermediate_size"],
+            model["num_experts_per_tok"]), name
+        assert (shape["held"], shape["experts"]) == (
+            model[held[name]], model["router_experts"]), name
 
 
 def test_flash_bench_times_each_kernel_beside_its_roofline():

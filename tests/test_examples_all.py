@@ -84,7 +84,7 @@ def test_qwen3_next_moe_example(tmp_path):
     lines = [x for x in rows if x["name"] == "step_metrics"]
     assert [x["trace_id"] for x in lines] == [1, 2, 3]
     assert all(x["attrs"]["moe_dropped"] == 0.0 and x["attrs"]["moe_rows"] > 0
-               for x in lines)
+               and x["attrs"]["moe_blocks_run"] >= 1.0 for x in lines)
 
 
 def test_joyai_llm_flash_example(tmp_path):
@@ -99,7 +99,8 @@ def test_joyai_llm_flash_example(tmp_path):
     lines = [x for x in rows if x["name"] == "step_metrics"]
     assert [x["trace_id"] for x in lines] == [1, 2, 3]
     assert all(x["attrs"]["moe_dropped"] == 0.0 and x["attrs"]["mtp_loss"] > 0
-               and x["attrs"]["lm_loss"] > 0 for x in lines)
+               and x["attrs"]["lm_loss"] > 0
+               and x["attrs"]["moe_blocks_run"] >= 1.0 for x in lines)
 
 
 def test_sd15_unet_example(tmp_path):

@@ -115,11 +115,13 @@ def test_it_trains_through_the_trainer_and_counts_its_routing():
         losses.append(float(m["loss"]))
     assert losses[-1] < losses[0]
     c = m["counters"]
-    assert set(c) == {"moe_rows", "moe_load_max_over_mean", "moe_dropped"}
+    assert set(c) == {"moe_rows", "moe_load_max_over_mean", "moe_dropped",
+                      "moe_blocks_run"}
     # all 8 experts held: every assignment falls here, none is lost
     assert float(c["moe_rows"]) == 8 * 32 * CFG.top_k
     assert float(c["moe_dropped"]) == 0.0
     assert float(c["moe_load_max_over_mean"]) >= 1.0
+    assert float(c["moe_blocks_run"]) == 1.0     # all held: one block is all
 
 
 def test_a_mesh_with_fsdp_gives_the_one_chip_losses():

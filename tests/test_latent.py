@@ -82,6 +82,7 @@ def test_the_second_prediction_targets_two_ahead_and_leaves_the_last_two_out():
     assert float(c["lm_loss"]) == pytest.approx(float(lm), rel=1e-6)
     assert float(c["mtp_loss"]) == pytest.approx(float(mtp), rel=1e-6)
     assert float(c["moe_dropped"]) == 0.0 and float(c["moe_rows"]) == 2 * 32 * 2
+    assert float(c["moe_blocks_run"]) == 1.0     # all held: one block is all
     # the last position's placeholder and the last two tokens' targets reach
     # neither loss: other tokens there, same losses
     moved = tokens.at[:, -1].set((tokens[:, -1] + 1) % CFG.vocab_size)
@@ -247,6 +248,7 @@ def test_it_trains_through_the_trainer_and_a_mesh_gives_the_one_chip_losses():
         out.append(run)
     assert out[0][-1] < out[0][0]
     assert set(m["counters"]) == {"moe_rows", "moe_load_max_over_mean",
-                                  "moe_dropped", "lm_loss", "mtp_loss"}
+                                  "moe_dropped", "moe_blocks_run", "lm_loss",
+                                  "mtp_loss"}
     assert float(m["counters"]["moe_dropped"]) == 0.0
     np.testing.assert_allclose(out[0], out[1], rtol=2e-5)

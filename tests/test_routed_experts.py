@@ -1,6 +1,7 @@
 """``models/moe.RoutedExperts``: a chip's share of a sparse feed-forward
-layer, against a dense loop over the experts; no token is ever dropped; and
-the shares of all the chips add up to the whole layer."""
+layer, against a dense loop over the experts; no token is ever dropped; the
+shares of all the chips add up to the whole layer; and only the blocks of
+sorted rows that hold a row are worked off, in both passes."""
 
 import jax
 import jax.numpy as jnp
@@ -225,3 +226,129 @@ def test_an_unknown_score_function_is_refused():
     with pytest.raises(ValueError):
         RoutedExperts(E, K, F, (0, E), score="tanh").init(
             jax.random.key(0), jnp.zeros((4, D)))
+
+
+# ---- the block loop: only blocks that hold a row run, in both passes --------
+
+BLOCK = 40     # 2 of 8 experts held: 2 x 80 assignments x 2 / 8
+
+LOADS = {
+    # load: (every token's first choice, its second, rows held, blocks run)
+    "no_row": (4, 5, 0, 0),
+    "one_block_exactly": (0, 5, BLOCK, 1),
+    "every_assignment": (0, 1, 2 * BLOCK, 2),
+}
+
+
+def loaded(load):
+    """2 of 8 experts held: 80 assignments in two blocks of 40.  Every token
+    chooses the same two experts, so the rows held are 0, 40 or 80."""
+    m = layer(0, 2)
+    x = jax.random.normal(jax.random.key(7), (T, D)).at[:, 0].set(1.0)
+    params = jax.tree.map(lambda a: 10 * a,
+                          m.init(jax.random.key(6), x)["params"])
+    one, two, rows, blocks = LOADS[load]
+    params["router"]["kernel"] = params["router"]["kernel"].at[0, one].add(
+        12.0).at[0, two].add(6.0)
+    return m, params, x, rows, blocks
+
+
+@pytest.mark.parametrize("load", list(LOADS))
+def test_the_loop_runs_the_blocks_that_hold_a_row_and_no_other(load):
+    m, params, x, rows, blocks = loaded(load)
+    out, stats = m.apply({"params": params}, x)
+    assert float(stats["rows"]) == rows
+    assert float(stats["blocks_run"]) == blocks == -(-rows // BLOCK)
+    assert float(stats["dropped"]) == 0.0
+
+
+@pytest.mark.parametrize("load", list(LOADS))
+def test_values_and_gradients_match_a_dense_loop_at_every_load(load):
+    m, params, x, rows, _ = loaded(load)
+    out, _ = m.apply({"params": params}, x)
+    ref = dense_loop(params, x, 0, 2)
+    assert float(jnp.max(jnp.abs(out - ref))) <= 1e-5 * float(jnp.max(jnp.abs(ref)))
+    f = lambda fn: lambda p, x: jnp.sum(jnp.sin(fn(p, x)))  # noqa: E731
+    got = jax.grad(f(lambda p, x: m.apply({"params": p}, x)[0]), (0, 1))(params, x)
+    want = jax.grad(f(lambda p, x: dense_loop(p, x, 0, 2)), (0, 1))(params, x)
+    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(got),
+                            jax.tree.leaves(want)):
+        assert float(jnp.max(jnp.abs(a - b))) <= 1e-4 * float(
+            jnp.max(jnp.abs(b))) + 1e-6, jax.tree_util.keystr(path)
+    if rows == 0:       # the shared expert's alone: nothing reaches an expert
+        assert float(jnp.max(jnp.abs(out - dense_loop(params, x, 0, 0)))) == 0.0
+        assert all(float(jnp.max(jnp.abs(g))) == 0.0
+                   for g in jax.tree.leaves(got[0]["experts"]))
+    else:
+        assert float(jnp.max(jnp.abs(got[0]["router"]["kernel"]))) > 0.0
+        assert all(float(jnp.max(jnp.abs(g))) > 0.0
+                   for g in jax.tree.leaves(got[0]["experts"]))
+
+
+def test_it_differentiates_under_checkpoint_inside_a_scan_over_layers():
+    """As ``hybrid.py`` and ``latent.py`` run it: rematerialised, scanned over
+    stacked layers.  Two layers' gradients match the unscanned sum."""
+    m, params, x, _, _ = loaded("every_assignment")
+    other = jax.tree.map(lambda a: 0.5 * a, params)
+    stacked = jax.tree.map(lambda a, b: jnp.stack([a, b]), params, other)
+
+    def scanned(stack, x):
+        layer_fn = jax.checkpoint(lambda p, h: m.apply({"params": p}, h))
+
+        def step(h, p):
+            y, stats = layer_fn(p, h)
+            return h + 0.1 * jnp.tanh(y), stats["blocks_run"]
+
+        h, runs = jax.lax.scan(step, x, stack)
+        return jnp.sum(jnp.sin(h)), runs
+
+    def unscanned(stack, x):
+        h = x
+        for i in range(2):
+            y, _ = m.apply({"params": jax.tree.map(lambda a: a[i], stack)}, h)
+            h = h + 0.1 * jnp.tanh(y)
+        return jnp.sum(jnp.sin(h))
+
+    (value, runs), got = jax.value_and_grad(scanned, (0, 1), has_aux=True)(stacked, x)
+    want_value, want = jax.value_and_grad(unscanned, (0, 1))(stacked, x)
+    assert runs.tolist() == [2.0, 2.0]
+    assert float(value) == pytest.approx(float(want_value), rel=1e-5)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert float(jnp.max(jnp.abs(a - b))) <= 1e-4 * float(jnp.max(jnp.abs(b))) + 1e-6
+
+
+def _loop_bodies(jaxpr):
+    """Every jaxpr that runs once an iteration of some loop under ``jaxpr``
+    (a ``while``'s or a ``scan``'s body), with all that is nested in it."""
+    def subjaxprs(eqn):
+        for v in eqn.params.values():
+            for u in (v if isinstance(v, (tuple, list)) else (v,)):
+                u = getattr(u, "jaxpr", u)
+                if hasattr(u, "eqns"):
+                    yield u
+
+    def walk(j, inside):
+        for eqn in j.eqns:
+            for sub in subjaxprs(eqn):
+                looped = inside or eqn.primitive.name in ("while", "scan")
+                if looped:
+                    yield sub
+                yield from walk(sub, looped)
+
+    return list(walk(jaxpr, False))
+
+
+def test_no_block_of_either_pass_makes_a_zero_tensor_of_the_sums_shape():
+    """What the scan over all blocks paid for a skipped one: a ``(t, d)``
+    zero tensor in the forward pass, and in the backward pass one of the
+    shape of everything its body read (tokens, the three expert stacks)."""
+    m, params, x = make(0, 3)           # 80 assignments in blocks of 60
+    f = lambda p, x: jnp.sum(jnp.sin(m.apply({"params": p}, x)[0]))  # noqa: E731
+    jaxpr = jax.make_jaxpr(jax.value_and_grad(f, (0, 1)))(params, x).jaxpr
+    sums = {(T, D), (3, D, F), (3, F, D)}
+    bodies = _loop_bodies(jaxpr)
+    assert len(bodies) >= 2          # the forward loop's and the backward's
+    made = [tuple(eqn.outvars[0].aval.shape) for body in bodies
+            for eqn in body.eqns if eqn.primitive.name == "broadcast_in_dim"]
+    # a body masks its rows with zeros of a block's shape, and makes no other
+    assert (60, D) in made and not [s for s in made if s in sums], made

@@ -332,13 +332,22 @@ class HybridDecoder(PlannedDecoder):
     """The period-scanned decoder of this module (``HybridConfig``)."""
 
 
+def routing_counters(c: dict) -> dict:
+    """A step's routing counters from a decoder's ``counters`` (one value a
+    sparse layer each): assignments that fell on held experts (mean over the
+    layers), the largest held expert's rows over the mean (worst layer),
+    assignments lost (sum), and the blocks of rows a layer ran (mean)."""
+    return {"moe_rows": jnp.mean(c["rows"]),
+            "moe_load_max_over_mean": jnp.max(c["load_max_over_mean"]),
+            "moe_dropped": jnp.sum(c["dropped"]),
+            "moe_blocks_run": jnp.mean(c["blocks_run"])}
+
+
 def make_loss_fn(model: HybridDecoder, *, ce_chunk: int = 512):
     """The ``Trainer`` loss: chunked next-token cross-entropy, and beside
-    ``accuracy`` the step's routing ``counters`` (``run_train_loop`` writes
-    whatever a loss function returns under that name to the trace and to
-    gauges): assignments that fell on held experts (mean over the layers),
-    the largest held expert's rows over the mean (worst layer), and
-    assignments lost (sum)."""
+    ``accuracy`` the step's ``routing_counters`` as ``counters``
+    (``run_train_loop`` writes whatever a loss function returns under that
+    name to the trace and to gauges)."""
 
     def loss_fn(params, mstate, batch, rng):
         hidden, c = model.apply({"params": params}, batch["tokens"],
@@ -346,10 +355,7 @@ def make_loss_fn(model: HybridDecoder, *, ce_chunk: int = 512):
         loss, acc = chunked_causal_lm_loss(
             hidden, params["lm_head"]["kernel"], batch["tokens"],
             chunk_size=ce_chunk)
-        counters = {"moe_rows": jnp.mean(c["rows"]),
-                    "moe_load_max_over_mean": jnp.max(c["load_max_over_mean"]),
-                    "moe_dropped": jnp.sum(c["dropped"])}
-        return loss, ({"accuracy": acc, "counters": counters}, mstate)
+        return loss, ({"accuracy": acc, "counters": routing_counters(c)}, mstate)
 
     return loss_fn
 

@@ -38,7 +38,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from tpucfn.mesh import AXIS_FSDP
-from tpucfn.models.hybrid import LayerPlan, PlannedDecoder
+from tpucfn.models.hybrid import LayerPlan, PlannedDecoder, routing_counters
 from tpucfn.models.layers import AttentionFn, RMSNorm, SwiGLUMLP
 from tpucfn.models.llama import chunked_causal_lm_loss, remat_policy
 from tpucfn.models.moe import RoutedExperts
@@ -222,9 +222,8 @@ def make_loss_fn(model: LatentDecoder, *, ce_chunk: int = 512):
     ``mtp_lambda`` times that of the token after next, both through the one
     ``lm_head`` kernel (and, inside the model, the one embedding).  Beside
     ``accuracy`` (the next token's) the step's ``counters``: the two losses,
-    and over all sparse blocks (the trunk's and the prediction block's) the
-    assignments that fell on held experts (mean), the largest held expert's
-    rows over the mean (worst block) and assignments lost (sum)."""
+    and ``hybrid.routing_counters`` over all sparse blocks (the trunk's and
+    the prediction block's)."""
     cfg = model.cfg
 
     def loss_fn(params, mstate, batch, rng):
@@ -236,10 +235,7 @@ def make_loss_fn(model: LatentDecoder, *, ce_chunk: int = 512):
                                          chunk_size=ce_chunk)
         mtp, _ = chunked_causal_lm_loss(second, head, tokens,
                                         chunk_size=ce_chunk, ahead=2)
-        counters = {"moe_rows": jnp.mean(c["rows"]),
-                    "moe_load_max_over_mean": jnp.max(c["load_max_over_mean"]),
-                    "moe_dropped": jnp.sum(c["dropped"]),
-                    "lm_loss": lm, "mtp_loss": mtp}
+        counters = {**routing_counters(c), "lm_loss": lm, "mtp_loss": mtp}
         return lm + cfg.mtp_lambda * mtp, (
             {"accuracy": acc, "counters": counters}, mstate)
 

@@ -58,6 +58,7 @@ that flag, MoE under PP keeps the single-device dispatch.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any
 
 import flax.linen as nn
@@ -397,6 +398,85 @@ class _ExpertKernels(nn.Module):
                 KernelParam(down, self.param_dtype, name="down_proj")())
 
 
+def _block_rows(lo, block, t, order, sizes, ends, rows):
+    """The block of sorted assignments that starts at row ``lo``: their flat
+    indices and tokens, which of the block's rows hold an assignment, and how
+    many rows of each held expert lie in the block."""
+    idx = lax.dynamic_slice(order, (lo,), (block,))
+    taken = (lo + jnp.arange(block) < rows)[:, None]
+    here = jnp.clip(ends, lo, lo + block) - jnp.clip(ends - sizes, lo, lo + block)
+    return idx, idx % t, taken, here
+
+
+def _block_experts(dtype, xs, wg, wu, wd, w, taken, here):
+    """The SwiGLU experts on one block's gathered rows, times the rows'
+    weights, in float32 as the running sum takes it."""
+    # rows past the groups belong to no expert here, and what a grouped
+    # product leaves in them is not defined: zeros in, zeros out, so that
+    # neither pass carries anything of them
+    xs = jnp.where(taken, xs, 0).astype(dtype)
+    h = nn.silu(lax.ragged_dot(xs, wg, here)) * lax.ragged_dot(xs, wu, here)
+    y = lax.ragged_dot(jnp.where(taken, h, 0), wd, here)
+    y = jnp.where(taken, y, 0) * w[:, None].astype(dtype)
+    return y.astype(jnp.float32)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _run_blocks(block, dtype, xt, wg, wu, wd, weight, order, sizes, ends, rows):
+    """The held experts' part of the layer's sum over the sorted rows, a block
+    at a time: ``(out (t, d) float32, rows given to the grouped products,
+    blocks run)``.  The loop runs ``ceil(rows / block)`` times, so a block
+    past the last held row costs nothing, and a block that runs adds into the
+    running sum in place.  Reverse mode is the same loop written by hand
+    (``_run_blocks_bwd``): a loop whose trip count is traced has no
+    transpose, and a scan's would sum a zero cotangent of everything the
+    body reads once a block, run or not."""
+    t, d = xt.shape
+
+    def body(i, carry):
+        out, took = carry
+        idx, token, taken, here = _block_rows(i * block, block, t, order,
+                                              sizes, ends, rows)
+        y = _block_experts(dtype, xt[token], wg, wu, wd, weight[idx], taken, here)
+        return out.at[token].add(y), took + jnp.sum(here)
+
+    n_run = -(-rows // block)
+    out, took = lax.fori_loop(
+        0, n_run, body, (jnp.zeros((t, d), jnp.float32), jnp.int32(0)))
+    return out, took, n_run
+
+
+def _run_blocks_fwd(block, dtype, *args):
+    # residuals are the inputs alone: a block's products are made again in
+    # the backward loop, as ``jax.checkpoint`` around a block would
+    return _run_blocks(block, dtype, *args), args
+
+
+def _run_blocks_bwd(block, dtype, res, cts):
+    xt, wg, wu, wd, weight, order, sizes, ends, rows = res
+    d_out = cts[0]              # the two counts are integers: no cotangent
+    t = xt.shape[0]
+
+    def body(i, sums):
+        idx, token, taken, here = _block_rows(i * block, block, t, order,
+                                              sizes, ends, rows)
+        _, vjp = jax.vjp(
+            lambda *a: _block_experts(dtype, *a, taken, here),
+            xt[token], wg, wu, wd, weight[idx])
+        d_xs, d_wg, d_wu, d_wd, d_w = vjp(d_out[token])
+        d_xt, s_wg, s_wu, s_wd, d_weight = sums
+        return (d_xt.at[token].add(d_xs), s_wg + d_wg, s_wu + d_wu,
+                s_wd + d_wd, d_weight.at[idx].add(d_w))
+
+    sums = lax.fori_loop(
+        0, -(-rows // block), body,
+        tuple(jnp.zeros_like(a) for a in (xt, wg, wu, wd, weight)))
+    return (*sums, None, None, None, None)
+
+
+_run_blocks.defvjp(_run_blocks_fwd, _run_blocks_bwd)
+
+
 class RoutedExperts(nn.Module):
     """A chip's share of a sparse feed-forward layer that keeps every token.
 
@@ -420,16 +500,21 @@ class RoutedExperts(nn.Module):
     the layer's sum: what the other experts would add is another chip's
     part, and no exchange is made here.  There is no capacity and no dropped
     token: shapes are static and cover the case in which every assignment
-    falls here, a block of rows at a time, blocks past the last held row
-    skipped.  With ``shared_dim`` the shared expert is computed whole, as
+    falls here, a block of rows at a time (``_run_blocks``): the loop runs
+    once for each block that holds a row and adds that block's result into
+    the running sum in place, in the forward pass and, written by hand, in
+    the backward pass, so a block past the last held row costs nothing in
+    either.  With ``shared_dim`` the shared expert is computed whole, as
     on every chip of the deployment, times a sigmoid gate of its own
     (``shared_gate``, Qwen3-Next) or as it is (DeepSeek-V3).
 
     Returns ``(out, stats)``; ``stats`` holds float32 scalars: ``rows``
     (assignments that fell on held experts), ``load_max_over_mean`` (the
-    largest held expert's rows over the mean) and ``dropped`` (assignments
+    largest held expert's rows over the mean), ``dropped`` (assignments
     on held experts less the rows the grouped products of the blocks that
-    ran were given: 0 while every block that holds a row runs).
+    ran were given: 0 while every block that holds a row runs) and
+    ``blocks_run`` (the loop's trip count, ``ceil(rows / block)``: how many
+    blocks the layer paid for).
     """
 
     n_experts: int
@@ -493,44 +578,15 @@ class RoutedExperts(nn.Module):
         wg, wu, wd = (w.astype(self.dtype) for w in (wg, wu, wd))
 
         # The sorted rows are worked off in blocks of twice the share a
-        # uniform router sends here; a block past the last held row is
-        # skipped.  Every assignment lies in some block, so none is dropped
-        # at any load; memory is a block's, and time follows the rows that
-        # came, a block at a time.
+        # uniform router sends here, and only the blocks that hold a row are
+        # worked off at all.  Every assignment lies in some block, so none is
+        # dropped at any load; memory is a block's, and time follows the rows
+        # that came, a block at a time, in both passes.
         n = t * k
         block = min(n, -(-2 * n * count // self.n_experts))
-        blocks = -(-n // block)
-        order = jnp.pad(order, (0, blocks * block - n))
-
-        def part(lo):
-            idx = lax.dynamic_slice(order, (lo,), (block,))
-            token = idx % t
-            # rows past the groups belong to no expert here, and what a
-            # grouped product leaves in them is not defined: zeros in, zeros
-            # out, so that neither pass carries anything of them
-            taken = (lo + jnp.arange(block) < rows)[:, None]
-            here = jnp.clip(ends, lo, lo + block) - jnp.clip(
-                ends - sizes, lo, lo + block)
-            xs = jnp.where(taken, xt[token], 0).astype(self.dtype)
-            h = nn.silu(lax.ragged_dot(xs, wg, here)) * lax.ragged_dot(xs, wu, here)
-            y = lax.ragged_dot(jnp.where(taken, h, 0), wd, here)
-            y = jnp.where(taken, y, 0) * weight[idx, None].astype(self.dtype)
-            return (jnp.zeros((t, d), jnp.float32).at[token].add(
-                y.astype(jnp.float32)), jnp.sum(here))
-
-        @jax.checkpoint        # around the branch: a block keeps its offset
-        def maybe(lo):
-            return lax.cond(
-                lo < rows, part,
-                lambda _: (jnp.zeros((t, d), jnp.float32), jnp.int32(0)), lo)
-
-        def step(carry, lo):
-            y, took = maybe(lo)
-            return (carry[0] + y, carry[1] + took), None
-
-        (out, took), _ = lax.scan(
-            step, (jnp.zeros((t, d), jnp.float32), jnp.int32(0)),
-            jnp.arange(blocks) * block)
+        order = jnp.pad(order, (0, -n % block))
+        out, took, blocks_run = _run_blocks(
+            block, self.dtype, xt, wg, wu, wd, weight, order, sizes, ends, rows)
 
         if self.shared_dim:
             from tpucfn.models.layers import SwiGLUMLP
@@ -550,6 +606,7 @@ class RoutedExperts(nn.Module):
             "load_max_over_mean": jnp.max(sizes_f) / jnp.maximum(
                 jnp.mean(sizes_f), 1e-9),
             "dropped": (jnp.sum(mine) - took).astype(jnp.float32),
+            "blocks_run": blocks_run.astype(jnp.float32),
         }
         return out.reshape(*lead, d).astype(self.dtype), stats
 
