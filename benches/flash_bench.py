@@ -5,7 +5,7 @@ the kernels together against XLA's dense attention.
 Two modes, both meaningful only on the real chip (a CPU run times the
 Pallas interpreter):
 
-``--preset <cell>`` (the shapes of the benchmark's two flash cells) times
+``--preset <cell>`` (the shapes of the benchmark's flash cells) times
 the three kernels one at a time on (B, H, S, D) arrays, as the train step
 calls them, and prints one JSON line:
 
@@ -42,7 +42,7 @@ from pathlib import Path
 ROOT = Path(__file__).resolve().parent.parent
 sys.path.insert(0, str(ROOT))
 
-# The two cells whose attention runs through the kernels
+# The cells whose attention runs through the kernels
 # (benchmark/configs/*.json and benchmark/traffic/*.json hold the sources).
 PRESETS = {
     "mistral7b-s8192": dict(batch=1, seq=8192, heads=32, kv_heads=8,
@@ -52,6 +52,9 @@ PRESETS = {
     # latent attention: keys of 192 (128 + 64 rotary), values of 128
     "joyai-mla-s8192": dict(batch=2, seq=8192, heads=32, kv_heads=32,
                             head_dim=192, value_dim=128),
+    # heads of 64, half the lanes (the model's own scale changes no time)
+    "granite4h-ssd-s16384": dict(batch=1, seq=16384, heads=32, kv_heads=8,
+                                 head_dim=64),
 }
 
 
@@ -82,6 +85,7 @@ def kernel_times(batch, seq, heads, kv_heads, head_dim, value_dim=None, *,
     block_q, block_k = blocks or fa._choose_blocks(
         seq, head_dim, jnp.bfloat16, True, value_dim)
     grid = fa._Grid(True, block_q, block_k, 0, 0, seq, seq, False)
+    scale = head_dim ** -0.5
     keys = jax.random.split(jax.random.key(0), 4)
     q, do, k, v = (
         jax.random.normal(key, (batch, h, seq, d), jnp.bfloat16)
@@ -90,13 +94,13 @@ def kernel_times(batch, seq, heads, kv_heads, head_dim, value_dim=None, *,
 
     fwd = jax.jit(lambda q, k, v: fa._flash_fwd(
         q, k, v, None, None, causal=True, q_offset=0, k_offset=0, kv_len=seq,
-        block_sizes=(block_q, block_k), interpret=interpret))
+        block_sizes=(block_q, block_k), interpret=interpret, scale=scale))
     o, lse = fwd(q, k, v)
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
     dq = jax.jit(lambda *a: fa._flash_dq(*a, None, None, grid=grid,
-                                         interpret=interpret))
+                                         interpret=interpret, scale=scale))
     dkv = jax.jit(lambda *a: fa._flash_dkv(*a, None, None, grid=grid,
-                                           interpret=interpret))
+                                           interpret=interpret, scale=scale))
     return {
         "blocks": [block_q, block_k],
         "steps": grid.count(seq),

@@ -33,6 +33,11 @@ KERNEL_TOOLS = {
             "ffn_dim", "top_k", "device"),
         ["--preset", "joyai-mla-s8192", "--tokens", "64", "--dim", "32",
          "--ffn-dim", "16", "--iters", "1"]),
+    "ssd_bench.py": (
+        2, ("preset", "pass", "median_ms", "batch", "seq", "heads", "head_dim",
+            "state", "groups", "chunk", "device"),
+        ["--seq", "64", "--heads", "4", "--head-dim", "8", "--state", "16",
+         "--chunk", "16", "--iters", "1"]),
 }
 
 
@@ -89,6 +94,28 @@ def test_flash_bench_presets_are_the_flash_cells_shapes():
             want.update(head_dim=model["qk_head_dim"],
                         value_dim=model["v_head_dim"])
         assert shape == want, name
+
+
+def test_ssd_bench_preset_is_the_state_space_cells_shape():
+    """The preset is the cell's configuration and traffic files', and the
+    least time beside it is the cell's own yardstick's."""
+    sys.path.insert(0, str(REPO))
+    from benches import ssd_bench
+
+    from benchmark import flops, flops_granite4_h, run
+
+    (name, shape), = ssd_bench.PRESETS.items()
+    cell = run.load_cell(name, False)
+    model, mix = cell["config"]["model"], cell["mix"]["shape"]
+    assert shape == dict(
+        batch=mix["batch"], seq=mix["seq_len"], heads=model["mamba_n_heads"],
+        head_dim=model["mamba_d_head"], state=model["mamba_d_state"],
+        groups=model["mamba_n_groups"], chunk=model["mamba_chunk_size"])
+    peak = flops.peaks("TPU v5 lite")
+    least, bound = ssd_bench.least_ms(shape, ("fwd", "bwd"), peak)
+    want = sum(flops.roofline_seconds(*flops_granite4_h.ssd_call(
+        k, 1, 16384, model), peak)[0] for k in ("fwd", "bwd"))
+    assert least == pytest.approx(1e3 * want) and bound == "compute"
 
 
 def test_moe_bench_presets_are_the_sparse_cells_shapes():

@@ -149,6 +149,37 @@ def test_hybrid_layer_ops_compile_for_v5e(one_chip):
     assert "ragged-dot" in text and "tpu_custom_call" in text
 
 
+def test_state_space_layer_ops_compile_for_v5e(one_chip):
+    """What models/ssm.py brings at the benchmark cell's widths, forward and
+    backward, compiled for the chip: the chunked state-space op (64 heads of
+    64 over one group of state 128, 16,384 positions in chunks of 256,
+    bfloat16), and the flash kernels at 32 / 8 heads of 64 with the model's own
+    scale (a head half the 128 lanes wide) at the blocks its row gives."""
+    from tpucfn.kernels.flash_attention import flash_attention
+    from tpucfn.ops.ssd import ssd
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    s = 16384
+    op = jax.jit(jax.grad(
+        lambda x, dt, a, b, c, d: jnp.sum(ssd(x, dt, a, b, c, d)[0].astype(
+            jnp.float32)), argnums=(0, 1, 2, 3, 4, 5)))
+    text = op.lower(sds((1, s, 64, 64)), sds((1, s, 64), jnp.float32),
+                    sds((64,), jnp.float32), sds((1, s, 1, 128)),
+                    sds((1, s, 1, 128)), sds((64,), jnp.float32)
+                    ).compile().as_text()
+    assert "while" in text and "bf16[64,64,256,256]" in text
+
+    attn = jax.jit(jax.grad(
+        lambda q, k, v: jnp.sum(flash_attention(
+            q, k, v, causal=True, scale=1 / 64, interpret=False, block_q=1024,
+            block_k=1024).astype(jnp.float32)), argnums=(0, 1, 2)))
+    kv = sds((1, s, 8, 64))
+    text = attn.lower(sds((1, s, 32, 64)), kv, kv).compile().as_text()
+    assert text.count("tpu_custom_call") == 3, text.count("tpu_custom_call")
+
+
 def test_gdn_prep_compiles_for_v5e(one_chip):
     """The delta rule's preparation kernels at the benchmark cell's shapes
     (2 x 8,192 positions in chunks of 64, 16 key heads serving 32 value heads

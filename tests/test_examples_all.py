@@ -103,6 +103,21 @@ def test_joyai_llm_flash_example(tmp_path):
                and x["attrs"]["moe_blocks_run"] >= 1.0 for x in lines)
 
 
+def test_granite4_h_example(tmp_path):
+    """The state-space decoder through run_train_loop: the recurrence's two
+    counters reach the log and the ``step_metrics`` lines."""
+    r = _run("granite4_h.py", tmp_path, "--model", "tiny", "--seq-len", "32",
+             "--batch-size", "16", "--num-examples", "64")
+    _ok(r)
+    assert "ssm_log_decay_min=-" in r.stdout and "ssm_state_rms=" in r.stdout
+    rows = [json.loads(ln) for p in (tmp_path / "trace").glob("trace-*.jsonl")
+            for ln in p.read_text().splitlines()]
+    lines = [x for x in rows if x["name"] == "step_metrics"]
+    assert [x["trace_id"] for x in lines] == [1, 2, 3]
+    assert all(x["attrs"]["ssm_log_decay_min"] < 0 < x["attrs"]["ssm_state_rms"]
+               for x in lines)
+
+
 def test_sd15_unet_example(tmp_path):
     _ok(_run("sd15_unet.py", tmp_path, "--tiny", "--batch-size", "8",
              "--num-examples", "32"))

@@ -656,3 +656,65 @@ def test_the_table_key_learns_the_value_size_and_keeps_its_rows():
         == "TPU v5 lite|causal|8192|128|bfloat16"
     assert ft._key("TPU v5 lite", True, 8000, 192, jnp.bfloat16, 128) \
         == "TPU v5 lite|causal|8192|192v128|bfloat16"
+
+
+# ---- PR 33: a scale the model states, and head size 64 ----
+
+# (id, S, q heads, kv heads, D, scale, kwargs of flash_attention)
+SCALE_CASES = [
+    # granite-4.0-h's attention: 4 query heads a key head, heads of 64, 1/64
+    ("hd64_gqa_one_64th", 96, 8, 2, 64, 1 / 64, dict(block_q=32, block_k=32)),
+    ("hd64_padded_edge", 100, 4, 4, 64, 1 / 64, dict(block_q=64, block_k=32)),
+    ("hd32_twice_the_default", 64, 4, 2, 32, 2 * 32 ** -0.5, {}),
+    ("hd64_default_scale", 96, 8, 2, 64, None, dict(block_q=32, block_k=64)),
+]
+
+
+@pytest.mark.parametrize("s,hq,hkv,d,scale,kwargs", [c[1:] for c in SCALE_CASES],
+                         ids=[c[0] for c in SCALE_CASES])
+def test_a_stated_scale_matches_dense_forward_and_all_three_gradients(
+        s, hq, hkv, d, scale, kwargs):
+    """``scale`` multiplies the scores in all three kernels; None is the keys'
+    ``d ** -0.5``.  Held to the dense path fed queries multiplied by the ratio
+    of the two scales (the same scores without the argument), and to the dense
+    path's own ``scale``."""
+    q, k, v, w = (_rand((2, s, h, d), i) for i, h in enumerate((hq, hkv, hkv, hq)))
+    ratio = 1.0 if scale is None else scale / d ** -0.5
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, causal=True, scale=scale, interpret=True,
+                               **kwargs)
+
+    def dense(q, k, v):
+        return dot_product_attention(q * ratio, k, v, causal=True)
+
+    out, ref = flash(q, k, v), dense(q, k, v)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
+    np.testing.assert_allclose(
+        np.asarray(dot_product_attention(q, k, v, causal=True, scale=scale)),
+        np.asarray(ref), atol=2e-5)
+    if scale is not None:   # and the default would not have done
+        assert float(jnp.max(jnp.abs(
+            flash_attention(q, k, v, causal=True, interpret=True, **kwargs)
+            - ref))) > 1e-2
+    for a, b, name in zip(_grads(flash, q, k, v, w), _grads(dense, q, k, v, w), "qkv"):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=3e-4,
+                                   err_msg=f"d{name} mismatch")
+
+
+def test_the_default_scale_lowers_as_the_keys_own_and_another_does_not():
+    """Every call that names no scale lowers to the text it lowered to when
+    the kernels fixed ``d ** -0.5`` themselves (forward and backward)."""
+    q, k, v = _qkv(b=1, sq=64, sk=64, hq=4, hkv=2, d=32)
+
+    def text(**kw):
+        return jax.jit(jax.grad(lambda q, k, v: jnp.sum(flash_attention(
+            q, k, v, causal=True, interpret=True, **kw)), argnums=(0, 1, 2))
+        ).lower(q, k, v).as_text()
+
+    assert text() == text(scale=None) == text(scale=32 ** -0.5)
+    assert text() != text(scale=1 / 64)
+    from tpucfn.kernels.auto import auto_attention_static_zero
+    np.testing.assert_allclose(
+        np.asarray(auto_attention_static_zero(q, k, v, scale=1 / 64)),
+        np.asarray(dot_product_attention(q * (32 ** 0.5 / 64), k, v)), atol=2e-5)

@@ -157,23 +157,25 @@ def full_attention_auto(q, k, v, *, mask=None):
 
 
 def auto_attention_static_zero(q, k, v, *, causal=True, mask=None,
-                               q_offset=0, k_offset=0):
+                               q_offset=0, k_offset=0, scale=None):
     """AttentionFn for call sites whose offsets are STATICALLY zero but
     arrive as traced zeros (Llama's scan carry, the PP stage body):
     dispatches on the local (trace-time) sequence length and DROPS the
     traced zero offsets when taking the flash path — the kernel takes
     static offsets. The caller is responsible for only installing this
-    where q_offset/k_offset are provably zero."""
+    where q_offset/k_offset are provably zero.  ``scale`` multiplies the
+    scores on either path; None is the keys' ``D ** -0.5``."""
     if mask is None and should_use_flash(q.shape[1], causal=causal,
                                          d=q.shape[-1], dtype=q.dtype,
                                          dv=v.shape[-1]):
         from tpucfn.kernels.flash_attention import flash_attention
 
-        return flash_attention(q, k, v, causal=causal)
+        return flash_attention(q, k, v, causal=causal, scale=scale)
     from tpucfn.ops.attention import dot_product_attention
 
     return dot_product_attention(q, k, v, causal=causal, mask=mask,
-                                 q_offset=q_offset, k_offset=k_offset)
+                                 q_offset=q_offset, k_offset=k_offset,
+                                 scale=scale)
 
 
 def auto_attention(q, k, v, *, causal=True, mask=None, q_offset=0,

@@ -67,7 +67,9 @@ TPUCFN_FLASH_BLOCK_Q/_K, else the measured table of
 **Values narrower than keys.** ``v`` (and so ``o``, ``do``, ``dv``) may
 have another head size than ``q`` and ``k`` (latent attention: keys of 192,
 values of 128): every array has block specs and accumulators of its own
-width, and the scale is the keys'. A key size past the lanes that is no
+width, and the scale is ``scale`` or, by default, the keys' ``d ** -0.5``
+(a model that states its own multiplier passes it: the kernels multiply the
+scores by whatever they are given). A key size past the lanes that is no
 multiple of them (192 = 128 + 64) goes to the MXU as it is, one 192-wide
 product: on a v5e the 128 and the 64 as two products into one score read
 the same, and keys zero-padded to 256 make the three kernels 10% faster
@@ -334,7 +336,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, grid: _Grid, scale):
 
 
 def _flash_fwd(q, k, v, q_seg, kv_seg, *, causal, q_offset, k_offset,
-               kv_len, block_sizes, interpret):
+               kv_len, block_sizes, interpret, scale):
     """q: (B, H, SQ, D); k: (B, HKV, SK, D); v: (B, HKV, SK, DV) →
     (o[B,H,SQ,DV], lse[B,H,SQ,LANES]).
 
@@ -356,7 +358,7 @@ def _flash_fwd(q, k, v, q_seg, kv_seg, *, causal, q_offset, k_offset,
         args += seg_args
 
     o, lse = pl.pallas_call(
-        functools.partial(_fwd_kernel, grid=grid, scale=d ** -0.5),
+        functools.partial(_fwd_kernel, grid=grid, scale=scale),
         grid=(b, h, sq // block_q, sk // block_k),
         in_specs=in_specs,
         out_specs=[ospec, qrow],
@@ -509,7 +511,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
 
 
 def _flash_dq(q, k, v, do, lse, delta, q_seg, kv_seg, *, grid: _Grid,
-              interpret):
+              interpret, scale):
     """dQ: grid (b, h, qi, ki), KV blocks stream per query block. ``lse``
     and ``delta``: (B, H, SQ, LANES), lane-replicated."""
     b, h, sq, d = q.shape
@@ -524,7 +526,7 @@ def _flash_dq(q, k, v, do, lse, delta, q_seg, kv_seg, *, grid: _Grid,
         in_specs += seg_specs
         args += seg_args
     return pl.pallas_call(
-        functools.partial(_dq_kernel, grid=grid, scale=d ** -0.5),
+        functools.partial(_dq_kernel, grid=grid, scale=scale),
         grid=(b, h, sq // block_q, sk // block_k),
         in_specs=in_specs,
         out_specs=[qspec],
@@ -539,7 +541,7 @@ def _flash_dq(q, k, v, do, lse, delta, q_seg, kv_seg, *, grid: _Grid,
 
 
 def _flash_dkv(q, k, v, do, lse, delta, q_seg, kv_seg, *, grid: _Grid,
-               interpret):
+               interpret, scale):
     """dK/dV: grid (b, hkv, ki, rep, qi) — for each KV block, accumulate
     over the query-head group and the query blocks; the KV-head block
     stays resident for its whole accumulation. ``lse`` and ``delta``:
@@ -576,7 +578,7 @@ def _flash_dkv(q, k, v, do, lse, delta, q_seg, kv_seg, *, grid: _Grid,
         ]
         args += [_sublanes(q_seg), _lanes(kv_seg)]
     return pl.pallas_call(
-        functools.partial(_dkv_kernel, grid=grid, scale=d ** -0.5),
+        functools.partial(_dkv_kernel, grid=grid, scale=scale),
         grid=(b, hkv, sk // block_k, rep, nq),
         in_specs=in_specs,
         out_specs=[kspec, vspec],
@@ -597,7 +599,7 @@ def _flash_dkv(q, k, v, do, lse, delta, q_seg, kv_seg, *, grid: _Grid,
 
 
 def _flash_bwd(q, k, v, o, lse, do, q_seg, kv_seg, *, causal, q_offset,
-               k_offset, kv_len, block_sizes, interpret, dlse=None):
+               k_offset, kv_len, block_sizes, interpret, scale, dlse=None):
     """q: (B, H, SQ, D); k: (B, HKV, SK, D); v: (B, HKV, SK, DV); o/do:
     (B, H, SQ, DV) — KV stays un-repeated; ``lse``: (B, H, SQ, LANES) as
     the forward wrote it. ``dlse`` (B, H, SQ) is the LSE-output cotangent
@@ -610,9 +612,10 @@ def _flash_bwd(q, k, v, o, lse, do, q_seg, kv_seg, *, causal, q_offset,
         # cotangent flows through d lse / d s = p: ds = p (dp - delta + dlse).
         delta = delta - dlse.astype(jnp.float32)
     dq = _flash_dq(q, k, v, do, lse, _lanes(delta), q_seg, kv_seg,
-                   grid=grid, interpret=interpret)
+                   grid=grid, interpret=interpret, scale=scale)
     dk, dv = _flash_dkv(q, k, v, do, _sublanes(lse[..., 0]), _sublanes(delta),
-                        q_seg, kv_seg, grid=grid, interpret=interpret)
+                        q_seg, kv_seg, grid=grid, interpret=interpret,
+                        scale=scale)
     return dq, dk, dv
 
 
@@ -621,31 +624,26 @@ def _flash_bwd(q, k, v, o, lse, do, q_seg, kv_seg, *, causal, q_offset,
 # --------------------------------------------------------------------------
 
 
-def _make_flash(causal, q_offset, k_offset, kv_len, block_sizes, interpret):
+def _make_flash(causal, q_offset, k_offset, kv_len, block_sizes, interpret,
+                scale):
     """custom_vjp closure over the static config; segment ids ride as
     residual (nondiff) operands."""
+    static = dict(causal=causal, q_offset=q_offset, k_offset=k_offset,
+                  kv_len=kv_len, block_sizes=block_sizes, interpret=interpret,
+                  scale=scale)
 
     @jax.custom_vjp
     def run(q, k, v, q_seg, kv_seg):
-        o, _ = _flash_fwd(q, k, v, q_seg, kv_seg, causal=causal,
-                          q_offset=q_offset, k_offset=k_offset,
-                          kv_len=kv_len, block_sizes=block_sizes,
-                          interpret=interpret)
+        o, _ = _flash_fwd(q, k, v, q_seg, kv_seg, **static)
         return o
 
     def fwd(q, k, v, q_seg, kv_seg):
-        o, lse = _flash_fwd(q, k, v, q_seg, kv_seg, causal=causal,
-                            q_offset=q_offset, k_offset=k_offset,
-                            kv_len=kv_len, block_sizes=block_sizes,
-                            interpret=interpret)
+        o, lse = _flash_fwd(q, k, v, q_seg, kv_seg, **static)
         return o, (q, k, v, q_seg, kv_seg, o, lse)
 
     def bwd(res, do):
         q, k, v, q_seg, kv_seg, o, lse = res
-        dq, dk, dv = _flash_bwd(q, k, v, o, lse, do, q_seg, kv_seg,
-                                causal=causal, q_offset=q_offset,
-                                k_offset=k_offset, kv_len=kv_len,
-                                block_sizes=block_sizes, interpret=interpret)
+        dq, dk, dv = _flash_bwd(q, k, v, o, lse, do, q_seg, kv_seg, **static)
         zero_seg = (np.zeros(q_seg.shape, jax.dtypes.float0)
                     if q_seg is not None else None)
         zero_kseg = (np.zeros(kv_seg.shape, jax.dtypes.float0)
@@ -657,16 +655,16 @@ def _make_flash(causal, q_offset, k_offset, kv_len, block_sizes, interpret):
 
 
 def _make_flash_with_lse(causal, q_offset, k_offset, kv_len, block_sizes,
-                         interpret):
+                         interpret, scale):
     """Like _make_flash but LSE is a first-class differentiable output
     (the ring-hop merge consumes it): the backward takes (do, dlse) and
     routes dlse through the kernels' p·dlse term."""
+    static = dict(causal=causal, q_offset=q_offset, k_offset=k_offset,
+                  kv_len=kv_len, block_sizes=block_sizes, interpret=interpret,
+                  scale=scale)
 
     def _fwd_pair(q, k, v):
-        o, lse_l = _flash_fwd(q, k, v, None, None, causal=causal,
-                              q_offset=q_offset, k_offset=k_offset,
-                              kv_len=kv_len, block_sizes=block_sizes,
-                              interpret=interpret)
+        o, lse_l = _flash_fwd(q, k, v, None, None, **static)
         return o, lse_l[..., 0]  # (B, H, SQ) float32
 
     @jax.custom_vjp
@@ -681,10 +679,7 @@ def _make_flash_with_lse(causal, q_offset, k_offset, kv_len, block_sizes,
         do, dlse = cts
         q, k, v, o, lse = res
         dq, dk, dv = _flash_bwd(q, k, v, o, _lanes(lse), do, None, None,
-                                causal=causal, q_offset=q_offset,
-                                k_offset=k_offset, kv_len=kv_len,
-                                block_sizes=block_sizes, interpret=interpret,
-                                dlse=dlse)
+                                dlse=dlse, **static)
         return dq, dk.astype(k.dtype), dv.astype(v.dtype)
 
     run.defvjp(fwd, bwd)
@@ -735,10 +730,14 @@ def flash_attention_with_lse(
     qt, kt, vt, blocks, (sq, sk, _, _), interpret = _prep_inputs(
         q, k, v, block_q, block_k, interpret, causal)
     run = _make_flash_with_lse(causal, int(q_offset), int(k_offset), sk,
-                               blocks, interpret)
+                               blocks, interpret, _scale(None, q))
     o, lse = run(qt, kt, vt)
     return (jnp.swapaxes(o[:, :, :sq], 1, 2),
             jnp.swapaxes(lse[:, :, :sq], 1, 2))
+
+
+def _scale(scale: float | None, q: jax.Array) -> float:
+    return q.shape[-1] ** -0.5 if scale is None else float(scale)
 
 
 def _check_block(value: int, origin: str) -> int:
@@ -795,9 +794,12 @@ def flash_attention(
     block_q: int | None = None,
     block_k: int | None = None,
     interpret: bool | None = None,
+    scale: float | None = None,
 ) -> jax.Array:
     """Drop-in replacement for
     :func:`tpucfn.ops.attention.dot_product_attention`.
+
+    ``scale`` multiplies the scores; None is the keys' ``D ** -0.5``.
 
     ``segment_ids``: (B, S) int array (self-attention) or a
     ``(q_ids, kv_ids)`` pair — attention is masked across segment
@@ -829,6 +831,6 @@ def flash_attention(
             _pad_seq(kv_seg.astype(jnp.int32), sk_pad, 1), -1)
 
     run = _make_flash(causal, int(q_offset), int(k_offset), sk,
-                      blocks, interpret)
+                      blocks, interpret, _scale(scale, q))
     o = run(qt, kt, vt, q_seg, kv_seg)
     return jnp.swapaxes(o[:, :, :sq], 1, 2)

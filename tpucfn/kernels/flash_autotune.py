@@ -156,13 +156,16 @@ def tune(
     iters: int = 5,
     include_bwd: bool = True,
     persist: bool = True,
+    scale: float | None = None,
 ) -> dict:
     """Time each candidate block pair eagerly; persist + return results.
 
     Returns {"best": (bq, bk), "rows": [{blocks, fwd_ms, bwd_ms, total_ms
     | error}], "key": cache_key}. Call OUTSIDE jit, on the device you
     intend to run on (CPU runs interpret mode — only useful for testing
-    the mechanism, not for real numbers).
+    the mechanism, not for real numbers).  ``scale`` is the scores'
+    multiplier both paths are timed at (None: the keys' ``d ** -0.5``); the
+    table's key does not hold it, a block step costs the same at any.
     """
     import jax
     import jax.numpy as jnp
@@ -190,14 +193,15 @@ def tune(
         row = {"blocks": (bq, bk)}
         try:
             fwd = jax.jit(lambda q, k, v, bq=bq, bk=bk: flash_attention(
-                q, k, v, causal=causal, block_q=bq, block_k=bk))
+                q, k, v, causal=causal, block_q=bq, block_k=bk, scale=scale))
             row["fwd_ms"] = round(timed(fwd, q, k, v), 3)
             total = row["fwd_ms"]
             if include_bwd:
                 bwd = jax.jit(jax.grad(
                     lambda q, k, v, bq=bq, bk=bk: jnp.sum(flash_attention(
-                        q, k, v, causal=causal, block_q=bq, block_k=bk
-                    ).astype(jnp.float32) ** 2), argnums=(0, 1, 2)))
+                        q, k, v, causal=causal, block_q=bq, block_k=bk,
+                        scale=scale).astype(jnp.float32) ** 2),
+                    argnums=(0, 1, 2)))
                 row["bwd_ms"] = round(timed(bwd, q, k, v), 3)
                 total += row["bwd_ms"]
             row["total_ms"] = round(total, 3)
@@ -224,11 +228,12 @@ def tune(
 
         try:
             dfwd = jax.jit(lambda q, k, v: dot_product_attention(
-                q, k, v, causal=causal))
+                q, k, v, causal=causal, scale=scale))
             dense_f = timed(dfwd, q, k, v)
             dbwd = jax.jit(jax.grad(
                 lambda q, k, v: jnp.sum(dot_product_attention(
-                    q, k, v, causal=causal).astype(jnp.float32) ** 2),
+                    q, k, v, causal=causal, scale=scale
+                ).astype(jnp.float32) ** 2),
                 argnums=(0, 1, 2)))
             dense_ms = round(dense_f + timed(dbwd, q, k, v), 3)
             speedup = round(dense_ms / best_row["total_ms"], 3)

@@ -42,7 +42,8 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from tpucfn.mesh import AXIS_FSDP
-from tpucfn.models.layers import AttentionFn, apply_rope, rope_frequencies
+from tpucfn.models.layers import (AttentionFn, apply_rope, causal_conv_silu,
+                                  rope_frequencies)
 from tpucfn.models.llama import chunked_causal_lm_loss, remat_policy
 from tpucfn.models.moe import KernelParam, RoutedExperts
 from tpucfn.ops.gated_delta import gated_delta_rule
@@ -186,13 +187,10 @@ class GatedDeltaNet(nn.Module):
         dt_bias = self.param("dt_bias", nn.initializers.ones, (hv,), f32)
         g = -jnp.exp(a_log) * jax.nn.softplus(a + dt_bias)
 
-        # depthwise causal convolution: y_t = sum_j w_j x_(t - width + 1 + j)
         width = cfg.conv_kernel
         w = KernelParam((width, qkv.shape[-1]), cfg.param_dtype,
                         nn.initializers.normal(width ** -0.5), name="conv")()
-        padded = jnp.pad(qkv, ((0, 0), (width - 1, 0), (0, 0)))
-        qkv = nn.silu(sum(padded[:, j:j + s].astype(f32) * w[j].astype(f32)
-                          for j in range(width))).astype(cfg.dtype)
+        qkv = causal_conv_silu(qkv, w, dtype=cfg.dtype)
 
         q, k, v = jnp.split(qkv, [hk * dk, 2 * hk * dk], axis=-1)
         unit = lambda t: t * jax.lax.rsqrt(  # noqa: E731
@@ -270,12 +268,17 @@ class LayerPlan:
     # ``(x, tokens, embed) -> (hidden, counters)`` from the trunk's output
     # before the final norm, the tokens and the embedding module itself
     after: tuple[str, Any] | None = None
+    # the embedding's output is multiplied by the one, the logits divided by
+    # the other; a tied head is the embedding's own table and no ``lm_head``
+    embedding_multiplier: float = 1.0
+    logits_scaling: float = 1.0
+    tie_embeddings: bool = False
 
 
 class PlannedDecoder(nn.Module):
-    """Embedding, the layers of ``cfg.layer_plan()``, final norm, untied head:
-    the one decoder ``HybridDecoder`` and ``models/latent.LatentDecoder``
-    are."""
+    """Embedding, the layers of ``cfg.layer_plan()``, final norm, and a head
+    of its own or the embedding's table: the one decoder ``HybridDecoder``,
+    ``models/latent.LatentDecoder`` and ``models/ssm.SSMDecoder`` are."""
 
     cfg: Any
     # None = the automatic dense/flash dispatch of kernels/auto.py
@@ -301,6 +304,8 @@ class PlannedDecoder(nn.Module):
                          param_dtype=cfg.param_dtype, name="embed_tokens",
                          embedding_init=nn.initializers.normal(0.02))
         x = embed(tokens)
+        if plan.embedding_multiplier != 1.0:
+            x = x * plan.embedding_multiplier
         found = []
         for name, layer in plan.leading:
             x, c = layer(cfg, attention_fn, name=name)(x)
@@ -321,10 +326,15 @@ class PlannedDecoder(nn.Module):
             *[c for c in found if c])
         if return_hidden:
             return hidden, counters
-        head = nn.DenseGeneral(
-            cfg.vocab_size, use_bias=False, dtype=jnp.float32,
-            param_dtype=cfg.param_dtype, name="lm_head",
-            kernel_init=nn.initializers.normal(0.02))
+        if plan.tie_embeddings:
+            table = embed.embedding.astype(jnp.float32)
+            head = lambda h: jnp.einsum(  # noqa: E731
+                "...d,vd->...v", h, table) / plan.logits_scaling
+        else:
+            head = nn.DenseGeneral(
+                cfg.vocab_size, use_bias=False, dtype=jnp.float32,
+                param_dtype=cfg.param_dtype, name="lm_head",
+                kernel_init=nn.initializers.normal(0.02))
         return jax.tree.map(lambda h: head(h.astype(jnp.float32)), hidden), counters
 
 
@@ -390,16 +400,20 @@ def sharding_rules(cfg: HybridConfig) -> ShardingRules:
     ))
 
 
-RECURRENT_MODEL_TYPES = ("qwen3_next",)
+RECURRENT_MODEL_TYPES = ("qwen3_next", "granitemoehybrid")
 
 
 def refuse_recurrent_model(what, where: str) -> None:
     """Serving and checkpoint conversion are out of scope for a decoder with
-    recurrent layers, and say so by name: ``what`` is a model, a config of
-    this module, or a published config (``model_type``)."""
-    if (isinstance(what, (HybridDecoder, HybridConfig))
+    recurrent layers (Gated DeltaNet here, Mamba-2 in ``models/ssm.py``), and
+    say so by name: ``what`` is a model, a config of either module, or a
+    published config (``model_type``)."""
+    from tpucfn.models.ssm import SSMConfig, SSMDecoder
+
+    if (isinstance(what, (HybridDecoder, HybridConfig, SSMDecoder, SSMConfig))
             or getattr(what, "model_type", None) in RECURRENT_MODEL_TYPES):
         raise NotImplementedError(
-            f"{where} cannot take a decoder with Gated DeltaNet layers "
+            f"{where} cannot take a decoder with recurrent layers "
             f"({type(what).__name__}): there is no cache for recurrent state "
-            "yet (ROADMAP R4, D3); models/hybrid.py trains only")
+            "yet (ROADMAP R4, D3); models/hybrid.py and models/ssm.py train "
+            "only")

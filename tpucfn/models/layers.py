@@ -50,6 +50,21 @@ def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array,
     return out.astype(x.dtype)
 
 
+def causal_conv_silu(x: jax.Array, w: jax.Array, bias: jax.Array | None = None,
+                     *, dtype: Any) -> jax.Array:
+    """``silu(y)`` with ``y_t = sum_j w_j x_(t - width + 1 + j) (+ bias)`` a
+    channel: the depthwise causal convolution in front of a recurrent mixer
+    (Gated DeltaNet's q, k, v; Mamba-2's x, B, C).  x: (B, S, channels); w:
+    (width, channels); float32 inside, ``dtype`` out."""
+    width, s = w.shape[0], x.shape[1]
+    padded = jnp.pad(x, ((0, 0), (width - 1, 0), (0, 0)))
+    y = sum(padded[:, j:j + s].astype(jnp.float32) * w[j].astype(jnp.float32)
+            for j in range(width))
+    if bias is not None:
+        y = y + bias.astype(jnp.float32)
+    return nn.silu(y).astype(dtype)
+
+
 # attention_fn(q, k, v, causal=..., q_offset=..., k_offset=...) -> out
 AttentionFn = Callable[..., jax.Array]
 

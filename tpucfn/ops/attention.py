@@ -36,6 +36,7 @@ def dot_product_attention_with_lse(
     mask: jax.Array | None = None,  # broadcastable to (B, Hq, Sq, Sk); True = attend
     q_offset: int | jax.Array = 0,  # global position of q[0] (ring/SP shards)
     k_offset: int | jax.Array = 0,
+    scale: float | None = None,  # of the scores; None = the keys' D ** -0.5
 ) -> tuple[jax.Array, jax.Array]:
     """Returns (out (B,Sq,Hq,D), lse (B,Sq,Hq)). Softmax in fp32.
 
@@ -50,7 +51,8 @@ def dot_product_attention_with_lse(
     k = _repeat_kv(k, hq // hkv)
     v = _repeat_kv(v, hq // hkv)
 
-    scale = q.shape[-1] ** -0.5
+    if scale is None:
+        scale = q.shape[-1] ** -0.5
     # (B, H, Sq, Sk)
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k, preferred_element_type=jnp.float32)
     logits = logits * scale
@@ -83,9 +85,11 @@ def dot_product_attention(
     mask: jax.Array | None = None,
     q_offset: int | jax.Array = 0,
     k_offset: int | jax.Array = 0,
+    scale: float | None = None,
 ) -> jax.Array:
     """Returns (B, Sq, Hq, D); see :func:`dot_product_attention_with_lse`."""
     out, _ = dot_product_attention_with_lse(
-        q, k, v, causal=causal, mask=mask, q_offset=q_offset, k_offset=k_offset
+        q, k, v, causal=causal, mask=mask, q_offset=q_offset, k_offset=k_offset,
+        scale=scale,
     )
     return out
