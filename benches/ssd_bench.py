@@ -5,17 +5,22 @@ jax selects (a number means something only on the chip).
 Times ``tpucfn.ops.ssd.ssd`` forward and forward + backward at the shape of the
 benchmark cell ``granite4h-ssd-s16384`` (``--preset``, the default: 1 x 16,384
 positions, 64 heads of 64, one group of state 128, chunk 256, bfloat16; any
-size can be given) and prints one JSON line a pass:
+size can be given), through the ``jnp`` form and through the path the model
+takes (the Pallas kernels of ``tpucfn/kernels/ssd.py`` where the op's rule
+says so), and prints one JSON line a path and pass:
 
-    {"preset": "granite4h-ssd-s16384", "pass": "fwd_bwd", "median_ms": ...,
-     "least_ms": ..., "bound": "memory", "roofline_pct": ..., "device": ...}
+    {"preset": "granite4h-ssd-s16384", "path": "model", "pass": "fwd_bwd",
+     "pallas": true, "median_ms": ..., "least_ms": ..., "bound": "compute",
+     "roofline_pct": ..., "device": ...}
 
+The path is chosen as the program chooses it, from the backend it is told: the
+``jnp`` rows answer ``cpu`` to that question, nothing else differs; ``pallas``
+says whether the kernels ran (off a TPU both paths are ``jnp``'s).
 ``least_ms`` is ``benchmark/flops_granite4_h.ssd_call`` through
 ``benchmark/peaks.json``: the yardstick of the cell's ``ssd_roofline.g4h``
 (forward + backward: one pass of each, as the metric counts a step's).  What a
-Mamba layer pays a step is two to three forward passes (the layer's
-rematerialisation and the op's own) and one backward.  No cell of the benchmark
-runs this tool.
+Mamba layer pays a step is two forward passes (the second the layer's
+rematerialisation) and one backward.  No cell of the benchmark runs this tool.
 
 Usage (on a TPU host):  python benches/ssd_bench.py [--chunk 128 ...]
 """
@@ -63,7 +68,7 @@ def main() -> int:
     import jax
     import jax.numpy as jnp
 
-    from tpucfn.ops.ssd import ssd
+    from tpucfn.ops import ssd as ssd_op
 
     device = jax.devices()[0].device_kind
     peaks = json.loads((ROOT / "benchmark" / "peaks.json").read_text())
@@ -78,24 +83,33 @@ def main() -> int:
     bm, cm = (jax.random.normal(k, (b, s, g, n), jnp.bfloat16) for k in ks[3:])
     d = jnp.ones((h,))
 
-    def op(*t):
-        return ssd(*t, chunk_size=shape["chunk"])[0]
+    backend = ssd_op._backend
+    for path in ("jnp", "model"):
+        # each path is traced under its own answer to the backend question,
+        # through functions of its own: jit keys its traces by the function
+        ssd_op._backend = (lambda: "cpu") if path == "jnp" else backend
+        pallas = ssd_op._kernel_serves(jnp.bfloat16, shape["chunk"], n, h // g, p_)
 
-    def loss(*t):
-        return jnp.sum(op(*t).astype(jnp.float32) ** 2)
+        def op(*t):
+            return ssd_op.ssd(*t, chunk_size=shape["chunk"])[0]
 
-    passes = {"fwd": (jax.jit(op), ("fwd",)),
-              "fwd_bwd": (jax.jit(jax.grad(loss, argnums=tuple(range(6)))),
-                          ("fwd", "bwd"))}
-    for name, (fn, counted) in passes.items():
-        row = {"preset": args.preset, "pass": name,
-               "median_ms": round(median_ms(fn, x, dt, a, bm, cm, d,
-                                            iters=args.iters), 3)}
-        if device in peaks:   # off the chip there is no roofline to stand beside
-            least, bound = least_ms(shape, counted, peaks[device])
-            row.update(least_ms=round(least, 3), bound=bound,
-                       roofline_pct=round(100 * least / row["median_ms"], 2))
-        print(json.dumps({**row, **shape, "device": device}), flush=True)
+        def loss(*t):
+            return jnp.sum(op(*t).astype(jnp.float32) ** 2)
+
+        passes = {"fwd": (jax.jit(op), ("fwd",)),
+                  "fwd_bwd": (jax.jit(jax.grad(loss, argnums=tuple(range(6)))),
+                              ("fwd", "bwd"))}
+        for name, (fn, counted) in passes.items():
+            row = {"preset": args.preset, "path": path, "pass": name,
+                   "pallas": pallas,
+                   "median_ms": round(median_ms(fn, x, dt, a, bm, cm, d,
+                                                iters=args.iters), 3)}
+            if device in peaks:   # off the chip there is no roofline to stand beside
+                least, bound = least_ms(shape, counted, peaks[device])
+                row.update(least_ms=round(least, 3), bound=bound,
+                           roofline_pct=round(100 * least / row["median_ms"], 2))
+            print(json.dumps({**row, **shape, "device": device}), flush=True)
+    ssd_op._backend = backend
     return 0
 
 

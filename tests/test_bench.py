@@ -34,8 +34,8 @@ KERNEL_TOOLS = {
         ["--preset", "joyai-mla-s8192", "--tokens", "64", "--dim", "32",
          "--ffn-dim", "16", "--iters", "1"]),
     "ssd_bench.py": (
-        2, ("preset", "pass", "median_ms", "batch", "seq", "heads", "head_dim",
-            "state", "groups", "chunk", "device"),
+        4, ("preset", "path", "pass", "pallas", "median_ms", "batch", "seq",
+            "heads", "head_dim", "state", "groups", "chunk", "device"),
         ["--seq", "64", "--heads", "4", "--head-dim", "8", "--state", "16",
          "--chunk", "16", "--iters", "1"]),
 }
@@ -63,6 +63,12 @@ def test_kernel_tool_prints_its_documented_rows(tool):
         # off a TPU both paths are the jnp preparation, and the row says so
         assert not any(row["pallas"] for row in rows)
         assert {row["device"] for row in rows} == {"cpu"}
+    if tool == "ssd_bench.py":
+        assert [(row["path"], row["pass"]) for row in rows] == [
+            ("jnp", "fwd"), ("jnp", "fwd_bwd"),
+            ("model", "fwd"), ("model", "fwd_bwd")]
+        # off a TPU (and at a head of 8) the model takes the jnp form too
+        assert not any(row["pallas"] for row in rows)
     if tool == "moe_bench.py":
         # 64 tokens x 8 in blocks of 64: half a block, one and a half, all 8
         assert [(row["load"], row["pass"], row["rows"], row["blocks_run"])

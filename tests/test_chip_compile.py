@@ -149,26 +149,58 @@ def test_hybrid_layer_ops_compile_for_v5e(one_chip):
     assert "ragged-dot" in text and "tpu_custom_call" in text
 
 
-def test_state_space_layer_ops_compile_for_v5e(one_chip):
-    """What models/ssm.py brings at the benchmark cell's widths, forward and
-    backward, compiled for the chip: the chunked state-space op (64 heads of
+def _ssd_step_text(one_chip):
+    """The chunked state-space op at the benchmark cell's widths (64 heads of
     64 over one group of state 128, 16,384 positions in chunks of 256,
-    bfloat16), and the flash kernels at 32 / 8 heads of 64 with the model's own
-    scale (a head half the 128 lanes wide) at the blocks its row gives."""
-    from tpucfn.kernels.flash_attention import flash_attention
+    bfloat16), value and every gradient, compiled for the chip: its text."""
     from tpucfn.ops.ssd import ssd
 
     def sds(shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     s = 16384
-    op = jax.jit(jax.grad(
+    op = jax.jit(jax.value_and_grad(
         lambda x, dt, a, b, c, d: jnp.sum(ssd(x, dt, a, b, c, d)[0].astype(
-            jnp.float32)), argnums=(0, 1, 2, 3, 4, 5)))
-    text = op.lower(sds((1, s, 64, 64)), sds((1, s, 64), jnp.float32),
+            jnp.float32) ** 2), argnums=(0, 1, 2, 3, 4, 5)))
+    return op.lower(sds((1, s, 64, 64)), sds((1, s, 64), jnp.float32),
                     sds((64,), jnp.float32), sds((1, s, 1, 128)),
                     sds((1, s, 1, 128)), sds((64,), jnp.float32)
                     ).compile().as_text()
+
+
+def test_state_space_kernels_compile_for_v5e(one_chip, monkeypatch):
+    """The path the op takes on the chip: ``ssd_own_fwd``, ``ssd_chunk_fwd``
+    and their backward kernels compiled, not interpreted (the backend here is
+    the CPU, so the test answers "tpu" for it), around XLA's scan over chunks;
+    no array of every chunk's and every head's 256 x 256 decays is left."""
+    import functools
+
+    from tpucfn.kernels import ssd as kernels
+    from tpucfn.ops import ssd as ssd_op
+
+    monkeypatch.setattr(ssd_op, "_backend", lambda: "tpu")
+    for name in ("ssd_chunk", "ssd_own"):
+        monkeypatch.setattr(kernels, name, functools.partial(
+            getattr(kernels, name), interpret=False))
+    text = _ssd_step_text(one_chip)
+    assert text.count("tpu_custom_call") == 4, text.count("tpu_custom_call")
+    assert "while" in text and "[64,64,256,256]" not in text
+
+
+def test_state_space_layer_ops_compile_for_v5e(one_chip):
+    """What models/ssm.py brings at the benchmark cell's widths, forward and
+    backward, compiled for the chip: the chunked state-space op's ``jnp`` form
+    (what a CPU backend is given: the decays of every chunk and head at once),
+    and the flash kernels at 32 / 8 heads of 64 with the model's own
+    scale (a head half the 128 lanes wide) at the blocks its row gives."""
+    from tpucfn.kernels.flash_attention import flash_attention
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    s = 16384
+    text = _ssd_step_text(one_chip)
+    assert "tpu_custom_call" not in text
     assert "while" in text and "bf16[64,64,256,256]" in text
 
     attn = jax.jit(jax.grad(

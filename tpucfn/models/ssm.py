@@ -18,8 +18,12 @@ scale that starts at 1; no bias but the convolution's):
   of ``ssm_head_dim``), ``B`` and ``C`` (``ssm_groups`` groups of
   ``ssm_state``, a group shared by its heads); ``dt = softplus(dt + dt_bias)``,
   ``a = -exp(A_log)``; the recurrence ``ops/ssd.py`` in chunks of
-  ``ssm_chunk``; ``y = norm(y * silu(z))``, the gate *before* one RMS norm over
-  all the heads' channels; ``out_proj``.
+  ``ssm_chunk`` (its chunk-local parts the Pallas kernels of
+  ``kernels/ssd.py`` where the op's own rule says so, ``jnp`` elsewhere; the
+  layer's rematerialisation is the only one: what the op keeps for its
+  backward pass is its inputs and the chunks' states); ``y = norm(y *
+  silu(z))``, the gate *before* one RMS norm over all the heads' channels;
+  ``out_proj``.
 
 The stack is ``models/hybrid.PlannedDecoder``'s: ``layer_types`` is a whole
 number of repetitions of its shortest period, and a period is laid out as
@@ -179,13 +183,10 @@ class Mamba2Mixer(nn.Module):
                                        jnp.log(1e-1)))), (nh,))
         d = self.param("D", nn.initializers.ones, (nh,), f32)
         dt = jax.nn.softplus(dt.astype(f32) + dt_bias)
-        # rematerialised on its own inside the layer's remat, as the delta
-        # rule is: the chunk-local decays of every chunk at once (chunks x
-        # heads x chunk^2) are then alive in one layer's backward pass only
-        y, state, decay_min = jax.checkpoint(
-            lambda *a: ssd(*a, chunk_size=cfg.ssm_chunk)
-        )(x.reshape(b, s, nh, p), dt, -jnp.exp(a_log),
-          bm.reshape(b, s, g, n), cm.reshape(b, s, g, n), d)
+        y, state, decay_min = ssd(
+            x.reshape(b, s, nh, p), dt, -jnp.exp(a_log),
+            bm.reshape(b, s, g, n), cm.reshape(b, s, g, n), d,
+            chunk_size=cfg.ssm_chunk)
         y = y.reshape(b, s, inner).astype(f32) * nn.silu(z.astype(f32))
         y = RMSNorm(cfg.norm_eps, cfg.dtype, name="norm")(y)
         stats = jax.lax.stop_gradient({

@@ -20,20 +20,51 @@ loop, position by position).  With ``l`` the log-decay summed inside a chunk of
         + exp(l_i) H_in C_i                                     # from the state
 
 and the state it hands on ``exp(l_L) H_in + sum_j exp(l_L - l_j) dt_j x_j
-B_j^T``.  The chunk-local part and each chunk's own sum are made for all chunks
-at once; a ``lax.scan`` over chunks carries ``H``.  Log-decays and the state are
-float32, every decay factor is ``exp`` of a sum or a difference that is never
-positive (so a step whose decay underflows gives 0, not ``inf * 0``), a
-position's factor on itself is the constant 1 and not ``exp(l_i - l_i)`` (whose
-gradient, two equal terms of opposite sign, would be rounded at the size of
-the undecayed term and lose the decayed ones), and the large products run in the inputs' dtype with float32 accumulation.  Everything here
-is XLA's; the backward pass is autodiff's.
+B_j^T``.  Each chunk's own sum is made for all chunks at once and a ``lax.scan``
+over chunks carries ``H``; then every chunk's output is made from the state it
+starts from.  Log-decays and the state are float32, every decay factor is
+``exp`` of a sum or a difference that is never positive (so a step whose decay
+underflows gives 0, not ``inf * 0``), a position's factor on itself is the
+constant 1 and not ``exp(l_i - l_i)`` (whose gradient, two equal terms of
+opposite sign, would be rounded at the size of the undecayed term and lose the
+decayed ones), and the large products run in the inputs' dtype with float32
+accumulation.
+
+Two owners.  What a chunk makes on its own has two implementations of one
+arithmetic, rounded at the same points.  In ``jnp`` (the einsum for each
+chunk's own sum into the state, and :func:`chunk_outputs` for the chunks'
+outputs once the states they start from are known: a group's ``C_i . B_j``,
+each head's ``(L, L)`` decays, their product with ``dt_j`` and ``x``, the
+state's part, ``d x``), made for every chunk and head at once, the backward
+pass autodiff's; and the Pallas kernels of :mod:`tpucfn.kernels.ssd`
+(``ssd_own``, ``ssd_chunk``), which keep a chunk's tensors in VMEM, forward and
+backward.  :func:`ssd` picks the kernels from what it can see
+(:func:`_kernel_serves`: the backend is a TPU, the compute dtype bfloat16, the
+chunk and the state size multiples of the 128 lanes, the heads whole lane
+tiles) and the ``jnp`` form everywhere else; there is no option.  The
+cumulative sums and the scan over chunks stay XLA's on both paths, their
+backward pass autodiff's.
 """
 
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+
+
+def _backend() -> str:
+    return jax.default_backend()
+
+
+def _kernel_serves(dtype, chunk: int, state: int, heads: int, width: int) -> bool:
+    """The Pallas kernels where their tiles are whole: on a TPU, in bfloat16,
+    the chunk and the state size multiples of the 128 lanes, and a group's
+    ``heads`` heads of ``width`` channels whole lane tiles of x (a head a
+    multiple of 128 wide, or 128 // width heads to a tile)."""
+    tile = max(1, 128 // width)
+    return (_backend() == "tpu" and dtype == jnp.bfloat16
+            and chunk % 128 == 0 and state % 128 == 0
+            and (tile * width) % 128 == 0 and heads % tile == 0)
 
 
 def ssd(x, dt, a, b, c, d=None, *, chunk_size: int = 256):
@@ -71,11 +102,53 @@ def ssd(x, dt, a, b, c, d=None, *, chunk_size: int = 256):
     after = jax.lax.cumsum(jnp.pad(step[:, :, 1:], ((0, 0), (0, 0), (0, 1),
                                                     (0, 0), (0, 0))),
                            axis=2, reverse=True)
+    kernels = b.dtype == c.dtype == dtype and _kernel_serves(dtype, L, n, r, p)
+    if kernels:
+        from tpucfn.kernels.ssd import ssd_chunk, ssd_own
+
+        flat = tuple(t.reshape(bsz, nc * L, -1) for t in (x, b, c))
+
+    # what each chunk adds to the state, and what it leaves of the one it got
+    to_end = jnp.exp(after) * dtc                                 # (B,Nc,L,G,R)
+    if kernels:
+        own = ssd_own(flat[0], flat[1], to_end, p)
+    else:
+        own = jnp.einsum("bcjgrp,bcjgn->cbgrpn",
+                         (xc.astype(f32) * to_end[..., None]).astype(dtype), bc,
+                         preferred_element_type=f32)
+    kept = jnp.moveaxis(jnp.exp(cum[:, :, -1]), 1, 0)             # (Nc,B,G,R)
+
+    def step(state, xs):
+        own_i, kept_i = xs
+        return state * kept_i[..., None, None] + own_i, state.astype(dtype)
+
+    # before: the state each chunk starts from, as the products take it
+    state, before = jax.lax.scan(step, jnp.zeros((bsz, g, r, p, n), f32),
+                                 (own, kept))
+    if kernels:
+        y = ssd_chunk(*flat, dtc, cum, before,
+                      jnp.zeros((h,), f32) if d is None else d)
+    else:
+        y = chunk_outputs(xc, bc, cc, dtc, cum, before, d)
+    y = y.reshape(bsz, nc * L, h, p)[:, :s]
+    return y, state.reshape(bsz, h, p, n), jnp.min(cum[:, :, -1])
+
+
+def chunk_outputs(xc, bc, cc, dtc, cum, before, d):
+    """Every chunk's outputs from what a chunk can see: xc (B,Nc,L,G,R,P); bc,
+    cc (B,Nc,L,G,N); the steps and the log-decay summed inside each chunk,
+    (B,Nc,L,G,R) float32; ``before`` (Nc,B,G,R,P,N), the state each chunk
+    starts from, in ``xc.dtype``; d (H,) or None.  Returns (B,Nc,L,G,R,P) in
+    ``xc.dtype``.  The ``jnp`` form: what runs wherever the kernel does not,
+    and what the kernel is held to."""
+    L, g, r = xc.shape[2:5]
+    dtype, f32 = xc.dtype, jnp.float32
     heads_first = lambda t: jnp.moveaxis(t, 2, -1)  # noqa: E731  (B,Nc,G,R,L)
     cum_h, dt_h = heads_first(cum), heads_first(dtc)
 
     # chunk-local: (C_i . B_j) a group, times each head's decay from j to i
-    # (the diagonal is 1 by itself, for the same reason: no l_i - l_i)
+    # (the diagonal is 1 by itself, as l_L - l_i is summed by itself: no
+    # l_i - l_i)
     below = jnp.tril(jnp.ones((L, L), bool), -1)
     diff = cum_h[..., :, None] - cum_h[..., None, :]              # (…,i,j)
     decay = (jnp.where(below, jnp.exp(jnp.where(below, diff, 0.0)), 0.0)
@@ -83,24 +156,9 @@ def ssd(x, dt, a, b, c, d=None, *, chunk_size: int = 256):
     cb = jnp.einsum("bcign,bcjgn->bcgij", cc, bc, preferred_element_type=f32)
     m = (cb[:, :, :, None] * decay * dt_h[..., None, :]).astype(dtype)
     y = jnp.einsum("bcgrij,bcjgrp->bcigrp", m, xc, preferred_element_type=f32)
-
-    # what each chunk adds to the state, and what it leaves of the one it got
-    to_end = jnp.exp(after) * dtc                                 # (B,Nc,L,G,R)
-    own = jnp.einsum("bcjgrp,bcjgn->cbgrpn",
-                     (xc.astype(f32) * to_end[..., None]).astype(dtype), bc,
-                     preferred_element_type=f32)
-    kept = jnp.moveaxis(jnp.exp(cum[:, :, -1]), 1, 0)             # (Nc,B,G,R)
-
-    def step(state, xs):
-        own_i, kept_i = xs
-        return state * kept_i[..., None, None] + own_i, state
-
-    state, before = jax.lax.scan(step, jnp.zeros((bsz, g, r, p, n), f32),
-                                 (own, kept))
-    y = y + jnp.einsum("bcign,cbgrpn->bcigrp", cc, before.astype(dtype),
+    # the state's part
+    y = y + jnp.einsum("bcign,cbgrpn->bcigrp", cc, before,
                        preferred_element_type=f32) * jnp.exp(cum)[..., None]
     if d is not None:
         y = y + xc.astype(f32) * d.astype(f32).reshape(g, r, 1)
-    y = y.astype(dtype).reshape(bsz, nc * L, h, p)[:, :s]
-    return y, state.reshape(bsz, h, p, n), jnp.min(cum[:, :, -1])
-
+    return y.astype(dtype)
