@@ -143,14 +143,10 @@ def run_train_loop(trainer, ds, mesh, args, *, items_per_step, extra_axes=(),
     from tpucfn.parallel import shard_batch
     from tpucfn.train.trainer import TrainerObs
 
-    from tpucfn.obs import CompileCacheProbe, start_profiler_server
+    from tpucfn.obs import start_profiler_server
 
     # The compile cache itself was enabled at module import (see top of
-    # file — it must precede the process's first compile).  The probe
-    # tells the goodput ledger whether the first step's compile came
-    # from that cache (compile vs compile_cached bucket); TrainerObs
-    # re-arms it at the first step's entry.
-    compile_probe = CompileCacheProbe(enable_compile_cache())
+    # file — it must precede the process's first compile).
     if getattr(args, "profile_server", 0):
         start_profiler_server(args.profile_server)
 
@@ -247,17 +243,17 @@ def run_train_loop(trainer, ds, mesh, args, *, items_per_step, extra_axes=(),
                          lambda: trainer._jit_eval))
         # Fleet warm start (ISSUE 13): when the launcher fanned out
         # artifact-server addresses (TPUCFN_COMPILE_CACHE_ADDRS) — or a
-        # local store dir is pinned — the trainer's jitted programs go
-        # lower → key → fetch-or-compile, the probe learns the verdict
-        # (compile / compile_cached / compile_fetched in the ledger),
-        # and fetches land a compile_fetch trace span.  Env unset ⇒
-        # None installed, the jit path is byte-identical.
+        # local store dir is pinned — the trainer's programs go
+        # lower → key → fetch-or-compile, and fetches land a compile_fetch
+        # trace span.  Env unset ⇒ None installed: lower → compile.
         from tpucfn.compilecache import configure_from_env
 
-        configure_from_env(tracer=tracer, registry=registry,
-                           probe=compile_probe)
-        obs = TrainerObs(registry, tracer, ledger=ledger, flight=flight,
-                         compile_probe=compile_probe)
+        configure_from_env(tracer=tracer, registry=registry)
+        obs = TrainerObs(registry, tracer, ledger=ledger, flight=flight)
+        # Every program the trainer compiles writes one step_program span
+        # (what it is made of, where its compile came from: the first
+        # step's compile / compile_cached / compile_fetched in the ledger).
+        trainer.on_program = obs.record_program
         obs_srv = start_obs_server(
             registry, role="trainer", host_id=host,
             health_fn=lambda: (True, {"step": obs.last_step.value}),

@@ -34,12 +34,10 @@ def main() -> int:
     from tpucfn.compilecache import configure_from_env
     from tpucfn.compilecache.jit import maybe_warm
     from tpucfn.obs.goodput import GoodputLedger
-    from tpucfn.obs.profiler import CompileCacheProbe
     from tpucfn.obs.registry import MetricRegistry
     from tpucfn.train.trainer import TrainerObs
 
-    probe = CompileCacheProbe(work / "xla-cache")
-    client = configure_from_env(probe=probe)
+    client = configure_from_env()
     assert client is not None, "drill env must carry the cache fan-out"
 
     def fn(x):
@@ -50,7 +48,8 @@ def main() -> int:
 
     step = maybe_warm(jax.jit(fn), label="ft_drill")
     ledger = GoodputLedger(work / "goodput", host)
-    obs = TrainerObs(MetricRegistry(), ledger=ledger, compile_probe=probe)
+    obs = TrainerObs(MetricRegistry(), ledger=ledger)
+    step.on_program = obs.record_program  # the bucket follows the outcome
     x = np.full((16, 16), 0.01, np.float32)
     with obs.step(1):
         out = float(step(x))

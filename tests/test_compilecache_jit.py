@@ -52,7 +52,10 @@ def test_configure_from_env_absent_installs_nothing():
     assert get_default_client() is None
 
 
-def test_trainer_jit_untouched_without_client():
+def test_trainer_step_is_the_one_path_without_client():
+    """No client configured: the trainer's step is the same holder with
+    nothing in front of its compile — it lowers and compiles once, calls
+    the ``Compiled`` from then on, and says what it compiled."""
     from tpucfn.mesh import MeshSpec, build_mesh
     from tpucfn.parallel.presets import dense_rules
     from tpucfn.train.trainer import Trainer
@@ -69,10 +72,33 @@ def test_trainer_jit_untouched_without_client():
 
     tr = Trainer(mesh, dense_rules(fsdp=False), loss_fn,
                  optax.sgd(0.1), init_fn)
+    heard = []
+    tr.on_program = lambda compiled, **about: heard.append(about)
     state = tr.init(jax.random.key(0))
-    state, _ = tr.step(state, {"x": np.ones((8, 4), np.float32)})
-    # the compiled step is the plain jax.jit result, not a WarmJit
-    assert not isinstance(tr._jit_step, WarmJit)
+    for _ in range(3):
+        state, _ = tr.step(state, {"x": np.ones((8, 4), np.float32)})
+    assert isinstance(tr._jit_step, WarmJit) and tr._jit_step.client is None
+    assert tr._jit_step._fast is not None and not tr._jit_step._disabled
+    assert [h["label"] for h in heard] == ["train_step"]
+    assert heard[0]["outcome"] in ("hit", "miss")
+    assert heard[0]["lower_start"] <= heard[0]["compile_start"] \
+        <= heard[0]["compile_end"]
+
+
+def test_without_client_a_compile_error_reaches_the_caller():
+    """There is no artifact plane to degrade from: what the compiler (or
+    the lowering) raises is the caller's, once, not a second attempt
+    through the plain jit."""
+    calls = []
+
+    def bad(x):
+        calls.append(1)
+        raise RuntimeError("cannot trace this")
+
+    w = WarmJit(jax.jit(bad), None, label="bad")
+    with pytest.raises(RuntimeError, match="cannot trace this"):
+        w(np.ones((2,), np.float32))
+    assert calls == [1] and not w._disabled
 
 
 # -- fingerprinting ---------------------------------------------------------
@@ -182,57 +208,39 @@ def test_trainer_trajectory_bit_identical_with_cache(tmp_path):
 
 # -- probe / goodput split --------------------------------------------------
 
-def test_probe_mark_outcomes(tmp_path):
-    from tpucfn.obs.profiler import CompileCacheProbe
-
-    probe = CompileCacheProbe(tmp_path)
-    assert probe.outcome() is None
-    probe.mark("fetch")
-    assert probe.outcome() == "fetch" and probe.hit() is True
-    probe.mark("store")
-    assert probe.outcome() == "hit" and probe.hit() is True
-    probe.mark("compile")
-    assert probe.outcome() == "miss" and probe.hit() is False
-    probe.rearm()  # first-step entry clears explicit marks too
-    assert probe.outcome() is None
-
-
-def test_client_marks_probe_and_ledger_buckets(tmp_path):
-    """End-to-end bucket split: the client's verdict reaches the probe,
-    TrainerObs charges the right first-step bucket, and the merge
-    reports the new compile_fetched column."""
+@pytest.mark.parametrize("fleet, bucket", [
+    ("compile", "compile"), ("store", "compile_cached"),
+    ("fetch", "compile_fetched")])
+def test_client_verdict_reaches_the_ledger_bucket(tmp_path, fleet, bucket):
+    """End-to-end bucket split: the client's verdict becomes the program's
+    ``outcome``, TrainerObs charges the first step's bucket from it, and
+    the merge reports the column."""
     from tpucfn.obs.goodput import (GoodputLedger, REPORT_BUCKETS,
                                     host_goodput, read_goodput_dir)
-    from tpucfn.obs.profiler import CompileCacheProbe
+    from tpucfn.obs.registry import MetricRegistry
     from tpucfn.train.trainer import TrainerObs
 
-    assert "compile_fetched" in REPORT_BUCKETS
-
-    probe = CompileCacheProbe(tmp_path / "xla")
+    assert bucket in REPORT_BUCKETS
     c = _client(tmp_path)
-    c.probe = probe
-    fn = jax.jit(lambda x: x.sum())
-    w = maybe_warm(fn, label="probe", client=c)
-
-    from tpucfn.obs.registry import MetricRegistry
-
+    real = c.get_or_compile
+    # the artifact came from where ``fleet`` says (a peer, the local store,
+    # the compiler): the program is the same either way
+    c.get_or_compile = lambda key, fn, **kw: (real(key, fn, **kw)[0], fleet)
     ledger = GoodputLedger(tmp_path / "gp", 0)
-    obs = TrainerObs(MetricRegistry(), ledger=ledger, compile_probe=probe)
+    obs = TrainerObs(MetricRegistry(), ledger=ledger)
+    w = WarmJit(jax.jit(lambda x: x.sum()), c, label="probe",
+                on_program=obs.record_program)
     with obs.step(1):
         w(np.ones((4,), np.float32))
-    # simulate: the artifact came from a fleet peer.  The mark lands
-    # INSIDE the step (where the warm path runs) — step entry rearm()s
-    # the probe, exactly like the real first step.
-    obs2 = TrainerObs(MetricRegistry(), ledger=ledger, compile_probe=probe)
-    with obs2.step(2):
-        probe.mark("fetch")
+    with obs.step(2):
+        w(np.ones((4,), np.float32))
     ledger.close()
     by_host, _ = read_goodput_dir(tmp_path / "gp")
     rep = host_goodput(by_host[0])
-    # first TrainerObs charged compile (client compiled), second
-    # charged compile_fetched (explicit fetch mark)
-    assert rep["buckets"]["compile"] > 0
-    assert rep["buckets"]["compile_fetched"] > 0
+    assert rep["buckets"][bucket] > 0
+    assert all(rep["buckets"][b] == 0 for b in
+               ("compile", "compile_cached", "compile_fetched")
+               if b != bucket)
 
 
 def test_warm_jit_fast_path_single_bucket(tmp_path):

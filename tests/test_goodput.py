@@ -409,64 +409,34 @@ def test_compile_cached_is_its_own_bucket_and_advances_max_step(tmp_path):
     assert abs(rep["unaccounted_s"]) < 1e-9
 
 
-def test_compile_cache_probe_decides_the_bucket(tmp_path):
-    from tpucfn.obs import CompileCacheProbe
+@pytest.mark.parametrize("outcome, bucket", [
+    ("miss", "compile"), ("hit", "compile_cached"),
+    ("fetch", "compile_fetched"), (None, "compile")])
+def test_the_program_outcome_decides_the_bucket(tmp_path, outcome, bucket):
+    """The first step's charge follows where its program came from: a
+    compile (or a first step that compiled nothing the trainer heard of)
+    is ``compile``, a cache's hit ``compile_cached``, a fleet peer's
+    artifact ``compile_fetched``; a program compiled outside any step (an
+    eval's) decides nothing."""
     from tpucfn.train.trainer import TrainerObs
 
-    cache = tmp_path / "xla_cache"
-
-    def run_first_step(probe, ledger_dir, during_step=None):
-        clk = FakeClock(0.0)
-        led = GoodputLedger(ledger_dir, 0, clock=clk)
-        obs = TrainerObs(MetricRegistry(), ledger=led, clock=clk,
-                         compile_probe=probe)
-        with obs.step(1):
-            if during_step is not None:
-                during_step()
-            clk.advance(1.0)
-        led.close()
-        recs, _ = read_jsonl_counting(
-            ledger_dir / "goodput-host000.jsonl")
-        return [r["bucket"] for r in recs if r.get("kind") == "phase"]
-
-    # cold: XLA persists a new entry DURING the first step -> compile
-    cache.mkdir()
-    (cache / "step-atime").write_bytes(b"\0" * 8)  # pre-existing pair
-    (cache / "step-cache").write_bytes(b"x")
-    probe = CompileCacheProbe(cache)
-    assert run_first_step(
-        probe, tmp_path / "cold",
-        during_step=lambda: (cache / "new-cache").write_text("x"),
-    ) == ["compile"]
-    # warm: jax's cache get() rewrites the *-atime sidecar on every
-    # read — a served-from-cache first step leaves exactly that trace
-    probe2 = CompileCacheProbe(cache)
-    assert run_first_step(
-        probe2, tmp_path / "warm",
-        during_step=lambda: (cache / "step-atime").write_bytes(b"\1" * 8),
-    ) == ["compile_cached"]
-    # a SHARED non-empty cache holding none of this run's programs:
-    # nothing read, nothing written -> unknown -> plain compile (a
-    # sub-threshold cold compile must NOT read as a phantom hit)
-    probe3 = CompileCacheProbe(cache)
-    assert run_first_step(probe3, tmp_path / "shared") == ["compile"]
-    # resumed run: the restore path writes/reads entries BEFORE step 1;
-    # the rearm at step entry discounts them, and step 1's own cache
-    # read still lands the hit
-    probe4 = CompileCacheProbe(cache)
-    (cache / "restore-cache").write_text("x")   # restore's own program
-    (cache / "step-atime").write_bytes(b"\2" * 8)  # restore-path read
-    assert run_first_step(
-        probe4, tmp_path / "resumed",
-        during_step=lambda: (cache / "step-atime").write_bytes(b"\3" * 8),
-    ) == ["compile_cached"]
-    # unknown: empty cache, nothing written -> plain compile
-    empty = tmp_path / "empty_cache"
-    probe5 = CompileCacheProbe(empty)
-    assert probe5.hit() is None
-    assert run_first_step(probe5, tmp_path / "unk") == ["compile"]
-    # no probe at all keeps the historical charge
-    assert run_first_step(None, tmp_path / "noprobe") == ["compile"]
+    clk = FakeClock(0.0)
+    led = GoodputLedger(tmp_path, 0, clock=clk)
+    obs = TrainerObs(MetricRegistry(), ledger=led, clock=clk)
+    about = dict(label="train_step", lower_start=0.0, compile_start=0.1,
+                 compile_end=0.9)
+    obs.record_program(None, outcome="fetch", **{**about,
+                                                 "label": "train_eval"})
+    with obs.step(1):
+        if outcome is not None:
+            obs.record_program(None, outcome=outcome, **about)
+        clk.advance(1.0)
+    with obs.step(2):
+        clk.advance(0.5)
+    led.close()
+    recs, _ = read_jsonl_counting(tmp_path / "goodput-host000.jsonl")
+    assert [r["bucket"] for r in recs if r.get("kind") == "phase"] == [
+        bucket, "step"]
 
 
 # -- fleet warm start (ISSUE 13) ---------------------------------------------

@@ -29,8 +29,8 @@ half (jax's persistent compile cache); this package is the fleet half:
 The goodput ledger splits the first step's charge three ways —
 ``compile`` (a real XLA compile ran), ``compile_cached`` (jax's
 persistent cache or the local artifact store served it), and
-``compile_fetched`` (a fleet peer's artifact was fetched) — via the
-extended :class:`~tpucfn.obs.profiler.CompileCacheProbe`.
+``compile_fetched`` (a fleet peer's artifact was fetched) — from the
+``outcome`` the trainer's ``step_program`` span carries.
 """
 
 from tpucfn.compilecache.store import (  # noqa: F401
@@ -50,7 +50,7 @@ from tpucfn.compilecache.service import (  # noqa: F401
 )
 
 
-def configure_from_env(*, tracer=None, registry=None, probe=None, env=None):
+def configure_from_env(*, tracer=None, registry=None, env=None):
     """Build and install the process-default compile-cache client from
     the launcher's env fan-out.  Returns the client, or None when
     neither ``TPUCFN_COMPILE_CACHE_ADDRS`` nor
@@ -64,4 +64,4 @@ def configure_from_env(*, tracer=None, registry=None, probe=None, env=None):
     from tpucfn.compilecache.jit import configure_client_from_env
 
     return configure_client_from_env(tracer=tracer, registry=registry,
-                                     probe=probe, env=env)
+                                     env=env)

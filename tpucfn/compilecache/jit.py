@@ -1,8 +1,8 @@
 """jax glue for the artifact cache: fingerprint → fetch-or-compile.
 
-``maybe_warm(jitted, label=...)`` is the one integration point the
-trainer and serve engine use: it wraps a ``jax.jit`` callable so the
-first call per avals-signature runs
+``maybe_warm(jitted, label=...)`` is the integration point the serve
+engine, the RL plane and the trainer's init use: it wraps a ``jax.jit``
+callable in a :class:`WarmJit` so the first call per avals-signature runs
 
     lower (cheap) → cache key (BEFORE compiling — a hit skips the
     compile entirely) → local store / fleet fetch / single-flight
@@ -10,7 +10,11 @@ first call per avals-signature runs
 
 and subsequent calls go straight to the compiled executable.  With no
 client configured it returns the jitted callable itself — the pinned
-byte-identical default.
+byte-identical default.  The trainer's step and eval programs are always a
+:class:`WarmJit` (``Trainer._program``): with no client that is lower →
+compile → call the ``Compiled``, the same one path without the fetch, so
+that the program can time its compile and say what it compiled
+(``obs.program``).
 
 Serialization uses jax's AOT export surface
 (``jax.experimental.serialize_executable.serialize`` /
@@ -37,6 +41,7 @@ from __future__ import annotations
 import hashlib
 import json
 import threading
+import time
 from typing import Any, Callable
 
 from tpucfn.compilecache.service import (
@@ -77,7 +82,7 @@ def runtime_identity() -> tuple[str, str]:
     return kind, f"{jax.__version__}/{getattr(jaxlib, '__version__', '?')}"
 
 
-def configure_client_from_env(*, tracer=None, registry=None, probe=None,
+def configure_client_from_env(*, tracer=None, registry=None,
                               env=None) -> CompileCacheClient | None:
     """Install the process-default client per the launcher fan-out.
     ``TPUCFN_COMPILE_CACHE_ADDRS`` and/or ``TPUCFN_COMPILE_CACHE_DIR``
@@ -99,7 +104,7 @@ def configure_client_from_env(*, tracer=None, registry=None, probe=None,
                           jax_version=jax_version)
     client = CompileCacheClient(
         store, addrs, device_kind=device_kind, jax_version=jax_version,
-        registry=registry, tracer=tracer, probe=probe)
+        registry=registry, tracer=tracer)
     set_default_client(client)
     return client
 
@@ -181,29 +186,39 @@ def deserialize_compiled(payload: bytes, meta: dict):
 # -- the wrapper ------------------------------------------------------------
 
 def _avals_signature(args: tuple, kwargs: dict) -> tuple:
-    """Hashable (shape, dtype) tree signature of one call — what keys
-    the per-wrapper executable memo (bucketed serve prefills get one
+    """Hashable (shape, dtype, weak type) tree signature of one call — what
+    keys the per-wrapper executable memo (bucketed serve prefills get one
     entry per bucket, the trainer exactly one)."""
     import jax
 
     leaves, treedef = jax.tree_util.tree_flatten((args, kwargs))
     return (treedef,
             tuple((getattr(x, "shape", None),
-                   str(getattr(x, "dtype", type(x).__name__)))
+                   str(getattr(x, "dtype", type(x).__name__)),
+                   bool(getattr(x, "weak_type", False)))
                   for x in leaves))
 
 
 class WarmJit:
-    """Callable wrapper over one ``jax.jit`` result that routes each
-    new avals-signature through the artifact cache.  Thread-safe; any
-    warm-path failure disables the wrapper (plain jit from then on) —
-    degradation is always to the exact same program."""
+    """One ``jax.jit`` result, lowered and compiled explicitly: each new
+    avals-signature is lowered at the call's arguments, compiled — through
+    the artifact cache where a ``client`` is given, by the compiler (and
+    JAX's persistent cache) where it is None — and the ``Compiled`` is
+    called from then on.  ``on_program(compiled, label=, outcome=,
+    lower_start=, compile_start=, compile_end=)`` hears of every program:
+    ``outcome`` is ``fetch`` (a fleet peer's artifact), ``hit`` (the local
+    artifact store, or JAX's persistent cache) or ``miss`` (the compiler
+    ran).  Thread-safe.  A failure of the artifact plane disables the
+    wrapper (plain jit from then on) — degradation is always to the exact
+    same program; with no client there is no plane to fail, and what the
+    compiler raises reaches the caller."""
 
-    def __init__(self, jitted, client: CompileCacheClient, *,
-                 label: str = ""):
+    def __init__(self, jitted, client: CompileCacheClient | None = None, *,
+                 label: str = "", on_program: Callable | None = None):
         self._jit = jitted
         self.client = client
         self.label = label
+        self.on_program = on_program
         self._compiled: dict[tuple, Any] = {}
         # Steady-state fast path: while exactly ONE shape bucket exists
         # (the trainer's every-step case), dispatch straight to its
@@ -222,7 +237,7 @@ class WarmJit:
     def _cache_size(self) -> int:
         """Resolved-executable count, the duck-type the
         ``jit_cache_programs`` gauge reads (obs.metrics ``jit_sources``):
-        warm buckets live in ``_compiled``, plus whatever the underlying
+        the buckets in ``_compiled``, plus whatever the underlying
         jit compiled itself on the degraded path."""
         try:
             n = int(self._jit._cache_size())
@@ -230,16 +245,31 @@ class WarmJit:
             n = 0
         return n + len(self._compiled)
 
-    def _warm(self, args, kwargs):
+    def _build(self, args, kwargs):
+        from tpucfn.obs.program import CacheVerdict
+
+        lower_start = time.monotonic()
         lowered = self._jit.lower(*args, **kwargs)
-        key = lowered_fingerprint(lowered, label=self.label)
-        result, _outcome = self.client.get_or_compile(
-            key,
-            lambda: lowered.compile(),
-            serialize_fn=_serialize_or_none,
-            deserialize_fn=deserialize_compiled,
-            label=self.label)
-        return result
+        compile_start = time.monotonic()
+        with CacheVerdict() as verdict:
+            if self.client is None:
+                compiled = lowered.compile()
+                outcome = verdict.outcome
+            else:
+                compiled, fleet = self.client.get_or_compile(
+                    lowered_fingerprint(lowered, label=self.label),
+                    lowered.compile,
+                    serialize_fn=_serialize_or_none,
+                    deserialize_fn=deserialize_compiled,
+                    label=self.label)
+                outcome = {"fetch": "fetch", "store": "hit"}.get(
+                    fleet, verdict.outcome)
+        if self.on_program is not None:
+            self.on_program(compiled, label=self.label, outcome=outcome,
+                            lower_start=lower_start,
+                            compile_start=compile_start,
+                            compile_end=time.monotonic())
+        return compiled
 
     def __call__(self, *args, **kwargs):
         if self._disabled:
@@ -264,8 +294,10 @@ class WarmJit:
                 compiled = self._compiled.get(sig)
                 if compiled is None:
                     try:
-                        compiled = self._warm(args, kwargs)
+                        compiled = self._build(args, kwargs)
                     except Exception:  # noqa: BLE001 — degrade, bit-identical
+                        if self.client is None:
+                            raise
                         self._disabled = True
                         return self._jit(*args, **kwargs)
                     self._compiled[sig] = compiled

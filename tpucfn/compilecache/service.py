@@ -599,9 +599,9 @@ class CompileCacheClient:
     jax-free orchestration: ``compile_fn``/``serialize_fn``/
     ``deserialize_fn`` are injected per call, so the jax glue
     (:mod:`tpucfn.compilecache.jit`) and the stampede tests share one
-    implementation.  Outcomes (also marked on the attached
-    :class:`~tpucfn.obs.profiler.CompileCacheProbe` and counted on the
-    registry):
+    implementation.  Outcomes (returned, kept as ``last_outcome`` and
+    counted on the registry; ``compilecache.jit.WarmJit`` hands them on
+    as its program's ``outcome``):
 
     * ``"store"``   — the local artifact store had it (warm restart on
       the same machine); ledger bucket ``compile_cached``;
@@ -615,7 +615,7 @@ class CompileCacheClient:
     def __init__(self, store: ArtifactStore | None,
                  addrs: Sequence[str] = (), *,
                  device_kind: str = "", jax_version: str = "",
-                 registry=None, tracer=None, probe=None,
+                 registry=None, tracer=None,
                  wait_s: float = 600.0, poll_s: float = 0.25,
                  connect_timeout_s: float = 5.0,
                  op_deadline_s: float | None = None,
@@ -627,7 +627,6 @@ class CompileCacheClient:
         self.device_kind = device_kind
         self.jax_version = jax_version
         self.tracer = tracer
-        self.probe = probe
         self.wait_s = wait_s
         self.poll_s = poll_s
         self.connect_timeout_s = connect_timeout_s
@@ -677,11 +676,6 @@ class CompileCacheClient:
 
     def _mark(self, outcome: str) -> None:
         self.last_outcome = outcome
-        if self.probe is not None:
-            try:
-                self.probe.mark(outcome)
-            except Exception:  # noqa: BLE001 — the probe is best-effort
-                pass
 
     def _try_deserialize(self, key: str, payload: bytes, meta: dict,
                          deserialize_fn):

@@ -270,6 +270,9 @@ def sharding_rules(cfg: LlamaConfig, *, fsdp: bool = True, tensor: bool = True,
     ))
 
 
+# no Flax module, so no scope of its own: this names the head's product for the
+# trace's map (obs.program); metadata, nothing that runs
+@jax.named_scope("lm_head")
 def chunked_causal_lm_loss(
     hidden: jax.Array,          # (B, S, D) — Llama(...)(…, return_hidden=True)
     lm_head_kernel: jax.Array,  # (D, V)

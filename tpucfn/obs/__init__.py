@@ -20,7 +20,6 @@ from tpucfn.obs.goodput import (  # noqa: F401
     read_goodput_dir,
 )
 from tpucfn.obs.profiler import (  # noqa: F401
-    CompileCacheProbe,
     ProfileCapture,
     ProfilerBusy,
     enable_compile_cache,

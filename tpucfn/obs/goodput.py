@@ -64,7 +64,7 @@ from typing import Iterable
 # restart_downtime are derived by the merge.  ``compile_cached`` is the
 # warm-restart refinement (ISSUE 6 satellite): a first step served from
 # the persistent compile cache pays deserialization + warmup, not a real
-# XLA compile — ``TrainerObs`` splits the two via CompileCacheProbe so
+# XLA compile — ``TrainerObs`` splits the two by its program's outcome so
 # warm restarts stop inflating ``compile``.  ``compile_fetched`` is the
 # fleet refinement (ISSUE 13): a first step whose executable was fetched
 # from a peer's artifact cache paid network + deserialization — its own

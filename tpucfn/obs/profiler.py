@@ -17,7 +17,7 @@ import time
 from pathlib import Path
 
 # jax is imported lazily at the trace/config call sites: this module's
-# CompileCacheProbe and ProfileCapture plumbing also run on the jax-free
+# ProfileCapture plumbing also runs on the jax-free
 # planes (obs server routes, `tpucfn check`), where a top-level import
 # would drag the whole runtime in.
 
@@ -141,105 +141,6 @@ class ProfileCapture:
                     "dur_s": round(t1 - t0, 4)}
         finally:
             self._lock.release()
-
-
-class CompileCacheProbe:
-    """Did the first step's XLA compile come from the persistent cache?
-
-    The goodput ledger charges the whole first step of each incarnation
-    to ``compile``; a warm restart (persistent cache hit via
-    :func:`enable_compile_cache`) pays deserialization + warmup instead
-    of a real compile, and lumping the two inflates the bucket (ISSUE 6
-    satellite).  The signal is the cache directory itself, observed
-    over the first step (arm/:meth:`rearm` before, :meth:`hit` after):
-
-    * new entries appeared -> the compiler ran and persisted: **miss**;
-    * an existing ``*-atime`` sidecar was rewritten -> jax's cache
-      ``get`` unconditionally stamps the access-time file on every
-      read, so a served-from-cache load leaves exactly this trace:
-      **hit**;
-    * neither -> **unknown** — the cache is disabled, the layout has no
-      atime sidecars, or the compile ran under the min-compile-time
-      persistence threshold (nothing read, nothing written) — charge
-      plain ``compile``; no number beats a wrong number.  Notably a
-      SHARED non-empty cache dir holding none of this run's programs
-      stays unknown, not a phantom hit.
-
-    The fleet artifact plane (ISSUE 13) bypasses jax's persistent
-    cache entirely — a fetched AOT executable deserializes without
-    touching this directory — so the
-    :class:`~tpucfn.compilecache.service.CompileCacheClient` reports
-    its verdict explicitly through :meth:`mark`; an explicit mark wins
-    over the directory heuristic.  :meth:`outcome` is the three-way
-    answer the goodput ledger buckets on: ``"fetch"`` (a fleet peer's
-    artifact) / ``"hit"`` (persistent cache or local artifact store) /
-    ``"miss"`` (a real compile ran) / None (unknown).
-    """
-
-    def __init__(self, cache_dir: str | Path):
-        self.cache_dir = Path(cache_dir)
-        self._before = self._snapshot()
-        self._mark: str | None = None
-
-    def _snapshot(self) -> tuple[int, int]:
-        """(entry count, newest ``*-atime`` mtime_ns): persists move
-        the first, cache reads move the second."""
-        count, atime_ns = 0, 0
-        try:
-            for p in self.cache_dir.iterdir():
-                count += 1
-                if p.name.endswith("-atime"):
-                    try:
-                        atime_ns = max(atime_ns, p.stat().st_mtime_ns)
-                    except OSError:
-                        continue  # racing eviction
-        except OSError:
-            pass
-        return count, atime_ns
-
-    def rearm(self) -> None:
-        """Re-snapshot both signals (and clear any explicit mark).
-        TrainerObs calls this at the FIRST step's entry: programs
-        compiled (or cache-loaded) between enabling the cache and the
-        loop reaching step 1 — checkpoint restore's re-materialize
-        copy, eval_shape probes — move them too, and counting those
-        against the step would misread every resumed run."""
-        self._before = self._snapshot()
-        self._mark = None
-
-    def mark(self, outcome: str) -> None:
-        """Explicit verdict from the artifact plane, recorded as the
-        compile ran: ``"fetch"`` (fleet artifact installed),
-        ``"store"`` (local artifact store hit), ``"compile"`` (the
-        client compiled for real).  Wins over the directory heuristic
-        in :meth:`outcome` — the artifact path never touches the
-        persistent-cache dir, so the heuristic cannot see it."""
-        self._mark = outcome
-
-    def hit(self) -> bool | None:
-        if self._mark is not None:
-            return self._mark in ("fetch", "store")
-        count, atime_ns = self._snapshot()
-        if count > self._before[0]:
-            return False
-        if atime_ns > self._before[1]:
-            return True
-        return None
-
-    def outcome(self) -> str | None:
-        """``"fetch"`` | ``"hit"`` | ``"miss"`` | None (unknown) — the
-        goodput split: fetch → ``compile_fetched``, hit →
-        ``compile_cached``, miss/None → ``compile``."""
-        if self._mark == "fetch":
-            return "fetch"
-        if self._mark == "store":
-            return "hit"
-        if self._mark == "compile":
-            return "miss"
-        h = self.hit()
-        if h is None:
-            return None
-        return "hit" if h else "miss"
 
 
 @contextlib.contextmanager

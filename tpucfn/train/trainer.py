@@ -14,6 +14,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import logging
 import time
 from typing import Any, Callable
 
@@ -116,6 +117,9 @@ class Trainer:
         self.config = config
         self._jit_step = None
         self._jit_eval = None
+        # Who hears of every program ``step`` and ``eval_step`` compile
+        # (``TrainerObs.record_program``, set by the loop); None: nobody.
+        self.on_program: Callable | None = None
         self._state_shardings = None
         self._abstract_state = None
 
@@ -169,9 +173,11 @@ class Trainer:
         are *born sharded* on their owner devices (no host staging, no
         broadcast; the analogue of the reference's rank-0-initializes-then-
         KVStore-pushes startup, minus the wire traffic)."""
-        return self._maybe_warm(
+        from tpucfn.compilecache.jit import maybe_warm
+
+        return maybe_warm(
             jax.jit(self._create_state, out_shardings=self.state_shardings()),
-            "train_init")(rng)
+            label="train_init")(rng)
 
     def init_or_resume(self, rng: jax.Array, ckpt=None, *,
                        fresh: bool = False) -> tuple[TrainState, int | None]:
@@ -206,17 +212,22 @@ class Trainer:
             self._abstract(), sh,
         )
 
-    # ---- fleet warm start (ISSUE 13) ------------------------------------
+    # ---- the step's program ---------------------------------------------
 
-    def _maybe_warm(self, jitted, label: str):
-        """Route this jit through the fleet compile-artifact cache when
-        a client is configured (``tpucfn.compilecache`` — the launcher
-        fans out ``TPUCFN_COMPILE_CACHE_ADDRS``); with none configured
-        ``maybe_warm`` returns the jitted callable UNCHANGED —
-        byte-identical behavior, pinned by test_compilecache."""
-        from tpucfn.compilecache.jit import maybe_warm
+    def _program(self, jitted, label: str):
+        """The step's one lower -> compile path: the jitted function is
+        lowered at the first call's arguments and compiled explicitly (by
+        the fleet's artifact cache where a client is configured), the
+        executable is kept and called from then on, and ``on_program``
+        hears what was compiled.  ``.lower`` is the jitted function's."""
+        from tpucfn.compilecache.jit import WarmJit, get_default_client
 
-        return maybe_warm(jitted, label=label)
+        def heard(compiled, **about):
+            if self.on_program is not None:
+                self.on_program(compiled, **about)
+
+        return WarmJit(jitted, get_default_client(), label=label,
+                       on_program=heard)
 
     # ---- step ----------------------------------------------------------
 
@@ -264,8 +275,11 @@ class Trainer:
     def _step_fn(self, state: TrainState, batch: Any):
         step_rng = jax.random.fold_in(state.rng, state.step)
         loss, aux, new_model_state, grads = self._grads(state, batch, step_rng)
-        updates, new_opt = self.tx.update(grads, state.opt_state, state.params)
-        new_params = optax.apply_updates(state.params, updates)
+        # a name for the trace's map (obs.program): metadata, nothing that runs
+        with jax.named_scope("optimizer"):
+            updates, new_opt = self.tx.update(grads, state.opt_state,
+                                              state.params)
+            new_params = optax.apply_updates(state.params, updates)
         if self.config.ema_decay:
             # Post-update EMA; owns model_state["ema"] (re-attached even
             # when a loss_fn rebuilds its model_state from scratch).
@@ -289,7 +303,7 @@ class Trainer:
         if self._jit_step is None:
             shardings = self.state_shardings()
             metric_spec = NamedSharding(self.mesh, P())
-            self._jit_step = self._maybe_warm(jax.jit(
+            self._jit_step = self._program(jax.jit(
                 self._step_fn,
                 in_shardings=(shardings, self.batch_sharding()),
                 out_shardings=(shardings, metric_spec),
@@ -306,7 +320,7 @@ class Trainer:
                     state.params, state.model_state, batch, state.rng
                 )
                 return {"loss": loss, **aux}
-            self._jit_eval = self._maybe_warm(jax.jit(
+            self._jit_eval = self._program(jax.jit(
                 _eval,
                 in_shardings=(self.state_shardings(), self.batch_sharding()),
                 out_shardings=NamedSharding(self.mesh, P()),
@@ -356,8 +370,7 @@ class TrainerObs:
     """
 
     def __init__(self, registry=None, tracer=None, *, prefix: str = "train",
-                 ledger=None, clock=time.monotonic, flight=None,
-                 compile_probe=None):
+                 ledger=None, clock=time.monotonic, flight=None):
         """``ledger`` is a :class:`tpucfn.obs.goodput.GoodputLedger` (or
         None): every phase the loop reports is also attributed to the
         per-host goodput JSONL so ``tpucfn obs goodput`` can decompose
@@ -367,12 +380,7 @@ class TrainerObs:
         ``flight`` is a :class:`tpucfn.obs.flight.FlightRecorder` (or
         None): every phase also lands one sample in the in-memory ring,
         plus an ``hbm`` device-memory sample per step — the last-N-
-        seconds record a postmortem reads (ISSUE 6).  ``compile_probe``
-        is a :class:`tpucfn.obs.profiler.CompileCacheProbe` (or None):
-        when it reports the first step was served from the persistent
-        compile cache, the ledger charges ``compile_cached`` instead of
-        ``compile``, so warm restarts stop inflating the compile
-        bucket."""
+        seconds record a postmortem reads (ISSUE 6)."""
         from tpucfn.obs.goodput import GoodputLedger
         from tpucfn.obs.registry import default_registry
         from tpucfn.obs.trace import Tracer
@@ -384,7 +392,10 @@ class TrainerObs:
         self.ledger = ledger if ledger is not None else GoodputLedger(None)
         self.clock = clock
         self.flight = flight
-        self.compile_probe = compile_probe
+        # the step span that is open, as (step, span id), and where the
+        # program compiled inside the first one came from
+        self._open: tuple[int | None, int] | None = None
+        self._outcome: str | None = None
         self.step_time = r.histogram(
             f"{prefix}_step_seconds", "host-observed step wall time")
         self.data_wait_time = r.histogram(
@@ -420,35 +431,44 @@ class TrainerObs:
             if self.flight is not None:
                 self.flight.record(name, step=step, dur_s=dt)
 
-    def _compile_bucket(self) -> str:
-        """``compile`` vs ``compile_cached`` vs ``compile_fetched`` for
-        the first step (ISSUE 6/13): the probe's verdict decides — a
-        fleet-fetched AOT executable gets its own bucket so the warm-
-        start plane's effect is visible in the ledger; no probe, or an
-        unknown/throwing probe, keeps the plain ``compile`` charge."""
-        if self.compile_probe is None:
-            return "compile"
+    def record_program(self, compiled, *, label: str, outcome: str,
+                       **clock_readings: float) -> None:
+        """What ``Trainer.on_program`` is set to: one ``step_program`` span
+        (``obs.program.record_program``) about a program the trainer has
+        just compiled, ``trace_id`` the global step it compiled at and its
+        parent the ``step`` span it fell in.  ``outcome`` (``hit``,
+        ``miss``, ``fetch``) also decides the first step's goodput
+        bucket."""
+        from tpucfn.obs.program import record_program
+
+        step, parent = self._open if self._open else (
+            int(self.last_step.value) or None, None)
+        if self._open:
+            self._outcome = outcome
         try:
-            outcome = self.compile_probe.outcome() \
-                if hasattr(self.compile_probe, "outcome") \
-                else {True: "hit", False: "miss"}.get(
-                    self.compile_probe.hit())
-        except Exception:  # noqa: BLE001 — the probe is best-effort
-            outcome = None
-        if outcome is not None:
-            self.tracer.event("compile_cache", outcome=outcome,
-                              hit=outcome in ("hit", "fetch"))
-        if outcome == "fetch":
-            return "compile_fetched"
-        if outcome == "hit":
-            return "compile_cached"
-        return "compile"
+            record_program(self.tracer, compiled, label=label,
+                           outcome=outcome, trace_id=step, parent_id=parent,
+                           **clock_readings)
+        except Exception:  # noqa: BLE001 — the span must not stop the job
+            logging.getLogger(__name__).exception(
+                "no step_program span for %s", label)
+
+    def _compile_bucket(self) -> str:
+        """``compile`` vs ``compile_cached`` vs ``compile_fetched`` for the
+        first step (ISSUE 6/13), from where its program came: a fleet-fetched
+        executable gets its own bucket so the warm-start plane's effect is
+        visible in the ledger, a hit in a cache (JAX's persistent one or the
+        local artifact store) is ``compile_cached``, and a compile, or a
+        first step that compiled nothing the trainer heard of, keeps the
+        plain ``compile`` charge."""
+        return {"fetch": "compile_fetched",
+                "hit": "compile_cached"}.get(self._outcome, "compile")
 
     def _record_step(self, step: int | None, dur_s: float) -> None:
         """Shared post-step bookkeeping: the first step of a process is
         compile-dominated and lands in the ``compile`` bucket — or
-        ``compile_cached`` when the probe says the persistent cache
-        served it (the StepTimer warmup-exclusion rule applied to
+        ``compile_cached`` when a cache served its program
+        (the StepTimer warmup-exclusion rule applied to
         accounting); steady steps are ``step`` and feed the live
         efficiency gauges."""
         self._steps_seen += 1
@@ -496,23 +516,18 @@ class TrainerObs:
         reading.  A loop that never calls it writes ``step`` alone."""
         @contextlib.contextmanager
         def _span():
-            if self._steps_seen == 0 and self.compile_probe is not None:
-                # Arm the hit/miss baseline at the first step's ENTRY:
-                # anything the pre-loop path compiled (restore, probes)
-                # has already written its cache entries by now, so only
-                # this step's own compile moves the count.
-                try:
-                    self.compile_probe.rearm()
-                except Exception:  # noqa: BLE001 — probe is best-effort
-                    pass
             mark = StepMark(self.clock)
+            # drawn before the span is written: a program compiled inside
+            # the step names it as its parent
+            sid = self.tracer.next_span_id()
+            self._open = (step, sid)
             t0 = self.clock()
             try:
                 yield mark
             finally:
                 t1 = self.clock()
+                self._open = None
                 self.step_time.observe(t1 - t0)
-                sid = self.tracer.next_span_id()
                 self.tracer.record("step", start=t0, end=t1, trace_id=step,
                                    span_id=sid)
                 if mark.at is not None:
