@@ -16,7 +16,9 @@ the ``jnp`` rows answer ``cpu`` to that question, nothing else differs;
 ``pallas`` says whether the kernel pair ran (off a TPU both rows are ``jnp``'s).
 No cell of the benchmark runs this tool.
 
-Usage (on a TPU host):  python benches/gdn_bench.py [--seq 8192 ...]
+Usage (on a TPU host):  python benches/gdn_bench.py [--seq 8192 ...]; the
+shapes of ``olmohybrid-gdn-s16384``: ``--batch 1 --seq 16384 --key-heads 30
+--value-heads 30 --head-dim 96 --value-dim 192``.
 """
 
 from __future__ import annotations
@@ -50,6 +52,8 @@ def main() -> int:
     p.add_argument("--key-heads", type=int, default=16)
     p.add_argument("--value-heads", type=int, default=32)
     p.add_argument("--head-dim", type=int, default=128)
+    p.add_argument("--value-dim", type=int, default=None,
+                   help="the values' head size where it is not --head-dim")
     p.add_argument("--chunk", type=int, default=64)
     p.add_argument("--iters", type=int, default=10)
     args = p.parse_args()
@@ -63,13 +67,14 @@ def main() -> int:
     ks = jax.random.split(jax.random.key(0), 5)
     b, s, hk, hv, d = (args.batch, args.seq, args.key_heads, args.value_heads,
                        args.head_dim)
+    dv = args.value_dim or d
 
     def unit(x):
         return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + 1e-6)
 
     q = (unit(jax.random.normal(ks[0], (b, s, hk, d))) * d ** -0.5).astype(jnp.bfloat16)
     k = unit(jax.random.normal(ks[1], (b, s, hk, d))).astype(jnp.bfloat16)
-    v = jax.random.normal(ks[2], (b, s, hv, d), jnp.bfloat16)
+    v = jax.random.normal(ks[2], (b, s, hv, dv), jnp.bfloat16)
     g = -jax.random.uniform(ks[3], (b, s, hv), minval=0.0, maxval=0.1)
     beta = jax.nn.sigmoid(jax.random.normal(ks[4], (b, s, hv)))
 
@@ -80,7 +85,7 @@ def main() -> int:
         gated_delta._backend = (lambda: "cpu") if path == "jnp" else backend
 
         def rule(*a):
-            return gated_delta.gated_delta_rule(*a, chunk_size=args.chunk)
+            return gated_delta.gated_delta_rule(*a, chunk_size=args.chunk)[0]
 
         def loss(*a):
             return jnp.sum(rule(*a).astype(jnp.float32) ** 2)
@@ -90,11 +95,12 @@ def main() -> int:
         for name, fn in passes.items():
             print(json.dumps({
                 "path": path, "pass": name,
-                "pallas": gated_delta._kernel_serves(jnp.bfloat16, d, d, args.chunk),
+                "pallas": gated_delta._kernel_serves(jnp.bfloat16, args.chunk),
                 "median_ms": round(median_ms(fn, q, k, v, g, beta,
                                              iters=args.iters), 3),
                 "batch": b, "seq": s, "key_heads": hk, "value_heads": hv,
-                "head_dim": d, "chunk": args.chunk, "device": device}),
+                "head_dim": d, "value_dim": dv, "chunk": args.chunk,
+                "device": device}),
                 flush=True)
     gated_delta._backend = backend
     return 0

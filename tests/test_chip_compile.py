@@ -136,7 +136,7 @@ def test_hybrid_layer_ops_compile_for_v5e(one_chip):
     gb = sds((b, s, 32), jnp.float32)
     delta = jax.jit(jax.grad(
         lambda q, k, v, g, beta: jnp.sum(gated_delta_rule(
-            q, k, v, g, beta).astype(jnp.float32)), argnums=(0, 1, 2, 3, 4)))
+            q, k, v, g, beta)[0].astype(jnp.float32)), argnums=(0, 1, 2, 3, 4)))
     assert "while" in delta.lower(qk, qk, v, gb, gb).compile().as_text()
 
     rows, experts, d, f = 8192, 64, 2048, 512
@@ -212,18 +212,24 @@ def test_state_space_layer_ops_compile_for_v5e(one_chip):
     assert text.count("tpu_custom_call") == 3, text.count("tpu_custom_call")
 
 
-def test_gdn_prep_compiles_for_v5e(one_chip):
-    """The delta rule's preparation kernels at the benchmark cell's shapes
+@pytest.mark.parametrize("b,hk,rep,n,dk,dv", [
+    (2, 16, 2, 128, 128, 128),    # qwen3next-ep8-s8192
+    (1, 30, 1, 256, 128, 256),    # olmohybrid-gdn-s16384: 96 / 192, padded
+], ids=["q3n", "olh"])
+def test_gdn_prep_compiles_for_v5e(one_chip, b, hk, rep, n, dk, dv):
+    """The delta rule's preparation kernels at the benchmark cells' shapes
     (2 x 8,192 positions in chunks of 64, 16 key heads serving 32 value heads
-    of 128, bfloat16), forward and backward: compiled, not interpreted."""
+    of 128; 16,384 positions, 30 key heads of 96 each serving a value head of
+    192, at the widths the op pads them to; bfloat16), forward and backward:
+    compiled, not interpreted."""
     from tpucfn.kernels.gated_delta import gdn_prep
 
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    b, hk, rep, n, c, d = 2, 16, 2, 128, 64, 128
-    qk = sds((b, n * c, hk * d), jnp.bfloat16)
-    v = sds((b, n * c, hk * rep * d), jnp.bfloat16)
+    c = 64
+    qk = sds((b, n * c, hk * dk), jnp.bfloat16)
+    v = sds((b, n * c, hk * rep * dv), jnp.bfloat16)
     row = sds((b, hk, rep, n, c), jnp.float32)
 
     def loss(*a):

@@ -118,6 +118,21 @@ def test_granite4_h_example(tmp_path):
                for x in lines)
 
 
+def test_olmo_hybrid_example(tmp_path):
+    """The Olmo-Hybrid decoder through run_train_loop: the delta rule's two
+    counters reach the log and the ``step_metrics`` lines."""
+    r = _run("olmo_hybrid.py", tmp_path, "--model", "tiny", "--seq-len", "32",
+             "--batch-size", "16", "--num-examples", "64")
+    _ok(r)
+    assert "gdn_reflect_share=" in r.stdout and "gdn_state_rms=" in r.stdout
+    rows = [json.loads(ln) for p in (tmp_path / "trace").glob("trace-*.jsonl")
+            for ln in p.read_text().splitlines()]
+    lines = [x for x in rows if x["name"] == "step_metrics"]
+    assert [x["trace_id"] for x in lines] == [1, 2, 3]
+    assert all(0 < x["attrs"]["gdn_reflect_share"] < 1
+               and x["attrs"]["gdn_state_rms"] > 0 for x in lines)
+
+
 def test_sd15_unet_example(tmp_path):
     _ok(_run("sd15_unet.py", tmp_path, "--tiny", "--batch-size", "8",
              "--num-examples", "32"))

@@ -37,11 +37,16 @@ def _against_reference(module, ref_fn, seed):
     params = jax.tree.map(
         lambda a: a + 0.3 * jax.random.normal(jax.random.key(a.size), a.shape),
         params)
+
+    def apply(p, x):   # a DeltaNet layer returns its readings beside its output
+        out = module.apply({"params": p}, x)
+        return out[0] if isinstance(out, tuple) else out
+
     with jax.default_matmul_precision("highest"):
-        out = module.apply({"params": params}, x)
+        out = apply(params, x)
         ref = ref_fn(MODEL, Numerics(), x, params)
         f = lambda fn: lambda p, x: jnp.sum(jnp.sin(fn(p, x)))  # noqa: E731
-        got = jax.grad(f(lambda p, x: module.apply({"params": p}, x)), (0, 1))(params, x)
+        got = jax.grad(f(apply), (0, 1))(params, x)
         want = jax.grad(f(lambda p, x: ref_fn(MODEL, Numerics(), x, p)), (0, 1))(params, x)
     # float32 on both sides; the orders of summation differ
     assert float(jnp.max(jnp.abs(out - ref))) <= 1e-5 * float(jnp.max(jnp.abs(ref)))
@@ -65,7 +70,7 @@ def test_gated_delta_net_matches_the_reference():
     position; 24 positions over chunks of 16."""
     from benchmark.reference import qwen3_next as ref
 
-    _against_reference(GatedDeltaNet(CFG), ref.gated_delta_net, 10)
+    _against_reference(GatedDeltaNet.of(CFG), ref.gated_delta_net, 10)
 
 
 def test_the_tree_is_stacked_by_period_and_the_rules_fit_it():

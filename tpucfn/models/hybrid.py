@@ -21,7 +21,8 @@ The layers, as their published modelling code computes them:
 * **Gated DeltaNet**: projections to q, k, v and an output gate z, and to
   two scalars a head (b, a); a depthwise causal convolution and SiLU over
   q, k and v; q and k normalised to unit length; the gated delta rule
-  (``ops/gated_delta.py``) with ``beta = sigmoid(b)`` and log-decay
+  (``ops/gated_delta.py``) with ``beta = sigmoid(b)`` (the layer's
+  ``beta_scale`` 1; ``models/olmo_hybrid.py`` runs it at 2) and log-decay
   ``-exp(A_log) * softplus(a + dt_bias)``; a per-head RMS norm gated by
   ``SiLU(z)``; the output projection;
 * **sparse feed-forward**: ``models/moe.RoutedExperts``, told which experts
@@ -166,47 +167,76 @@ class GatedAttention(nn.Module):
 
 
 class GatedDeltaNet(nn.Module):
-    cfg: HybridConfig
+    """The layer of every model with Gated DeltaNet mixers (``of`` reads the
+    sizes from a configuration's ``linear_*`` fields).  Returns the output and
+    two readings of the rule: the share of (position, value head) pairs whose
+    ``beta`` exceeds 1, and the root mean square of the state a sequence ends
+    with."""
+
+    key_heads: int
+    value_heads: int
+    key_dim: int
+    value_dim: int
+    conv_kernel: int = 4
+    chunk: int = 64
+    # beta = beta_scale * sigmoid(b): at 1 the transition I - beta k k^T has
+    # eigenvalues in [0, 1]; at 2 (a published ``allow_neg_eigval``) beta
+    # lies in (0, 2) and the eigenvalue along k in (-1, 1)
+    beta_scale: float = 1.0
+    norm_eps: float = 1e-6
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = jnp.float32
+
+    @classmethod
+    def of(cls, cfg, *, beta_scale: float = 1.0, name: str | None = None):
+        return cls(cfg.linear_key_heads, cfg.linear_value_heads,
+                   cfg.linear_key_dim, cfg.linear_value_dim, cfg.conv_kernel,
+                   cfg.delta_chunk, beta_scale, cfg.norm_eps, cfg.dtype,
+                   cfg.param_dtype, name=name)
 
     @nn.compact
     def __call__(self, x):
-        cfg = self.cfg
         b, s, _ = x.shape
-        hk, hv = cfg.linear_key_heads, cfg.linear_value_heads
-        dk, dv = cfg.linear_key_dim, cfg.linear_value_dim
+        hk, hv = self.key_heads, self.value_heads
+        dk, dv = self.key_dim, self.value_dim
         f32 = jnp.float32
-        qkv = jnp.concatenate([_dense(cfg, hk * dk, "q_proj")(x),
-                               _dense(cfg, hk * dk, "k_proj")(x),
-                               _dense(cfg, hv * dv, "v_proj")(x)], axis=-1)
-        z = _dense(cfg, hv * dv, "z_proj")(x).reshape(b, s, hv, dv)
-        beta = nn.sigmoid(_dense(cfg, hv, "b_proj")(x).astype(f32))
-        a = _dense(cfg, hv, "a_proj")(x).astype(f32)
+        qkv = jnp.concatenate([_dense(self, hk * dk, "q_proj")(x),
+                               _dense(self, hk * dk, "k_proj")(x),
+                               _dense(self, hv * dv, "v_proj")(x)], axis=-1)
+        z = _dense(self, hv * dv, "z_proj")(x).reshape(b, s, hv, dv)
+        beta = self.beta_scale * nn.sigmoid(
+            _dense(self, hv, "b_proj")(x).astype(f32))
+        a = _dense(self, hv, "a_proj")(x).astype(f32)
         # as published: A uniform on (0, 16), dt_bias ones
         a_log = self.param("A_log", lambda key, shape: jnp.log(jax.random.uniform(
             key, shape, f32, 1e-3, 16.0)), (hv,))
         dt_bias = self.param("dt_bias", nn.initializers.ones, (hv,), f32)
         g = -jnp.exp(a_log) * jax.nn.softplus(a + dt_bias)
 
-        width = cfg.conv_kernel
-        w = KernelParam((width, qkv.shape[-1]), cfg.param_dtype,
+        width = self.conv_kernel
+        w = KernelParam((width, qkv.shape[-1]), self.param_dtype,
                         nn.initializers.normal(width ** -0.5), name="conv")()
-        qkv = causal_conv_silu(qkv, w, dtype=cfg.dtype)
+        qkv = causal_conv_silu(qkv, w, dtype=self.dtype)
 
         q, k, v = jnp.split(qkv, [hk * dk, 2 * hk * dk], axis=-1)
         unit = lambda t: t * jax.lax.rsqrt(  # noqa: E731
             jnp.sum(t * t, axis=-1, keepdims=True) + 1e-6)
-        q = (unit(q.reshape(b, s, hk, dk).astype(f32)) * dk ** -0.5).astype(cfg.dtype)
-        k = unit(k.reshape(b, s, hk, dk).astype(f32)).astype(cfg.dtype)
+        q = (unit(q.reshape(b, s, hk, dk).astype(f32)) * dk ** -0.5).astype(self.dtype)
+        k = unit(k.reshape(b, s, hk, dk).astype(f32)).astype(self.dtype)
         # rematerialised on its own inside the layer's remat: what the rule's
         # scan keeps (its inputs and a state a chunk) and the expert layer's
         # rows are then never held at once in the backward pass (0.95 GB of
         # a 16 GB chip at 2 x 8,192, with the preparation in its kernel)
-        o = jax.checkpoint(
-            lambda *a: gated_delta_rule(*a, chunk_size=cfg.delta_chunk)
-        )(q, k, v.reshape(b, s, hv, dv), g, beta).astype(f32)
+        o, state = jax.checkpoint(
+            lambda *a: gated_delta_rule(*a, chunk_size=self.chunk)
+        )(q, k, v.reshape(b, s, hv, dv), g, beta)
 
-        o = GatedRMSNorm(cfg.norm_eps, cfg.dtype, name="norm")(o, z)
-        return _dense(cfg, x.shape[-1], "out_proj")(o.reshape(b, s, hv * dv))
+        o = GatedRMSNorm(self.norm_eps, self.dtype, name="norm")(o.astype(f32), z)
+        readings = jax.lax.stop_gradient({
+            "reflect_share": jnp.mean((beta > 1.0).astype(f32)),
+            "state_rms": jnp.sqrt(jnp.mean(jnp.square(state)))})
+        return (_dense(self, x.shape[-1], "out_proj")(o.reshape(b, s, hv * dv)),
+                readings)
 
 
 class HybridLayer(nn.Module):
@@ -223,9 +253,12 @@ class HybridLayer(nn.Module):
         cfg = self.cfg
         norm = lambda name: ZeroCentredRMSNorm(  # noqa: E731
             cfg.norm_eps, cfg.dtype, name=name)
-        mixer = (GatedAttention(cfg, self.attention_fn, name="mixer")
-                 if self.kind == "full" else GatedDeltaNet(cfg, name="mixer"))
-        x = x + mixer(norm("input_norm")(x))
+        if self.kind == "full":
+            mixed = GatedAttention(cfg, self.attention_fn, name="mixer")(
+                norm("input_norm")(x))
+        else:
+            mixed, _ = GatedDeltaNet.of(cfg, name="mixer")(norm("input_norm")(x))
+        x = x + mixed
         h, stats = RoutedExperts(
             cfg.n_experts, cfg.top_k, cfg.expert_dim, cfg.held_experts,
             shared_dim=cfg.shared_expert_dim, dtype=cfg.dtype,
@@ -400,20 +433,24 @@ def sharding_rules(cfg: HybridConfig) -> ShardingRules:
     ))
 
 
-RECURRENT_MODEL_TYPES = ("qwen3_next", "granitemoehybrid")
+RECURRENT_MODEL_TYPES = ("qwen3_next", "granitemoehybrid", "olmo_hybrid")
 
 
 def refuse_recurrent_model(what, where: str) -> None:
     """Serving and checkpoint conversion are out of scope for a decoder with
-    recurrent layers (Gated DeltaNet here, Mamba-2 in ``models/ssm.py``), and
-    say so by name: ``what`` is a model, a config of either module, or a
-    published config (``model_type``)."""
+    recurrent layers (Gated DeltaNet here and in ``models/olmo_hybrid.py``,
+    Mamba-2 in ``models/ssm.py``), and say so by name: ``what`` is a model, a
+    config of any of the three modules, or a published config
+    (``model_type``)."""
+    from tpucfn.models.olmo_hybrid import OlmoHybridConfig, OlmoHybridDecoder
     from tpucfn.models.ssm import SSMConfig, SSMDecoder
 
-    if (isinstance(what, (HybridDecoder, HybridConfig, SSMDecoder, SSMConfig))
+    if (isinstance(what, (HybridDecoder, HybridConfig, SSMDecoder, SSMConfig,
+                          OlmoHybridDecoder, OlmoHybridConfig))
             or getattr(what, "model_type", None) in RECURRENT_MODEL_TYPES):
         raise NotImplementedError(
             f"{where} cannot take a decoder with recurrent layers "
             f"({type(what).__name__}): there is no cache for recurrent state "
-            "yet (ROADMAP R4, D3); models/hybrid.py and models/ssm.py train "
-            "only")
+            "yet, Gated DeltaNet's or Mamba-2's, each with its convolution's "
+            "last inputs (ROADMAP R4, D3); models/hybrid.py, models/ssm.py "
+            "and models/olmo_hybrid.py train only")

@@ -26,14 +26,19 @@ Two parts, two owners.  The *chunk-local preparation* (decay mask, ``K K^T``,
 ``A``, ``T``, ``W = T K_in``, ``U = T V_in``, ``Q K^T * decay``, ``q_in``,
 ``k_out``: everything a chunk can know without the state) has two
 implementations of one arithmetic, rounded to the compute dtype at the same
-points: :func:`chunk_preparation`, in ``jnp``, whose inverse is a product of
-``log2 C`` matrix factors (the strict triangle is nilpotent) and whose backward
-pass is autodiff's; and the Pallas kernel pair of
+points: :func:`chunk_preparation`, in ``jnp``, whose inverse is a triangular
+solve and whose backward pass is autodiff's; and the Pallas kernel pair of
 :mod:`tpucfn.kernels.gated_delta`, which keeps a chunk's ``C x C`` tensors in
 VMEM, forward and backward.  :func:`gated_delta_rule` picks the kernel from what
 it can see (:func:`_kernel_serves`: the backend is a TPU, the compute dtype
-bfloat16, both head sizes multiples of the 128 lanes, the chunk a multiple of
-the 8 sublanes) and the ``jnp`` form everywhere else; there is no option.  The
+bfloat16, the chunk a multiple of the 8 sublanes) and the ``jnp`` form
+everywhere else; there is no option.  A head size that is no multiple of the
+128 lanes (keys of 96, values of 192) goes to the kernel zero-padded to whole
+lane tiles, in the fusion that makes q, k and v: exact for this rule, because a
+zero key lane never enters the state and a zero value lane stays zero, and
+what it costs is nothing inside the kernel (a (C, 96) tile occupies the lanes
+of a (C, 128) one) and the pad and slices around it.  The scan takes each
+head's own lanes back, so the state is ``(Dk, Dv)`` on both paths.  The
 *scan over chunks*, the cumulative sum that feeds both and ``last`` stay XLA's,
 the scan's backward pass autodiff's, each chunk's step rematerialised, so a
 step keeps its carry and no more.
@@ -45,23 +50,21 @@ import jax
 import jax.numpy as jnp
 
 HIGHEST = jax.lax.Precision.HIGHEST
+LANES = 128
 
 
 @jax.custom_vjp
 def _unit_lower_inverse(a):
-    """(I + a)^-1 for strictly lower-triangular ``a`` (..., C, C), float32:
-    sum_k (-a)^k = (I - a)(I + a^2)(I + a^4)..., exact because a^C = 0.  Its
-    backward pass is the inverse's own, ``-T^T dT T^T``, so that the factors
-    are not kept."""
-    c = a.shape[-1]
-    p = -a
-    t = jnp.eye(c, dtype=a.dtype) + p
-    power = 1
-    while 2 * power < c:
-        p = jnp.matmul(p, p, precision=HIGHEST)
-        t = t + jnp.matmul(t, p, precision=HIGHEST)
-        power *= 2
-    return t
+    """(I + a)^-1 for strictly lower-triangular ``a`` (..., C, C), float32, by
+    forward substitution (a triangular solve against the identity).  Its
+    backward pass is the inverse's own, ``-T^T dT T^T``.  (A product of
+    ``log2 C`` factors ``(I - a)(I + a^2)(I + a^4)...``, exact in exact
+    arithmetic because ``a^C = 0``, loses everything in float32 where ``beta``
+    nears 2 over repeated keys: the powers' entries grow as ``2^k`` times
+    binomials and cancel, 0.13 off at C 16 with every entry 1.9.)"""
+    eye = jnp.broadcast_to(jnp.eye(a.shape[-1], dtype=a.dtype), a.shape)
+    return jax.lax.linalg.triangular_solve(a, eye, left_side=True, lower=True,
+                                           unit_diagonal=True)
 
 
 def _unit_lower_inverse_fwd(a):
@@ -82,11 +85,28 @@ def _backend() -> str:
     return jax.default_backend()
 
 
-def _kernel_serves(dtype, dk: int, dv: int, chunk: int) -> bool:
-    """The Pallas preparation where its tiles are whole: on a TPU, in
-    bfloat16, head sizes that fill the 128 lanes, a chunk of whole sublanes."""
-    return (_backend() == "tpu" and dtype == jnp.bfloat16
-            and dk % 128 == 0 and dv % 128 == 0 and chunk % 8 == 0)
+def _kernel_serves(dtype, chunk: int) -> bool:
+    """The Pallas preparation on a TPU, in bfloat16, for a chunk of whole
+    sublanes; a head of any size (:func:`_lanes`)."""
+    return _backend() == "tpu" and dtype == jnp.bfloat16 and chunk % 8 == 0
+
+
+def _lanes(d: int) -> int:
+    """A head size rounded up to whole lane tiles: the width the kernel
+    reads and writes a head at."""
+    return -(-d // LANES) * LANES
+
+
+def _widen(x, width: int):
+    """The last axis zero-padded to ``width``."""
+    if x.shape[-1] == width:
+        return x
+    return jnp.pad(x, ((0, 0),) * (x.ndim - 1) + ((0, width - x.shape[-1]),))
+
+
+def _own(x, width: int):
+    """A head's own lanes of a padded one."""
+    return x if x.shape[-1] == width else x[..., :width]
 
 
 def chunk_preparation(qc, kc, vc, cum, bc):
@@ -120,7 +140,8 @@ def chunk_preparation(qc, kc, vc, cum, bc):
 
 def gated_delta_rule(q, k, v, g, beta, *, chunk_size: int = 64):
     """q, k: (B, S, Hk, Dk); v: (B, S, Hv, Dv); g, beta: (B, S, Hv).  Returns
-    (B, S, Hv, Dv) in ``v.dtype``.  ``S`` need not be a multiple of
+    the output (B, S, Hv, Dv) in ``v.dtype`` and the float32 state a sequence
+    ends with, (B, Hv, Dk, Dv).  ``S`` need not be a multiple of
     ``chunk_size``: the tail is padded with positions that change nothing."""
     b, s, hk, dk = q.shape
     hv, dv = v.shape[2], v.shape[3]
@@ -138,11 +159,13 @@ def gated_delta_rule(q, k, v, g, beta, *, chunk_size: int = 64):
     gc, bc = (x.astype(f32).reshape(b, n, c, hk, rep).transpose(0, 3, 4, 1, 2)
               for x in (g, beta))
     cum = jnp.cumsum(gc, axis=-1)
-    if _kernel_serves(dtype, dk, dv, c):
+    if _kernel_serves(dtype, c):
         from tpucfn.kernels.gated_delta import gdn_prep
 
-        xs = gdn_prep(q.reshape(b, n * c, hk * dk), k.reshape(b, n * c, hk * dk),
-                      v.reshape(b, n * c, hv * dv), cum, bc)
+        kw, vw = _lanes(dk), _lanes(dv)
+        xs = gdn_prep(_widen(q, kw).reshape(b, n * c, hk * kw),
+                      _widen(k, kw).reshape(b, n * c, hk * kw),
+                      _widen(v, vw).reshape(b, n * c, hv * vw), cum, bc)
     else:
         # chunked, heads leading: key heads (B,Hk,N,C,·), value heads (B,Hk,R,N,C,·)
         qc, kc = (x.reshape(b, n, c, hk, dk).transpose(0, 3, 1, 2, 4) for x in (q, k))
@@ -154,6 +177,8 @@ def gated_delta_rule(q, k, v, g, beta, *, chunk_size: int = 64):
     @jax.checkpoint
     def step(state, xs):
         w_i, u_i, qk_i, q_i, k_i, last_i = xs
+        w_i, q_i, k_i = (_own(x, dk) for x in (w_i, q_i, k_i))
+        u_i = _own(u_i, dv)
         sd = state.astype(dtype)
         new = (u_i.astype(f32) - jnp.matmul(w_i, sd, preferred_element_type=f32)
                ).astype(dtype)
@@ -163,7 +188,7 @@ def gated_delta_rule(q, k, v, g, beta, *, chunk_size: int = 64):
             "...ck,...cv->...kv", k_i, new, preferred_element_type=f32)
         return state, out.astype(dtype)
 
-    _, out = jax.lax.scan(step, jnp.zeros((b, hk, rep, dk, dv), f32), xs)
+    state, out = jax.lax.scan(step, jnp.zeros((b, hk, rep, dk, dv), f32), xs)
     # (N,B,Hk,R,C,Dv) -> (B,S,Hv,Dv)
-    out = out.transpose(1, 0, 4, 2, 3, 5).reshape(b, n * c, hv, dv)
-    return out[:, :s]
+    out = out.transpose(1, 0, 4, 2, 3, 5).reshape(b, n * c, hv, dv)[:, :s]
+    return out, state.reshape(b, hv, dk, dv)
