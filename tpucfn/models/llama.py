@@ -16,6 +16,7 @@ RoPE (theta 500k), SwiGLU MLP, untied LM head. TPU-first choices:
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any
 
 import flax.linen as nn
@@ -300,18 +301,28 @@ def chunked_causal_lm_loss(
     out of the mean.
 
     Numerically equal to ``causal_lm_loss(hidden @ W, tokens)`` (tests
-    assert values and grads): a ``lax.scan`` over sequence chunks
-    computes each chunk's fp32 logits, reduces them to a CE sum and a
-    correct-count, and drops them; ``jax.checkpoint`` on the chunk body
-    makes reverse-mode recompute logits chunkwise instead of stashing
-    them.  Peak logits memory is (B, chunk, V) instead of (B, S, V) —
+    assert values and grads).  The forward pass is a ``lax.scan`` over
+    chunks of ``chunk_size`` positions: each chunk's fp32 logits are
+    reduced to a CE sum, a correct-count and each row's log-sum-exp, and
+    dropped.  Peak logits memory is (B, chunk, V) instead of (B, S, V) —
     the difference between fitting and the observed on-chip OOM for
     Llama-1B (V=128k) on one 16 GB chip, and a hard requirement at the
     long-context end (S=32k never fits materialized).
-    """
-    import optax
 
-    b, s, _ = hidden.shape
+    The backward pass is a ``jax.custom_vjp`` of its own; its residuals are
+    the chunked hidden states, the table, the targets and the rows'
+    log-sum-exp, (chunks, B, chunk) fp32.  It recomputes the logits once,
+    with the forward's operand dtypes and precision, and walks them in the
+    order ``vocab_block`` picks from the shapes: by blocks of the
+    vocabulary over all rows (scope ``lm_head/vocab_blocks``: each block's
+    columns of the table gradient written once, the fp32 ``dh`` summed over
+    blocks), or by the forward's chunks (``lm_head/token_chunks``: each
+    chunk's ``dh`` written once, the fp32 table gradient summed over
+    chunks).  A block's fp32 logits over all rows stay within
+    ``HEAD_BLOCK_BYTES``.  Forward-mode differentiation (``jax.jvp``)
+    through the head is not defined.
+    """
+    b, s, d = hidden.shape
     n = s - ahead
     pred = hidden[:, :-ahead]
     targets = tokens[:, ahead:]
@@ -323,31 +334,150 @@ def chunked_causal_lm_loss(
     k = (n + pad) // c
     pred = pred.reshape(b, k, c, -1).swapaxes(0, 1)     # (k, B, c, D)
     targets = targets.reshape(b, k, c).swapaxes(0, 1)   # (k, B, c)
-
-    @jax.checkpoint
-    def chunk_sums(w, h_c, t_c):
-        logits = h_c.astype(jnp.float32) @ w.astype(jnp.float32)
-        per_tok = optax.softmax_cross_entropy_with_integer_labels(
-            logits, jnp.maximum(t_c, 0))
-        if z_loss:
-            per_tok = per_tok + z_loss * jax.nn.logsumexp(logits, axis=-1) ** 2
-        valid = t_c >= 0
-        ce = jnp.sum(jnp.where(valid, per_tok, 0.0))
-        correct = jnp.sum(jnp.where(valid, jnp.argmax(logits, -1) == t_c,
-                                    False).astype(jnp.float32))
-        return ce, correct
-
-    def body(carry, xs):
-        ce_acc, cor_acc = carry
-        h_c, t_c = xs
-        ce, cor = chunk_sums(lm_head_kernel, h_c, t_c)
-        return (ce_acc + ce, cor_acc + cor), None
-
-    (ce, cor), _ = jax.lax.scan(
-        body, (jnp.zeros((), jnp.float32), jnp.zeros((), jnp.float32)),
-        (pred, targets))
+    width = vocab_block(b * k * c, k, d, lm_head_kernel.shape[1],
+                        h_bytes=hidden.dtype.itemsize,
+                        w_bytes=lm_head_kernel.dtype.itemsize)
+    ce, cor = _head_sums(pred, lm_head_kernel, targets, float(z_loss), width)
     denom = b * n
     return ce / denom, cor / denom
+
+
+# The most fp32 logits the head's backward holds at once, in bytes: a
+# vocabulary block over every row (PERF.md, section 3, says why this size).
+HEAD_BLOCK_BYTES = 256 * 2**20
+_LANES = 128
+
+
+def vocab_block(rows: int, chunks: int, d: int, v: int, *,
+                h_bytes: int = 2, w_bytes: int = 4) -> int:
+    """The width of the blocks in which the head's backward walks the
+    vocabulary, or 0 where it walks the forward's chunks of positions.
+
+    ``rows`` positions, padding included, in ``chunks`` chunks, against a
+    ``(d, v)`` table.  By chunks, each chunk reads and writes the fp32 table
+    gradient whole and reads the table: ``chunks × (2·|dW| + |W|)`` bytes.
+    By blocks, each block reads and writes the fp32 ``dh`` and reads the
+    hidden states: ``blocks × (2·|dh| + |h|)``.  The walk that moves fewer
+    bytes is taken, a tie by chunks.  Blocks are whole 128-lane tiles, as
+    many as keep a block's fp32 logits over all rows within
+    ``HEAD_BLOCK_BYTES``, and as even as whole tiles allow (the last one
+    narrower where ``v`` is no multiple of the width); a table no wider than
+    one block is one block."""
+    most = max(_LANES, HEAD_BLOCK_BYTES // (4 * rows) // _LANES * _LANES)
+    if v <= most:
+        width = v
+    else:
+        width = -(-v // -(-v // most))          # ceil(v / blocks)
+        width = -(-width // _LANES) * _LANES
+    blocks = -(-v // width)
+    by_chunks = chunks * (2 * 4 + w_bytes) * d * v
+    by_blocks = blocks * (2 * 4 + h_bytes) * rows * d
+    return width if by_blocks < by_chunks else 0
+
+
+def _chunk_stats(h, w, t, z_loss):
+    """One chunk's fp32 logits reduced: CE sum, correct-count, and each
+    row's log-sum-exp."""
+    logits = h.astype(jnp.float32) @ w.astype(jnp.float32)
+    top = jnp.max(logits, -1)
+    lse = top + jnp.log(jnp.sum(jnp.exp(logits - top[..., None]), -1))
+    per_tok = lse - jnp.take_along_axis(
+        logits, jnp.maximum(t, 0)[..., None], -1)[..., 0]
+    if z_loss:
+        per_tok = per_tok + z_loss * lse ** 2
+    valid = t >= 0
+    ce = jnp.sum(jnp.where(valid, per_tok, 0.0))
+    correct = jnp.sum(jnp.where(valid, jnp.argmax(logits, -1) == t,
+                                False).astype(jnp.float32))
+    return ce, correct, lse
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _head_sums(pred, w, targets, z_loss, width):
+    """(CE sum, correct-count) over ``pred`` (chunks, B, chunk, D) against
+    ``w``; ``width`` is ``vocab_block``'s."""
+    return _head_sums_fwd(pred, w, targets, z_loss, width)[0]
+
+
+def _head_sums_fwd(pred, w, targets, z_loss, width):
+    def body(carry, xs):
+        ce, cor, lse = _chunk_stats(xs[0], w, xs[1], z_loss)
+        return (carry[0] + ce, carry[1] + cor), lse
+
+    zero = jnp.zeros((), jnp.float32)
+    sums, lse = jax.lax.scan(body, (zero, zero), (pred, targets))
+    return sums, (pred, w, targets, lse)
+
+
+def _dlogits(h, w, t, lse, g, z_loss, col0):
+    """The CE sum's cotangent ``g`` on the logits of rows ``h`` against
+    columns ``col0 ...`` of the table, ``w`` those columns: ``(softmax ·
+    (1 + 2 z lse) − onehot) · g``, zero on rows whose target is -1."""
+    logits = h.astype(jnp.float32) @ w.astype(jnp.float32)
+    p = jnp.exp(logits - lse[..., None])
+    if z_loss:
+        p = p * (1 + 2 * z_loss * lse)[..., None]
+    hit = t[..., None] == col0 + jnp.arange(w.shape[1])
+    return jnp.where(t >= 0, g, 0.0)[..., None] * jnp.where(hit, p - 1, p)
+
+
+def _rows_dot(h, dlog):
+    """hᵀ · dlog over every axis but the last: a block of the table
+    gradient."""
+    lead = tuple(range(h.ndim - 1))
+    return jnp.tensordot(h.astype(jnp.float32), dlog, (lead, lead))
+
+
+def _by_blocks(pred, w, targets, lse, g, z_loss, width):
+    full, tail = divmod(w.shape[1], width)
+
+    def block(carry, start, size):
+        dh, dw = carry
+        w_v = jax.lax.dynamic_slice_in_dim(w, start, size, axis=1)
+        dlog = _dlogits(pred, w_v, targets, lse, g, z_loss, start)
+        dh = dh + dlog @ w_v.astype(jnp.float32).T
+        dw = jax.lax.dynamic_update_slice_in_dim(
+            dw, _rows_dot(pred, dlog), start, axis=1)
+        return dh, dw
+
+    carry = (jnp.zeros(pred.shape, jnp.float32),
+             jnp.zeros(w.shape, jnp.float32))
+    # the start made from the loop's counter in the body, where the compiler
+    # sees it a multiple of the width: a write whose offset it cannot show
+    # aligned to the lane tiles takes the slow path
+    carry = jax.lax.fori_loop(
+        0, full, lambda i, cr: block(cr, i * width, width), carry)
+    if tail:
+        carry = block(carry, full * width, tail)
+    return carry
+
+
+def _by_chunks(pred, w, targets, lse, g, z_loss):
+    w32 = w.astype(jnp.float32)
+
+    def body(dw, xs):
+        h_c, t_c, lse_c = xs
+        dlog = _dlogits(h_c, w, t_c, lse_c, g, z_loss, 0)
+        return dw + _rows_dot(h_c, dlog), (dlog @ w32.T).astype(pred.dtype)
+
+    dw, dh = jax.lax.scan(body, jnp.zeros(w.shape, jnp.float32),
+                          (pred, targets, lse))
+    return dh, dw
+
+
+def _head_sums_bwd(z_loss, width, res, cts):
+    pred, w, targets, lse = res
+    g = cts[0]   # the correct-count's cotangent is zero: argmax is flat
+    if width:
+        with jax.named_scope("vocab_blocks"):
+            dh, dw = _by_blocks(pred, w, targets, lse, g, z_loss, width)
+    else:
+        with jax.named_scope("token_chunks"):
+            dh, dw = _by_chunks(pred, w, targets, lse, g, z_loss)
+    return dh.astype(pred.dtype), dw.astype(w.dtype), None
+
+
+_head_sums.defvjp(_head_sums_fwd, _head_sums_bwd)
 
 
 def causal_lm_loss(logits: jax.Array, tokens: jax.Array,
