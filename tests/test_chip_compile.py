@@ -239,3 +239,49 @@ def test_gdn_prep_compiles_for_v5e(one_chip, b, hk, rep, n, dk, dv):
     step = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4)))
     text = step.lower(qk, qk, v, row, row).compile().as_text()
     assert text.count("tpu_custom_call") == 2, text.count("tpu_custom_call")
+
+
+def test_the_sparse_attention_cells_step_lowers_its_named_kernels(
+        one_chip, monkeypatch):
+    """``dots3-dsa-s16384``'s whole step at the cell's sizes, lowered for the
+    described chip with the kernels' path taken as on the chip: the band's
+    ``swa_*`` kernels in the window layers, the selection's ``dsa_*`` in the
+    two full layers (the choice, attention over it, the indexer's loss), and
+    no plain ``flash_*`` call."""
+    import functools
+    import importlib
+
+    from jax.sharding import NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    from benchmark import families, program, run
+    from tpucfn.kernels import auto, flash_autotune
+    from tpucfn.mesh import MeshSpec, build_mesh
+    from tpucfn.ops import dsa
+
+    fa = importlib.import_module("tpucfn.kernels.flash_attention")
+    monkeypatch.setattr(auto, "_backend", lambda: "tpu")
+    monkeypatch.setattr(dsa, "_backend", lambda: "tpu")
+    monkeypatch.setattr(fa, "flash_attention", functools.partial(
+        fa.flash_attention, interpret=False))
+    monkeypatch.setattr(  # the table's rows for the chip, not this CPU's
+        flash_autotune, "_entry",
+        lambda s, d, dtype, causal, dv=None, mask_kind=None:
+        flash_autotune._load().get(flash_autotune._key(
+            "TPU v5 lite", causal, s, d, dtype, dv, mask_kind)))
+    c = run.load_cell("dots3-dsa-s16384", rehearse=False)
+    mesh = build_mesh(MeshSpec.for_devices(1), list(one_chip.device_set))
+    trainer, _ = families.load("dots3_note").build(
+        c["config"], c["mix"], mesh, program._init_fn(c["config"]))
+    tokens = jax.ShapeDtypeStruct((1, 16384), jnp.int32,
+                                  sharding=trainer.batch_sharding())
+    text = jax.jit(
+        trainer._step_fn,
+        in_shardings=(trainer.state_shardings(), trainer.batch_sharding()),
+        out_shardings=(trainer.state_shardings(), NamedSharding(mesh, P())),
+    ).lower(trainer.abstract_state(), {"tokens": tokens}).as_text()
+    found = {name: text.count(f'"{name}"') for name in (
+        "swa_fwd", "swa_dq", "swa_dkv", "dsa_fwd", "dsa_dq", "dsa_dkv",
+        "dsa_select", "dsa_kl_fwd", "dsa_kl_dq", "dsa_kl_dk")}
+    assert all(found.values()), found
+    assert "flash_fwd" not in text

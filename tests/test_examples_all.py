@@ -133,6 +133,23 @@ def test_olmo_hybrid_example(tmp_path):
                and x["attrs"]["gdn_state_rms"] > 0 for x in lines)
 
 
+def test_dots3_note_example(tmp_path):
+    """The sparse- and windowed-attention decoder through run_train_loop:
+    the indexer's loss, the share of keys chosen and the routing counters
+    reach the log and the ``step_metrics`` lines."""
+    r = _run("dots3_note.py", tmp_path, "--model", "tiny", "--seq-len", "32",
+             "--batch-size", "16", "--num-examples", "64")
+    _ok(r)
+    assert "dsa_kl=" in r.stdout and "moe_dropped=0 " in r.stdout
+    rows = [json.loads(ln) for p in (tmp_path / "trace").glob("trace-*.jsonl")
+            for ln in p.read_text().splitlines()]
+    lines = [x for x in rows if x["name"] == "step_metrics"]
+    assert [x["trace_id"] for x in lines] == [1, 2, 3]
+    # 12 keys a query of up to 32: Σ min(12, t + 1) over Σ (t + 1)
+    assert all(x["attrs"]["dsa_kl"] > 0 and x["attrs"]["dsa_select_share"]
+               == pytest.approx((78 + 20 * 12) / 528) for x in lines)
+
+
 def test_sd15_unet_example(tmp_path):
     _ok(_run("sd15_unet.py", tmp_path, "--tiny", "--batch-size", "8",
              "--num-examples", "32"))

@@ -830,3 +830,118 @@ def test_full_remat_lowers_the_dense_path_as_saving_nothing():
             argnums=(0, 1, 2))).lower(q, k, v).as_text()
 
     assert text(remat_policy("full")[1]) == text(None)
+
+
+# ---- a band (windowed attention) and a selection of keys carried as data ----
+
+# (id, S, q heads, kv heads, D, DV, window, blocks)
+BAND_CASES = [
+    # a window shorter than a block: rows see one or two key blocks
+    ("w40_32x64", 256, 2, 2, 32, 16, 40, (32, 64)),
+    # a window past a block, GQA, the grid's last steps running off the array
+    ("w130_128x128_gqa", 384, 4, 2, 32, 32, 130, (128, 128)),
+    # a window longer than the sequence's blocks: every causal pair
+    ("w513_64x32", 256, 2, 2, 16, 16, 513, (64, 32)),
+]
+
+
+@pytest.mark.parametrize("s,hq,hkv,d,dv,window,blocks",
+                         [c[1:] for c in BAND_CASES],
+                         ids=[c[0] for c in BAND_CASES])
+def test_a_band_matches_the_masked_dense_softmax(s, hq, hkv, d, dv, window,
+                                                 blocks):
+    """``swa_*``: forward and all three gradients against the dense path
+    under the mask ``0 <= t - s < window``; the grid walks only the key
+    (query) blocks a row (column) of blocks can touch."""
+    q, w = _rand((1, s, hq, d), 0), _rand((1, s, hq, dv), 3)
+    k, v = _rand((1, s, hkv, d), 1), _rand((1, s, hkv, dv), 2)
+    pos = np.arange(s)
+    band = jnp.asarray((pos[:, None] - pos[None, :] >= 0)
+                       & (pos[:, None] - pos[None, :] < window))
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, window=window, block_q=blocks[0],
+                               block_k=blocks[1], interpret=True)
+
+    def dense(q, k, v):
+        return dot_product_attention(q, k, v, mask=band[None, None])
+
+    np.testing.assert_allclose(np.asarray(flash(q, k, v)),
+                               np.asarray(dense(q, k, v)), atol=2e-5)
+    for a, b, name in zip(_grads(flash, q, k, v, w),
+                          _grads(dense, q, k, v, w), "qkv"):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=3e-4,
+                                   err_msg=f"d{name}")
+
+
+@pytest.mark.parametrize("geometry", [(32, 64, 40, 256), (128, 128, 130, 384),
+                                      (64, 32, 513, 256), (16, 64, 9, 128)],
+                         ids=["32x64_w40", "128x128_w130", "64x32_w513",
+                              "16x64_w9"])
+def test_a_bands_steps_and_clamps_against_its_mask(geometry):
+    """Every block the band needs lies on the steps that walk it, in both
+    orientations, each step's fetched block is needed (or the row's nearest),
+    and a block the band does not touch is never computed."""
+    from tpucfn.kernels.flash_attention import _Grid
+
+    bq, bk, window, s = geometry
+    grid = _Grid(True, bq, bk, 0, 0, s, s, False, window)
+    nq, nk = s // bq, s // bk
+    pos = np.arange(s)
+    keep = (pos[:, None] >= pos[None, :]) & (pos[:, None] - pos[None, :] < window)
+    needed = {(qi, ki) for qi in range(nq) for ki in range(nk)
+              if keep[qi * bq:(qi + 1) * bq, ki * bk:(ki + 1) * bk].any()}
+    assert needed == {(qi, ki) for qi in range(nq) for ki in range(nk)
+                      if grid.needed(qi, ki)}
+    for qi in range(nq):
+        walked = {int(grid.key_block(qi, j)) for j in range(grid.k_steps(nk))}
+        assert {ki for q_, ki in needed if q_ == qi} <= walked
+        for j in range(grid.k_steps(nk)):
+            ki = int(grid.key_block(qi, j))
+            held = int(grid.last_needed_k(qi, ki))
+            assert (qi, held) in needed
+    for ki in range(nk):
+        walked = {int(grid.query_block(ki, j)) for j in range(grid.q_steps(nq))}
+        assert {qi for qi, k_ in needed if k_ == ki} <= walked
+        for j in range(grid.q_steps(nq)):
+            held = int(grid.first_needed_q(grid.query_block(ki, j), ki, nq))
+            assert (held, ki) in needed
+    for qi, ki in needed:
+        block = keep[qi * bq:(qi + 1) * bq, ki * bk:(ki + 1) * bk]
+        assert not bool(grid.interior(qi, ki)) or block.all()
+
+
+def test_a_selection_matches_the_masked_dense_softmax():
+    """``dsa_*``: attention over the keys each query's words name (packed
+    bits, a causal choice of about a third), forward, the LSE it hands out
+    and all three gradients against the dense path under that mask."""
+    from tpucfn.kernels.flash_attention import pack
+    from tpucfn.ops.attention import dot_product_attention_with_lse
+
+    s = 256
+    q, w = _rand((1, s, 2, 48), 0), _rand((1, s, 2, 16), 3)
+    k, v = _rand((1, s, 2, 48), 1), _rand((1, s, 2, 16), 2)
+    pos = np.arange(s)
+    chosen = ((pos[:, None] >= pos[None, :])
+              & ((np.random.RandomState(5).rand(s, s) < 0.3)
+                 | (pos[:, None] == pos[None, :])))
+    words = pack(jnp.asarray(chosen)[None])
+    mask = jnp.asarray(chosen)[None, None]
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, select=words, block_q=128,
+                               block_k=128, interpret=True)
+
+    def dense(q, k, v):
+        return dot_product_attention(q, k, v, mask=mask)
+
+    np.testing.assert_allclose(np.asarray(flash(q, k, v)),
+                               np.asarray(dense(q, k, v)), atol=2e-5)
+    _, lse = flash_attention(q, k, v, select=words, block_q=128, block_k=128,
+                             interpret=True, return_lse=True)
+    _, want = dot_product_attention_with_lse(q, k, v, mask=mask)
+    np.testing.assert_allclose(np.asarray(lse), np.asarray(want), atol=2e-5)
+    for a, b, name in zip(_grads(flash, q, k, v, w),
+                          _grads(dense, q, k, v, w), "qkv"):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=3e-4,
+                                   err_msg=f"d{name}")

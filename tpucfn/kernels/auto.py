@@ -157,22 +157,47 @@ def full_attention_auto(q, k, v, *, mask=None):
 
 
 def auto_attention_static_zero(q, k, v, *, causal=True, mask=None,
-                               q_offset=0, k_offset=0, scale=None):
+                               q_offset=0, k_offset=0, scale=None,
+                               window=None, select=None, return_lse=False):
     """AttentionFn for call sites whose offsets are STATICALLY zero but
     arrive as traced zeros (Llama's scan carry, the PP stage body):
     dispatches on the local (trace-time) sequence length and DROPS the
     traced zero offsets when taking the flash path — the kernel takes
     static offsets. The caller is responsible for only installing this
     where q_offset/k_offset are provably zero.  ``scale`` multiplies the
-    scores on either path; None is the keys' ``D ** -0.5``."""
+    scores on either path; None is the keys' ``D ** -0.5``.  ``window``
+    (a band), ``select`` (packed words of the keys each query attends to)
+    and ``return_lse`` (the rows' log-sum-exp beside the output, as a
+    reading) as :func:`tpucfn.kernels.flash_attention.flash_attention`
+    takes them; the dense path builds their mask."""
     if mask is None and should_use_flash(q.shape[1], causal=causal,
                                          d=q.shape[-1], dtype=q.dtype,
                                          dv=v.shape[-1]):
         from tpucfn.kernels.flash_attention import flash_attention
 
-        return flash_attention(q, k, v, causal=causal, scale=scale)
-    from tpucfn.ops.attention import dot_product_attention
+        return flash_attention(q, k, v, causal=causal, scale=scale,
+                               window=window, select=select,
+                               return_lse=return_lse)
+    import jax
+    import jax.numpy as jnp
 
+    from tpucfn.ops.attention import (dot_product_attention,
+                                      dot_product_attention_with_lse)
+
+    if window:
+        sq, sk = q.shape[1], k.shape[1]
+        ahead = jnp.arange(sq)[:, None] - jnp.arange(sk)[None, :]
+        mask = ahead < window if mask is None else mask & (ahead < window)
+    if select is not None:
+        from tpucfn.kernels.flash_attention import unpack
+
+        chosen = unpack(select, k.shape[1])[:, None]
+        mask = chosen if mask is None else mask & chosen
+    if return_lse:
+        out, lse = dot_product_attention_with_lse(
+            q, k, v, causal=causal, mask=mask, q_offset=q_offset,
+            k_offset=k_offset, scale=scale)
+        return out, jax.lax.stop_gradient(lse)
     return dot_product_attention(q, k, v, causal=causal, mask=mask,
                                  q_offset=q_offset, k_offset=k_offset,
                                  scale=scale)

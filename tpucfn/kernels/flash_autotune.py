@@ -51,11 +51,14 @@ def _bucket(s: int) -> int:
 
 
 def _key(device_kind: str, causal: bool, s: int, d: int, dtype,
-         dv: int | None = None) -> str:
+         dv: int | None = None, mask_kind: str | None = None) -> str:
+    """``mask_kind`` names a mask beside causality (``window513``,
+    ``select``) whose blocks are tuned apart from plain causal ones."""
     import numpy as np
 
     head = str(d) if dv in (None, d) else f"{d}v{dv}"
-    return "|".join([device_kind, "causal" if causal else "full",
+    return "|".join([device_kind,
+                     mask_kind or ("causal" if causal else "full"),
                      str(_bucket(s)), head, str(np.dtype(dtype))])
 
 
@@ -103,12 +106,12 @@ def _save(cache: dict[str, tuple[int, int]]) -> None:
     os.replace(tmp, p)
 
 
-def _entry(s: int, d: int, dtype, causal: bool,
-           dv: int | None = None) -> tuple | None:
+def _entry(s: int, d: int, dtype, causal: bool, dv: int | None = None,
+           mask_kind: str | None = None) -> tuple | None:
     import jax
 
     kind = jax.devices()[0].device_kind
-    return _load().get(_key(kind, causal, s, d, dtype, dv))
+    return _load().get(_key(kind, causal, s, d, dtype, dv, mask_kind))
 
 
 def kind_has_entries(device_kind: str) -> bool:
@@ -121,12 +124,12 @@ def kind_has_entries(device_kind: str) -> bool:
     return any(k.startswith(prefix) for k in _load())
 
 
-def lookup(s: int, d: int, dtype, causal: bool,
-           dv: int | None = None) -> tuple[int, int] | None:
+def lookup(s: int, d: int, dtype, causal: bool, dv: int | None = None,
+           mask_kind: str | None = None) -> tuple[int, int] | None:
     """Best known (block_q, block_k) for this shape family on the
     current device, or None. Trace-time safe (no device work). ``dv`` is
     the values' head size where it is not ``d``."""
-    e = _entry(s, d, dtype, causal, dv)
+    e = _entry(s, d, dtype, causal, dv, mask_kind)
     return None if e is None else tuple(e[:2])
 
 
@@ -157,6 +160,8 @@ def tune(
     include_bwd: bool = True,
     persist: bool = True,
     scale: float | None = None,
+    window: int | None = None,
+    select: bool = False,
 ) -> dict:
     """Time each candidate block pair eagerly; persist + return results.
 
@@ -166,13 +171,21 @@ def tune(
     the mechanism, not for real numbers).  ``scale`` is the scores'
     multiplier both paths are timed at (None: the keys' ``d ** -0.5``); the
     table's key does not hold it, a block step costs the same at any.
+    ``window`` and ``select`` tune a band's or a selection's blocks under
+    keys of their own (a selection of every key: a masked block costs the
+    same whatever it keeps); neither is timed against dense.
     """
     import jax
     import jax.numpy as jnp
 
-    from tpucfn.kernels.flash_attention import SUBLANES, flash_attention
+    from tpucfn.kernels.flash_attention import (GROUP, LANES, SUBLANES,
+                                                flash_attention)
 
     dtype = dtype or jnp.bfloat16
+    mask = dict(window=window)
+    if select:
+        mask["select"] = jnp.full((batch, -(-s // GROUP), s, LANES), -1,
+                                  jnp.int32)
     kq, kk, kv = jax.random.split(jax.random.key(0), 3)
     q = jax.random.normal(kq, (batch, s, heads, d), dtype)
     k = jax.random.normal(kk, (batch, s, kv_heads, d), dtype)
@@ -193,14 +206,15 @@ def tune(
         row = {"blocks": (bq, bk)}
         try:
             fwd = jax.jit(lambda q, k, v, bq=bq, bk=bk: flash_attention(
-                q, k, v, causal=causal, block_q=bq, block_k=bk, scale=scale))
+                q, k, v, causal=causal, block_q=bq, block_k=bk, scale=scale,
+                **mask))
             row["fwd_ms"] = round(timed(fwd, q, k, v), 3)
             total = row["fwd_ms"]
             if include_bwd:
                 bwd = jax.jit(jax.grad(
                     lambda q, k, v, bq=bq, bk=bk: jnp.sum(flash_attention(
                         q, k, v, causal=causal, block_q=bq, block_k=bk,
-                        scale=scale).astype(jnp.float32) ** 2),
+                        scale=scale, **mask).astype(jnp.float32) ** 2),
                     argnums=(0, 1, 2)))
                 row["bwd_ms"] = round(timed(bwd, q, k, v), 3)
                 total += row["bwd_ms"]
@@ -223,7 +237,8 @@ def tune(
     # which the untuned-length rule in kernels.auto resolves.
     speedup = None
     dense_ms = None
-    if include_bwd:
+    mask_kind = f"window{window}" if window else "select" if select else None
+    if include_bwd and mask_kind is None:
         from tpucfn.ops.attention import dot_product_attention
 
         try:
@@ -240,7 +255,8 @@ def tune(
         except Exception as e:  # noqa: BLE001 — dense OOM at long S
             dense_ms = f"error: {repr(e)[:160]}"
 
-    key = _key(jax.devices()[0].device_kind, causal, s, d, dtype, dv)
+    key = _key(jax.devices()[0].device_kind, causal, s, d, dtype, dv,
+               mask_kind)
     if persist:
         global _MEM_CACHE
         user = _read_table(_cache_path())

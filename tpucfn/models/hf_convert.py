@@ -38,9 +38,11 @@ def config_from_hf(hf_config: Any, **overrides) -> LlamaConfig:
     converting to silently-wrong numerics."""
     import dataclasses
 
+    from tpucfn.models import dots3_note
     from tpucfn.models.hybrid import refuse_recurrent_model
 
     refuse_recurrent_model(hf_config, "hf_convert")
+    dots3_note.refuse(hf_config, "hf_convert")
     scaling = getattr(hf_config, "rope_scaling", None)
     if scaling not in (None, {}):
         raise NotImplementedError(

@@ -344,7 +344,7 @@ class PlannedDecoder(nn.Module):
             x, c = layer(cfg, attention_fn, name=name)(x)
             found.append(c)
         x, c = nn.scan(
-            plan.period, variable_axes={"params": 0},
+            plan.period, variable_axes={"params": 0, "intermediates": 0},
             split_rngs={"params": True}, length=plan.periods,
         )(cfg, attention_fn, name=plan.trunk)(x)
         found.append(c)
@@ -354,9 +354,7 @@ class PlannedDecoder(nn.Module):
             second, c = block(cfg, attention_fn, name=name)(x, tokens, embed)
             found.append(c)
             hidden = (hidden, second)
-        counters = jax.tree.map(
-            lambda *parts: jnp.concatenate([p.reshape(-1) for p in parts]),
-            *[c for c in found if c])
+        counters = merge_counters(found)
         if return_hidden:
             return hidden, counters
         if plan.tie_embeddings:
@@ -369,6 +367,14 @@ class PlannedDecoder(nn.Module):
                 param_dtype=cfg.param_dtype, name="lm_head",
                 kernel_init=nn.initializers.normal(0.02))
         return jax.tree.map(lambda h: head(h.astype(jnp.float32)), hidden), counters
+
+
+def merge_counters(found) -> dict:
+    """Each counter's values over the layers that report it, in order: a
+    kind of layer may report counters that another lacks."""
+    names = dict.fromkeys(n for c in found for n in c)
+    return {n: jnp.concatenate([c[n].reshape(-1) for c in found if n in c])
+            for n in names}
 
 
 class HybridDecoder(PlannedDecoder):

@@ -125,11 +125,19 @@ def rope_adjacent_pairs(x, theta: float):
 
 
 class LatentAttention(nn.Module):
+    """``gate``: each head's output times ``sigmoid(x W_g)`` of its own
+    before the output projection (headwise gate).  ``window``: causal
+    attention over the last ``window`` positions.  ``attend``, where given,
+    takes the place of the attention call: ``attend(q, k, v, c_q) -> (out,
+    readings)``, and the layer returns ``(output, readings)``."""
+
     cfg: LatentConfig
     attention_fn: AttentionFn
+    gate: bool = False
+    window: int | None = None
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, attend=None):
         cfg = self.cfg
         b, s, _ = x.shape
         h, nope, rot = cfg.n_heads, cfg.qk_nope_dim, cfg.qk_rope_dim
@@ -147,9 +155,20 @@ class LatentAttention(nn.Module):
             axis=-1)
         k = jnp.concatenate(
             [kv[..., :nope], jnp.broadcast_to(k_rot, (b, s, h, rot))], axis=-1)
-        out = self.attention_fn(q, k, kv[..., nope:], causal=True)
-        return _dense(cfg, x.shape[-1], "o_proj")(
+        if attend is not None:
+            out, readings = attend(q, k, kv[..., nope:], c_q)
+        elif self.window:
+            with jax.named_scope("swa_attend"):
+                out = self.attention_fn(q, k, kv[..., nope:], causal=True,
+                                        window=self.window)
+        else:
+            out = self.attention_fn(q, k, kv[..., nope:], causal=True)
+        if self.gate:
+            g = nn.sigmoid(_dense(cfg, h, "gate_proj")(x).astype(jnp.float32))
+            out = (out * g[..., None]).astype(out.dtype)
+        y = _dense(cfg, x.shape[-1], "o_proj")(
             out.reshape(b, s, h * cfg.v_head_dim))
+        return y if attend is None else (y, readings)
 
 
 class _Layer(nn.Module):

@@ -42,14 +42,16 @@ def remat_policy(remat: bool | str):
     both paths honor the same policy vocabulary.
 
     "full" saves only what the flash kernel names (its output and its
-    compact LSE, the two residuals its backward reads), so the kernel's
-    forward runs once a step; a layer with no flash call holds nothing
-    of that name and saves nothing."""
+    compact LSE, the two residuals its backward reads, and a selection of
+    keys it was handed), so the kernel's forward, and the selection's,
+    run once a step; a layer with no flash call holds nothing of those
+    names and saves nothing."""
     if remat in (True, "full"):
-        from tpucfn.kernels.flash_attention import FLASH_LSE, FLASH_OUT
+        from tpucfn.kernels.flash_attention import (FLASH_LSE, FLASH_OUT,
+                                                    FLASH_SELECT)
 
         return True, jax.checkpoint_policies.save_only_these_names(
-            FLASH_OUT, FLASH_LSE)
+            FLASH_OUT, FLASH_LSE, FLASH_SELECT)
     if remat in (False, "none"):
         return False, None
     if remat == "dots":
